@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 __all__ = ["bench_ingress", "tiny_config"]
 
 
@@ -161,6 +163,7 @@ def main():
     ap.add_argument("--tiny", action="store_true", help="CI-smoke geometry")
     ap.add_argument("--path", default="fused")
     args = ap.parse_args()
+    enable_compile_cache()
     kw = dict(tiny=args.tiny, path=args.path)
     if args.quick:
         kw.update(methods=("threshold",), buckets=(1, 8), n_iter=3)

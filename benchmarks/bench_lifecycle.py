@@ -31,6 +31,8 @@ from typing import Dict, List
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 __all__ = ["bench_lifecycle"]
 
 
@@ -184,6 +186,7 @@ def main():
     ap.add_argument("--tiny", action="store_true", help="CI-smoke geometry")
     ap.add_argument("--rate", type=float, default=2000.0)
     args = ap.parse_args()
+    enable_compile_cache()
     kw = dict(tiny=args.tiny, rate=args.rate)
     if args.quick:
         kw.update(n_requests=150, n_swaps=3)

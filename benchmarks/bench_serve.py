@@ -37,8 +37,8 @@ kind): the same raw-pixel workload served by a :class:`ServeMesh`-backed
 engine at 1/2/8 data shards — each row records the devices the batch was
 actually spread over (EXPERIMENTS.md §Serve/mesh).  Run it with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` on CPU;
-``benchmarks/run.py --emit-json`` does so via a subprocess so the main
-harness stays single-device.
+``benchmarks/run.py --emit-json`` sweeps it in-process over the devices
+the process has.
 
 Runs on CPU with the ``ref`` kernel backend (the non-TPU default).
 
@@ -55,6 +55,8 @@ from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
+
+from repro.launch.compile_cache import enable_compile_cache
 
 PAPER_RATE = 60_300        # classifications/s @ 27.8 MHz
 PAPER_LATENCY_US = 25.4    # single-image latency incl. system overhead
@@ -409,6 +411,7 @@ def main():
                     help="per-device-count ServeMesh rows instead of the "
                          "single-device sweep (wants 8 virtual devices)")
     args = ap.parse_args()
+    enable_compile_cache()
     buckets = (8, 64) if args.quick else (1, 8, 64, 256)
     reps = 3 if args.quick else 10
     print("name,us_per_call,derived")

@@ -43,6 +43,8 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 PAPER_RATE = 60_300        # classifications/s @ 27.8 MHz
 PAPER_LATENCY_US = 25.4    # single-image latency incl. system overhead
 
@@ -317,6 +319,7 @@ def main():
     ap.add_argument("--tiny", action="store_true", help="CI-smoke geometry")
     ap.add_argument("--path", default="fused")
     args = ap.parse_args()
+    enable_compile_cache()
     kw = dict(tiny=args.tiny)
     if args.quick:
         kw.update(rates=(500.0, 2000.0), delays_us=(0.0, 200.0),

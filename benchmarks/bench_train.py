@@ -137,6 +137,9 @@ def bench_tm_train(batch: int = 64, iters: int = 3) -> List[Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for r in bench_tm_train():
         print(f"{r['name']},{r['us_per_call']},{r['derived']}")

@@ -9,6 +9,7 @@ latency split) so the perf trajectory is comparable across PRs; CI
 smoke-runs it at ``--tiny`` geometry and uploads the artifact.
 
 Run:  PYTHONPATH=src python -m benchmarks.run [--quick]
+      XLA_FLAGS=--xla_force_host_platform_device_count=8 \
       PYTHONPATH=src python -m benchmarks.run --emit-json bench_out [--tiny]
 """
 
@@ -17,54 +18,29 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
 import sys
 import time
 
-_MESH_ROWS_MARK = "MESH_ROWS_JSON="
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _mesh_rows(*, tiny: bool) -> list:
     """Per-device-count ``serve_mesh`` rows for BENCH_serve.json.
 
-    The virtual device count must be set before jax initializes, so the
-    sweep runs in a subprocess with
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the parent
-    harness stays on its own device set) and ships its rows back as one
-    JSON line.
+    Runs in this process, over the devices it has: counts beyond them
+    are skipped.  One process holds the chip, so no child is started;
+    on CPU, run the whole command with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` to get the
+    1/2/8 sweep.
     """
-    env = dict(os.environ)
-    flags = env.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8"
-        ).strip()
-    env["PYTHONPATH"] = "src" + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
+    from benchmarks.bench_serve import bench_serve_mesh
+
+    return bench_serve_mesh(
+        device_counts=(1, 2, 8),
+        buckets=(8,) if tiny else (8, 64),
+        n_requests=3 if tiny else 10,
+        tiny=tiny,
     )
-    buckets = (8,) if tiny else (8, 64)
-    reps = 3 if tiny else 10
-    code = (
-        "import json\n"
-        "from benchmarks.bench_serve import bench_serve_mesh\n"
-        f"rows = bench_serve_mesh(device_counts=(1, 2, 8), "
-        f"buckets={buckets!r}, n_requests={reps}, tiny={tiny!r})\n"
-        f"print({_MESH_ROWS_MARK!r} + json.dumps(rows))\n"
-    )
-    r = subprocess.run(
-        [sys.executable, "-c", code],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        timeout=1800,
-    )
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"mesh benchmark subprocess failed:\n{r.stderr[-3000:]}"
-        )
-    for line in r.stdout.splitlines():
-        if line.startswith(_MESH_ROWS_MARK):
-            return json.loads(line[len(_MESH_ROWS_MARK):])
-    raise RuntimeError("mesh benchmark subprocess produced no rows line")
 
 
 def _csv(rows):
@@ -119,8 +95,7 @@ def emit_json(out_dir: str, *, tiny: bool) -> None:
         n_requests=reps,
         tiny=tiny,
     )
-    # Per-device-count sharded-serving rows (8 virtual CPU devices in a
-    # subprocess — device count is fixed at jax init).
+    # Per-device-count sharded-serving rows, over this process's devices.
     serve_rows += _mesh_rows(tiny=tiny)
     serve_rows += bench_service(
         rates=(500.0,) if tiny else (500.0, 2000.0),
@@ -180,6 +155,7 @@ def main() -> None:
         help="CI-smoke geometry for --emit-json (small clause pool/patches)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.emit_json:
         emit_json(args.emit_json, tiny=args.tiny)

@@ -31,29 +31,17 @@ __all__ = [
     "compressed_grad_sync",
     "int8_psum_shard_map",
     "tree_psum_batch",
-    "shard_map_compat",
+    "shard_map",
     "psum_tree",
 ]
 
 BLOCK = 2048
 
 
-def _shard_map():
-    """jax.shard_map (>= 0.6) or the experimental 0.4.x export."""
-    if hasattr(jax, "shard_map"):                    # jax >= 0.6
-        return functools.partial(jax.shard_map, check_vma=False)
-    from jax.experimental.shard_map import shard_map  # jax 0.4.x
-
-    return functools.partial(shard_map, check_rep=False)
-
-
-def shard_map_compat():
-    """The version-compat ``shard_map`` (jax >= 0.6 or the 0.4.x
-    experimental export), for callers outside this module that build
-    explicit per-shard programs — e.g. the clause-sharded serving step
-    (``serve/mesh.py``), whose partial class sums are combined with
-    :func:`psum_tree`."""
-    return _shard_map()
+#: ``jax.shard_map`` with the varying-manual-axes check off: the bodies
+#: built on it (here and the clause-sharded serving step in
+#: ``serve/mesh.py``) reduce their integer partials with explicit psums.
+shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def psum_tree(tree: Any, axis: str) -> Any:
@@ -130,7 +118,7 @@ def int8_psum_shard_map(x: jax.Array, mesh: Mesh, axis: str = "pod") -> jax.Arra
 
     other = tuple(a for a in mesh.axis_names if a != axis)
     spec = P(*((None,) * x.ndim))
-    return _shard_map()(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
+    return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
 
 
 def tree_psum_batch(tree: Any, mesh: Mesh | None = None, axis: str = "data") -> Any:
@@ -162,7 +150,7 @@ def tree_psum_batch(tree: Any, mesh: Mesh | None = None, axis: str = "data") -> 
     def body(*leaves):
         return tuple(jax.lax.psum(jnp.sum(x, axis=0), axis) for x in leaves)
 
-    outs = _shard_map()(
+    outs = shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs
     )(*flat)
     return jax.tree.unflatten(treedef, list(outs))

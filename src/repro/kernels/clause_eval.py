@@ -3,14 +3,23 @@
 This is the accelerator's clause pool (paper Sec. IV-D) re-derived for the
 TPU memory hierarchy:
 
-  * Literals arrive bit-packed: uint32 ``[B, P, W]`` — 9 words encode the
-    272 literals of a patch (vs 272 bytes dense: an 8.5x cut in HBM traffic
-    for the dominant input stream; the dense path is memory-bound).
-  * The include masks (the model's TA-action registers) are uint32
-    ``[C, W]``.  Their BlockSpec index map ignores the patch-chunk grid
-    axis, so the model block stays **resident in VMEM** across all patch
-    chunks — the TPU analogue of the ASIC's "model clock stopped, actions
-    held in DFFs".
+  * Literals arrive bit-packed: 9 uint32 words encode the 272 literals of
+    a patch (vs 272 bytes dense: an 8.5x cut in HBM traffic for the
+    dominant input stream; the dense path is memory-bound).  The kernel
+    takes them word-major, ``[W, B, P]``, so that patches lie on the
+    128-wide lane axis: one word of one image is a ``(1, Pc)`` row.
+  * The include masks (the model's TA-action registers) come as
+    ``[W, C, 1]``: clauses on sublanes, so one word of the clause block is
+    a ``(Cb, 1)`` column that broadcasts across the patch lanes.  Their
+    BlockSpec index map ignores the patch-chunk grid axis, so the model
+    block stays **resident in VMEM** across all patch chunks — the TPU
+    analogue of the ASIC's "model clock stopped, actions held in DFFs".
+  * Per image, the word loop ORs ``include & ~literal`` into a
+    ``(Cb, Pc)`` violation tile (16 vregs at 128 x 128); a clause fires
+    on a patch iff its violation word stays zero, and on the image iff
+    it fires on any patch (a lane reduction).  Every ref access uses
+    leading or sublane indices only; nothing is sliced dynamically
+    along lanes, which Mosaic does not lower.
   * Grid = (image blocks, clause blocks, patch chunks); the patch axis is
     innermost so the output tile acts as the sequential-OR register
     (Eq. 6) accumulated in VMEM.
@@ -28,8 +37,8 @@ by the ``nonempty`` mask, so zero-padding never changes the OR.  Clause
 padding uses zero include masks + nonempty=0; batch padding is sliced off.
 
 Correctness on CPU is established with ``interpret=True`` (tests sweep
-shapes/dtypes against ref.py); on real TPU hardware the same call compiles
-to Mosaic.
+shapes against ref.py); on a TPU the same call compiles to Mosaic
+(tests/test_tpu_compile.py compiles it for a described v5e).
 """
 
 from __future__ import annotations
@@ -46,8 +55,8 @@ __all__ = [
     "PALLAS_ORACLES",
     "clause_eval_kernel",
     "clause_eval_pallas",
-    "clause_eval_sparse_kernel",
     "clause_eval_sparse_pallas",
+    "image_fires",
 ]
 
 #: Pallas entry point -> its pure-jnp oracle in kernels/ref.py (aggregated
@@ -58,15 +67,42 @@ PALLAS_ORACLES = {
 }
 
 
-def clause_eval_kernel(lit_ref, inc_ref, nonempty_ref, out_ref, *, csrf: bool):
+def image_fires(lit_ref, mask_ref, b, *, sparse: bool) -> jax.Array:
+    """int32 ``(Cb, 1)``: 1 where a clause of the block fires on some
+    patch of image ``b``'s chunk.
+
+    lit_ref: uint32 ``[W, Bb, Pc]`` packed literals (word-major);
+    mask_ref: uint32 ``[W, Cb, 1]`` packed include masks (dense) or
+    exclude masks (sparse).  A word misses when a required literal is
+    absent: ``include & ~lit`` (dense) or ``~(lit | exclude)`` (sparse),
+    the same predicate.  The fori_loop over words carries only the
+    ``(Cb, Pc)`` miss tile, so trace size and live VMEM stay flat in W.
+    """
+    n_words, _, pc = lit_ref.shape
+    cb = mask_ref.shape[1]
+
+    def word_step(w, miss):
+        lw = lit_ref[w, pl.ds(b, 1), :]         # (1, Pc)  one word, all patches
+        mw = mask_ref[w]                        # (Cb, 1)  one word, all clauses
+        return miss | (~(lw | mw) if sparse else (mw & ~lw))
+
+    miss = jax.lax.fori_loop(0, n_words, word_step, jnp.zeros((cb, pc), jnp.uint32))
+    return jnp.max((miss == 0).astype(jnp.int32), axis=1, keepdims=True)
+
+
+def clause_eval_kernel(lit_ref, mask_ref, *rest, csrf: bool, sparse: bool):
     """Kernel body for one (image-block, clause-block, patch-chunk) tile.
 
     Refs:
-      lit_ref:      uint32 [Bb, Pc, W]   packed literals
-      inc_ref:      uint32 [Cb, W]       packed include masks (VMEM-resident)
-      nonempty_ref: int32  [1, Cb]       nonempty flags
-      out_ref:      int32  [Bb, Cb]      sequential-OR accumulator
+      lit_ref:      uint32 [W, Bb, Pc]   packed literals
+      mask_ref:     uint32 [W, Cb, 1]    packed include (or exclude) masks
+      nonempty_ref: int32  [Cb, 1]       nonempty flags (dense only)
+      out_ref:      int32  [Bb, Cb, 1]   sequential-OR accumulator
     """
+    if sparse:
+        (out_ref,) = rest
+    else:
+        nonempty_ref, out_ref = rest
     ip = pl.program_id(2)
 
     @pl.when(ip == 0)
@@ -74,30 +110,14 @@ def clause_eval_kernel(lit_ref, inc_ref, nonempty_ref, out_ref, *, csrf: bool):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     def _tile_body():
-        lit = lit_ref[...]                      # (Bb, Pc, W) uint32
-        inc = inc_ref[...]                      # (Cb, W)     uint32
-        # Violation reduction over the word axis as a fori_loop carrying
-        # only the [Bb, Pc, Cb] accumulator: viol[b, p, c] = any word
-        # with a required-but-absent literal.  (A python
-        # `for w in range(n_words)` unroll traced W copies of the body —
-        # compile time grew linearly in W past paper geometry — while a
-        # single broadcast any() would materialize the full
-        # [Bb, Pc, Cb, W] mask in VMEM, ~17 MB at default blocks for
-        # W=64.  The loop keeps both trace size and live VMEM flat in W.)
-        def word_step(w, viol):
-            lw = jax.lax.dynamic_index_in_dim(lit, w, axis=2, keepdims=False)
-            iw = jax.lax.dynamic_index_in_dim(inc, w, axis=1, keepdims=False)
-            return viol | ((iw[None, None, :] & ~lw[:, :, None]) != 0)
+        def image(b, carry):
+            hit = image_fires(lit_ref, mask_ref, b, sparse=sparse)
+            if not sparse:
+                hit = hit & (nonempty_ref[...] != 0).astype(jnp.int32)
+            out_ref[b] = out_ref[b] | hit       # Eq. (6) accumulator
+            return carry
 
-        viol = jax.lax.fori_loop(
-            0, lit.shape[2], word_step,
-            jnp.zeros(lit.shape[:2] + (inc.shape[0],), jnp.bool_),
-        )
-        fires = ~viol                           # (Bb, Pc, Cb)
-        any_fire = jnp.any(fires, axis=1)       # (Bb, Cb) — OR over patches
-        ne = nonempty_ref[0, :] != 0            # (Cb,)
-        hit = (any_fire & ne[None, :]).astype(out_ref.dtype)
-        out_ref[...] = out_ref[...] | hit       # Eq. (6) accumulator
+        jax.lax.fori_loop(0, lit_ref.shape[1], image, 0)
 
     if csrf:
         # CSRF: skip the tile once the OR register is saturated.
@@ -110,50 +130,61 @@ def clause_eval_kernel(lit_ref, inc_ref, nonempty_ref, out_ref, *, csrf: bool):
         _tile_body()
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_b", "block_c", "block_p", "csrf", "interpret"),
-)
-def clause_eval_pallas(
-    lit_packed: jax.Array,      # uint32 [B, P, W]
-    include_packed: jax.Array,  # uint32 [C, W]
-    nonempty: jax.Array,        # bool/uint8 [C]
-    *,
-    block_b: int = 8,
-    block_c: int = 128,
-    block_p: int = 64,
-    csrf: bool = True,
-    interpret: bool = False,
-) -> jax.Array:
-    """Pallas clause evaluation; returns uint8 0/1 ``[B, C]``.
-
-    Inputs must already satisfy the padding contract (see ops.py, which
-    pads and dispatches); B % block_b == 0 etc. are required here.
-    """
-    b, p, w = lit_packed.shape
-    c = include_packed.shape[0]
-    ne = nonempty.astype(jnp.int32).reshape(1, c)
-
+def _clause_eval_call(lit_t, mask_t, extra, *, block_b, block_c, block_p,
+                      csrf, sparse, interpret):
+    """The pallas_call both entry points share; returns uint8 [B, C]."""
+    w, b, p = lit_t.shape
+    c = mask_t.shape[1]
     grid = (
         grid_blocks(b, block_b, axis="B"),
         grid_blocks(c, block_c, axis="C"),
         grid_blocks(p, block_p, axis="P"),
     )
+    in_specs = [
+        # Literals: advance along image and patch axes; all words.
+        pl.BlockSpec((w, block_b, block_p), lambda ib, ic, ip: (0, ib, ip)),
+        # Model block: pinned across patch chunks (VMEM-resident).
+        pl.BlockSpec((w, block_c, 1), lambda ib, ic, ip: (0, ic, 0)),
+    ]
+    if not sparse:
+        in_specs.append(pl.BlockSpec((block_c, 1), lambda ib, ic, ip: (ic, 0)))
     out = pl.pallas_call(
-        functools.partial(clause_eval_kernel, csrf=csrf),
+        functools.partial(clause_eval_kernel, csrf=csrf, sparse=sparse),
         grid=grid,
-        in_specs=[
-            # Literals: advance along image and patch axes; full word dim.
-            pl.BlockSpec((block_b, block_p, w), lambda ib, ic, ip: (ib, ip, 0)),
-            # Model block: pinned across patch chunks (VMEM-resident).
-            pl.BlockSpec((block_c, w), lambda ib, ic, ip: (ic, 0)),
-            pl.BlockSpec((1, block_c), lambda ib, ic, ip: (0, ic)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_c), lambda ib, ic, ip: (ib, ic)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_b, block_c, 1), lambda ib, ic, ip: (ib, ic, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, c, 1), jnp.int32),
         interpret=interpret,
-    )(lit_packed, include_packed, ne)
-    return out.astype(jnp.uint8)
+    )(lit_t, mask_t, *extra)
+    return out[:, :, 0].astype(jnp.uint8)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_b", "block_c", "block_p", "csrf", "interpret"),
+)
+def clause_eval_pallas(
+    lit_t: jax.Array,           # uint32 [W, B, P]   (word-major)
+    include_t: jax.Array,       # uint32 [W, C, 1]
+    nonempty: jax.Array,        # bool/uint8 [C]
+    *,
+    block_b: int = 8,
+    block_c: int = 128,
+    block_p: int = 128,
+    csrf: bool = True,
+    interpret: bool = False,
+) -> jax.Array:
+    """Pallas clause evaluation; returns uint8 0/1 ``[B, C]``.
+
+    Inputs must already be in the kernel layout and satisfy the padding
+    contract (see ops.py, which pads, transposes and dispatches);
+    B % block_b == 0 etc. are required here.
+    """
+    ne = nonempty.astype(jnp.int32).reshape(-1, 1)
+    return _clause_eval_call(
+        lit_t, include_t, (ne,), block_b=block_b, block_c=block_c,
+        block_p=block_p, csrf=csrf, sparse=False, interpret=interpret,
+    )
 
 
 # --- clause-sparsity fast path ---------------------------------------------
@@ -163,58 +194,11 @@ def clause_eval_pallas(
 # form of the ASIC's ``Empty`` gating, which here removes the rows
 # entirely instead of masking them).  The model side is the packed
 # EXCLUDE mask: a patch satisfies a clause iff every literal word covers
-# it, ``~(lit | exclude) == 0``.  Violations are accumulated as popcount
-# word ops (``population_count`` maps to the VPU popcount): the int32
-# per-(image, patch, clause) violation COUNT is the quantity the matmul
-# formulation computes on the MXU, so the two sparse paths share
-# semantics exactly.  There is no ``nonempty`` operand — clause padding
-# uses all-ones exclude masks (fires everywhere) and callers slice the
-# rows off / give them zero weight columns.
-
-
-def clause_eval_sparse_kernel(lit_ref, exc_ref, out_ref, *, csrf: bool):
-    """Kernel body for one (image-block, clause-block, patch-chunk) tile.
-
-    Refs:
-      lit_ref: uint32 [Bb, Pc, W]   packed literals
-      exc_ref: uint32 [Cb, W]       packed exclude masks (VMEM-resident)
-      out_ref: int32  [Bb, Cb]      sequential-OR accumulator
-    """
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    def _tile_body():
-        lit = lit_ref[...]                      # (Bb, Pc, W) uint32
-        exc = exc_ref[...]                      # (Cb, W)     uint32
-        # Popcount violation-count reduction over the word axis; the
-        # fori_loop carries only the int32 [Bb, Pc, Cb] count accumulator
-        # (same trace/VMEM discipline as clause_eval_kernel's word loop).
-        def word_step(w, counts):
-            lw = jax.lax.dynamic_index_in_dim(lit, w, axis=2, keepdims=False)
-            ew = jax.lax.dynamic_index_in_dim(exc, w, axis=1, keepdims=False)
-            miss = ~(lw[:, :, None] | ew[None, None, :])    # required-but-absent
-            return counts + jax.lax.population_count(miss).astype(jnp.int32)
-
-        counts = jax.lax.fori_loop(
-            0, lit.shape[2], word_step,
-            jnp.zeros(lit.shape[:2] + (exc.shape[0],), jnp.int32),
-        )
-        fires = counts == 0                     # (Bb, Pc, Cb)
-        any_fire = jnp.any(fires, axis=1)       # (Bb, Cb) — OR over patches
-        out_ref[...] = out_ref[...] | any_fire.astype(out_ref.dtype)
-
-    if csrf:
-        # CSRF block-skip: all clauses in the tile saturated -> no-op.
-        not_saturated = jnp.logical_not(jnp.all(out_ref[...] > 0))
-
-        @pl.when(jnp.logical_or(ip == 0, not_saturated))
-        def _work():
-            _tile_body()
-    else:
-        _tile_body()
+# it, ``~(lit | exclude) == 0`` — the zero-violation test the matmul
+# formulation makes on the MXU, so the two sparse paths share semantics
+# exactly.  There is no ``nonempty`` operand — clause padding uses
+# all-ones exclude masks (fires everywhere) and callers slice the rows
+# off / give them zero weight columns.
 
 
 @functools.partial(
@@ -222,12 +206,12 @@ def clause_eval_sparse_kernel(lit_ref, exc_ref, out_ref, *, csrf: bool):
     static_argnames=("block_b", "block_c", "block_p", "csrf", "interpret"),
 )
 def clause_eval_sparse_pallas(
-    lit_packed: jax.Array,      # uint32 [B, P, W]
-    exclude_packed: jax.Array,  # uint32 [C_a, W] (pad clauses: all ones)
+    lit_t: jax.Array,           # uint32 [W, B, P]   (word-major)
+    exclude_t: jax.Array,       # uint32 [W, C_a, 1] (pad clauses: all ones)
     *,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
@@ -240,22 +224,7 @@ def clause_eval_sparse_pallas(
     clause with >= 1 include violates on them, and include-free clauses
     cannot exist in the active pool.
     """
-    b, p, w = lit_packed.shape
-    c = exclude_packed.shape[0]
-    grid = (
-        grid_blocks(b, block_b, axis="B"),
-        grid_blocks(c, block_c, axis="C"),
-        grid_blocks(p, block_p, axis="P"),
+    return _clause_eval_call(
+        lit_t, exclude_t, (), block_b=block_b, block_c=block_c,
+        block_p=block_p, csrf=csrf, sparse=True, interpret=interpret,
     )
-    out = pl.pallas_call(
-        functools.partial(clause_eval_sparse_kernel, csrf=csrf),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_p, w), lambda ib, ic, ip: (ib, ip, 0)),
-            pl.BlockSpec((block_c, w), lambda ib, ic, ip: (ic, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, block_c), lambda ib, ic, ip: (ib, ic)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.int32),
-        interpret=interpret,
-    )(lit_packed, exclude_packed)
-    return out.astype(jnp.uint8)

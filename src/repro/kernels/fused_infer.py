@@ -9,8 +9,11 @@ where clause outputs feed the adder trees without leaving the chip.
 
 Grid = (image blocks, clause chunks, patch chunks); patch axis innermost
 (sequential OR), clause chunks accumulate partial class sums into the
-[Bb, m] output block (revisited across ic).  CSRF block-skip applies to
-the patch loop as in clause_eval.py.
+[Bb, 1, m] output block (revisited across ic).  Operand layouts, the
+per-image word loop and the CSRF block-skip are those of clause_eval.py;
+the weights come transposed, ``[C, m]``, so a clause column of the OR
+register scales its weight row and a sublane reduction gives the class
+sums of one image.
 """
 
 from __future__ import annotations
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.clause_eval import image_fires
 from repro.kernels.shapes import grid_blocks
 
 __all__ = ["PALLAS_ORACLES", "fused_infer_pallas", "fused_infer_sparse_pallas"]
@@ -34,16 +38,21 @@ PALLAS_ORACLES = {
 }
 
 
-def _kernel(lit_ref, inc_ref, ne_ref, w_ref, out_ref, or_scratch, *, csrf: bool):
+def _kernel(lit_ref, mask_ref, *rest, csrf: bool, sparse: bool):
     """Refs:
-      lit_ref: uint32 [Bb, Pc, W]; inc_ref: uint32 [Cc, W]
-      ne_ref:  int32 [1, Cc];      w_ref: int32 [M, Cc]
-      out_ref: int32 [Bb, M]       (class sums, accumulated over ic)
-      or_scratch: int32 [Bb, Cc]   (sequential-OR register, VMEM)
+      lit_ref: uint32 [W, Bb, Pc];  mask_ref: uint32 [W, Cc, 1]
+      ne_ref:  int32 [Cc, 1] (dense only);  w_ref: int32 [Cc, M]
+      out_ref: int32 [Bb, 1, M]     (class sums, accumulated over ic)
+      or_scratch: int32 [Bb, Cc, 1] (sequential-OR register, VMEM)
     """
+    if sparse:
+        w_ref, out_ref, or_scratch = rest
+    else:
+        ne_ref, w_ref, out_ref, or_scratch = rest
     ic = pl.program_id(1)
     ip = pl.program_id(2)
     n_ip = pl.num_programs(2)
+    n_img = lit_ref.shape[1]
 
     @pl.when(jnp.logical_and(ic == 0, ip == 0))
     def _init_out():
@@ -54,25 +63,14 @@ def _kernel(lit_ref, inc_ref, ne_ref, w_ref, out_ref, or_scratch, *, csrf: bool)
         or_scratch[...] = jnp.zeros_like(or_scratch)
 
     def _eval_tile():
-        lit = lit_ref[...]                              # (Bb, Pc, W)
-        inc = inc_ref[...]                              # (Cc, W)
-        # Word-axis reduction as a fori_loop carrying the [Bb, Pc, Cc]
-        # accumulator (see clause_eval.py: the python unroll bloated the
-        # trace linearly in W; a broadcast any() would blow VMEM).
-        def word_step(w, viol):
-            lw = jax.lax.dynamic_index_in_dim(lit, w, axis=2, keepdims=False)
-            iw = jax.lax.dynamic_index_in_dim(inc, w, axis=1, keepdims=False)
-            return viol | ((iw[None, None, :] & ~lw[:, :, None]) != 0)
+        def image(b, carry):
+            hit = image_fires(lit_ref, mask_ref, b, sparse=sparse)
+            if not sparse:
+                hit = hit & (ne_ref[...] != 0).astype(jnp.int32)
+            or_scratch[b] = or_scratch[b] | hit
+            return carry
 
-        viol = jax.lax.fori_loop(
-            0, lit.shape[2], word_step,
-            jnp.zeros(lit.shape[:2] + (inc.shape[0],), jnp.bool_),
-        )
-        fires = jnp.any(~viol, axis=1)                  # (Bb, Cc)
-        ne = ne_ref[0, :] != 0
-        or_scratch[...] = or_scratch[...] | (fires & ne[None, :]).astype(
-            or_scratch.dtype
-        )
+        jax.lax.fori_loop(0, n_img, image, 0)
 
     if csrf:
         @pl.when(jnp.logical_or(ip == 0, jnp.logical_not(jnp.all(or_scratch[...] > 0))))
@@ -83,13 +81,46 @@ def _kernel(lit_ref, inc_ref, ne_ref, w_ref, out_ref, or_scratch, *, csrf: bool)
 
     @pl.when(ip == n_ip - 1)
     def _class_sums():
-        fired = or_scratch[...].astype(jnp.float32)      # (Bb, Cc) 0/1
-        w = w_ref[...].astype(jnp.float32)               # (M, Cc)
-        part = jax.lax.dot_general(
-            fired, w, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        out_ref[...] = out_ref[...] + part.astype(jnp.int32)
+        w = w_ref[...].astype(jnp.float32)               # (Cc, M), int8 range
+
+        def image(b, carry):
+            fired = or_scratch[b].astype(jnp.float32)    # (Cc, 1) 0/1
+            # |partial| <= 127 * Cc: exact in f32.
+            part = jnp.sum(fired * w, axis=0, keepdims=True)   # (1, M)
+            out_ref[b] = out_ref[b] + part.astype(jnp.int32)
+            return carry
+
+        jax.lax.fori_loop(0, n_img, image, 0)
+
+
+def _fused_call(lit_t, mask_t, extra, weights_t, *, block_b, block_c, block_p,
+                csrf, sparse, interpret):
+    """The pallas_call both entry points share; returns int32 [B, M]."""
+    w, b, p = lit_t.shape
+    c = mask_t.shape[1]
+    m = weights_t.shape[1]
+    grid = (
+        grid_blocks(b, block_b, axis="B"),
+        grid_blocks(c, block_c, axis="C"),
+        grid_blocks(p, block_p, axis="P"),
+    )
+    in_specs = [
+        pl.BlockSpec((w, block_b, block_p), lambda ib, ic, ip: (0, ib, ip)),
+        pl.BlockSpec((w, block_c, 1), lambda ib, ic, ip: (0, ic, 0)),
+    ]
+    if not sparse:
+        in_specs.append(pl.BlockSpec((block_c, 1), lambda ib, ic, ip: (ic, 0)))
+    in_specs.append(pl.BlockSpec((block_c, m), lambda ib, ic, ip: (ic, 0)))
+    out = pl.pallas_call(
+        functools.partial(_kernel, csrf=csrf, sparse=sparse),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((block_b, 1, m), lambda ib, ic, ip: (ib, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, m), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((block_b, block_c, 1), jnp.int32)],
+        interpret=interpret,
+    )(lit_t, mask_t, *extra, weights_t.astype(jnp.int32))
+    return out[:, 0, :]
 
 
 @functools.partial(
@@ -97,104 +128,26 @@ def _kernel(lit_ref, inc_ref, ne_ref, w_ref, out_ref, or_scratch, *, csrf: bool)
     static_argnames=("block_b", "block_c", "block_p", "csrf", "interpret"),
 )
 def fused_infer_pallas(
-    lit_packed: jax.Array,      # uint32 [B, P, W]
-    include_packed: jax.Array,  # uint32 [C, W]
+    lit_t: jax.Array,           # uint32 [W, B, P]   (word-major)
+    include_t: jax.Array,       # uint32 [W, C, 1]
     nonempty: jax.Array,        # bool/uint8/int [C]
-    weights: jax.Array,         # int [M, C]
+    weights_t: jax.Array,       # int [C, M]
     *,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns int32 [B, M] class sums. Padding contract as in ops.py."""
-    b, p, w = lit_packed.shape
-    c = include_packed.shape[0]
-    m = weights.shape[0]
-    ne = nonempty.astype(jnp.int32).reshape(1, c)
-    grid = (
-        grid_blocks(b, block_b, axis="B"),
-        grid_blocks(c, block_c, axis="C"),
-        grid_blocks(p, block_p, axis="P"),
+    """Returns int32 [B, M] class sums. Layout and padding as in ops.py."""
+    ne = nonempty.astype(jnp.int32).reshape(-1, 1)
+    return _fused_call(
+        lit_t, include_t, (ne,), weights_t, block_b=block_b, block_c=block_c,
+        block_p=block_p, csrf=csrf, sparse=False, interpret=interpret,
     )
-    return pl.pallas_call(
-        functools.partial(_kernel, csrf=csrf),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_p, w), lambda ib, ic, ip: (ib, ip, 0)),
-            pl.BlockSpec((block_c, w), lambda ib, ic, ip: (ic, 0)),
-            pl.BlockSpec((1, block_c), lambda ib, ic, ip: (0, ic)),
-            pl.BlockSpec((m, block_c), lambda ib, ic, ip: (0, ic)),
-        ],
-        out_specs=pl.BlockSpec((block_b, m), lambda ib, ic, ip: (ib, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_b, block_c), jnp.int32)],
-        interpret=interpret,
-    )(lit_packed, include_packed, ne, weights.astype(jnp.int32))
 
 
 # --- clause-sparsity fast path ---------------------------------------------
-
-
-def _sparse_kernel(lit_ref, exc_ref, w_ref, out_ref, or_scratch, *, csrf: bool):
-    """Sparse fused kernel: popcount violation counts over the ACTIVE
-    clause pool (packed exclude masks, no nonempty operand), sequential-OR
-    register in VMEM scratch, in-register class-sum on the last patch
-    chunk.  See clause_eval.clause_eval_sparse_kernel for the padding
-    contract (pad clauses: all-ones exclude + zero weight columns).
-
-    Refs:
-      lit_ref: uint32 [Bb, Pc, W]; exc_ref: uint32 [Cc, W]
-      w_ref:   int32 [M, Cc]
-      out_ref: int32 [Bb, M]       (class sums, accumulated over ic)
-      or_scratch: int32 [Bb, Cc]   (sequential-OR register, VMEM)
-    """
-    ic = pl.program_id(1)
-    ip = pl.program_id(2)
-    n_ip = pl.num_programs(2)
-
-    @pl.when(jnp.logical_and(ic == 0, ip == 0))
-    def _init_out():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    @pl.when(ip == 0)
-    def _init_or():
-        or_scratch[...] = jnp.zeros_like(or_scratch)
-
-    def _eval_tile():
-        lit = lit_ref[...]                              # (Bb, Pc, W)
-        exc = exc_ref[...]                              # (Cc, W)
-
-        def word_step(w, counts):
-            lw = jax.lax.dynamic_index_in_dim(lit, w, axis=2, keepdims=False)
-            ew = jax.lax.dynamic_index_in_dim(exc, w, axis=1, keepdims=False)
-            miss = ~(lw[:, :, None] | ew[None, None, :])
-            return counts + jax.lax.population_count(miss).astype(jnp.int32)
-
-        counts = jax.lax.fori_loop(
-            0, lit.shape[2], word_step,
-            jnp.zeros(lit.shape[:2] + (exc.shape[0],), jnp.int32),
-        )
-        fires = jnp.any(counts == 0, axis=1)            # (Bb, Cc)
-        or_scratch[...] = or_scratch[...] | fires.astype(or_scratch.dtype)
-
-    if csrf:
-        @pl.when(jnp.logical_or(ip == 0, jnp.logical_not(jnp.all(or_scratch[...] > 0))))
-        def _work():
-            _eval_tile()
-    else:
-        _eval_tile()
-
-    @pl.when(ip == n_ip - 1)
-    def _class_sums():
-        fired = or_scratch[...].astype(jnp.float32)      # (Bb, Cc) 0/1
-        w = w_ref[...].astype(jnp.float32)               # (M, Cc)
-        part = jax.lax.dot_general(
-            fired, w, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        out_ref[...] = out_ref[...] + part.astype(jnp.int32)
 
 
 @functools.partial(
@@ -202,35 +155,21 @@ def _sparse_kernel(lit_ref, exc_ref, w_ref, out_ref, or_scratch, *, csrf: bool):
     static_argnames=("block_b", "block_c", "block_p", "csrf", "interpret"),
 )
 def fused_infer_sparse_pallas(
-    lit_packed: jax.Array,      # uint32 [B, P, W]
-    exclude_packed: jax.Array,  # uint32 [C_a, W] (pad clauses: all ones)
-    weights_active: jax.Array,  # int [M, C_a]    (pad columns: zero)
+    lit_t: jax.Array,           # uint32 [W, B, P]   (word-major)
+    exclude_t: jax.Array,       # uint32 [W, C_a, 1] (pad clauses: all ones)
+    weights_active_t: jax.Array,  # int [C_a, M]     (pad rows: zero)
     *,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns int32 [B, M] class sums over the active clause pool."""
-    b, p, w = lit_packed.shape
-    c = exclude_packed.shape[0]
-    m = weights_active.shape[0]
-    grid = (
-        grid_blocks(b, block_b, axis="B"),
-        grid_blocks(c, block_c, axis="C"),
-        grid_blocks(p, block_p, axis="P"),
-    )
-    return pl.pallas_call(
-        functools.partial(_sparse_kernel, csrf=csrf),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, block_p, w), lambda ib, ic, ip: (ib, ip, 0)),
-            pl.BlockSpec((block_c, w), lambda ib, ic, ip: (ic, 0)),
-            pl.BlockSpec((m, block_c), lambda ib, ic, ip: (0, ic)),
-        ],
-        out_specs=pl.BlockSpec((block_b, m), lambda ib, ic, ip: (ib, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, m), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((block_b, block_c), jnp.int32)],
+    """Returns int32 [B, M] class sums over the active clause pool (see
+    clause_eval.py for the sparse padding contract: pad clauses carry
+    all-ones exclude masks and zero weight rows)."""
+    return _fused_call(
+        lit_t, exclude_t, (), weights_active_t, block_b=block_b,
+        block_c=block_c, block_p=block_p, csrf=csrf, sparse=True,
         interpret=interpret,
-    )(lit_packed, exclude_packed, weights_active.astype(jnp.int32))
+    )

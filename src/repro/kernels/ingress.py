@@ -1,34 +1,43 @@
 """Pallas TPU kernel: booleanized images -> packed patch literals.
 
-The ingress stage of the fused inference path (ISSUE: the ASIC streams
+The ingress stage of the fused inference path (the ASIC streams
 booleanized pixels straight into the clause datapath, Sec. IV-C).  The
 jnp ingress materializes the dense literal tensor ``uint8 [B, P, 2o]``
 in HBM between patch extraction and bit packing — 8.5x the bytes of the
 packed form, and at paper geometry (361 patches x 272 literals) by far
 the largest intermediate of the whole inference pipeline.  This kernel
 keeps the dense bits in VMEM for the lifetime of one image block and
-writes only the packed ``uint32 [B, P, W]`` words back to HBM, so the
-dense literals never exist in device memory at all.
+writes only the packed words back to HBM, so the dense literals never
+exist in device memory at all.
 
 Layout decisions:
 
-  * Grid = (image blocks,) only.  A booleanized image is tiny (28x28
-    bytes), and one image block's full patch set — window gather, the
-    position thermometer constants, the dense literal bits, and the
-    packed output — fits comfortably in VMEM (~800 KB at paper geometry
-    for ``block_b=8``), so there is nothing to win from patch chunking
-    here; the consumer kernels (clause_eval / fused_infer) chunk the
-    patch axis themselves.
-  * The window gather is expressed as a static strided-slice per window
-    offset (``Wy*Wx`` slices), not a gather: patch (py, px) reads
-    ``img[py*dy + wy, px*dx + wx]``, so feature k = wy*Wx + wx of *all*
-    patches is one strided view of the image.  Static slices lower on
-    Mosaic where gathers would not.
+  * Grid = (image blocks,) only.  One image block's full patch set fits
+    in VMEM at paper geometry, so there is nothing to win from patch
+    chunking here; the consumer kernels (clause_eval / fused_infer)
+    chunk the patch axis themselves.
+  * Patches lie on the lane axis, and the output is word-major
+    ``[W, B, P]``: the layout the consumer kernels read.
+  * The window gather runs on the MXU.  Feature k = (wy, wx) of patch p
+    is the flat image pixel ``origin(p) + wy*X + wx``.  The kernel rolls
+    the flat image left by that offset (one lane rotate per window
+    offset) and multiplies the stacked rolls by a constant one-hot
+    ``[Y*X, P]`` matrix that picks each patch's origin pixel.  0/1
+    operands make the bf16 x bf16 -> f32 product exact.  Mosaic lowers
+    neither a gather nor the ``(Bb, By, Bx) -> (Bb, P)`` reshape a
+    strided-slice gather needs; a matmul it lowers.
   * The position thermometer bits are per-patch constants (they depend
     only on the geometry), computed by the same
     ``core.patches._index_tables`` the jnp path uses — one source of
     truth for the literal order — and passed as a pinned VMEM-resident
     input (Pallas does not allow kernels to close over array constants).
+  * Bits are packed in int32: the 32 shifted bits of a word are
+    disjoint, so their sum never carries and equals their OR, bit 31
+    included (Mosaic reduces no unsigned integers).  The wrapper
+    bitcasts the words to uint32.
+
+The one-hot matrix grows as ``Y*X*P``: about 0.7 MB at paper geometry,
+and it must fit in VMEM with the rest of the block.
 
 Correctness on CPU is established with ``interpret=True`` against the
 jnp oracle (``ref.ingress_pack_ref``); shape sweeps in
@@ -41,10 +50,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.patches import PatchSpec, _index_tables
-from repro.kernels.shapes import grid_blocks
+from repro.kernels.shapes import grid_blocks, round_up
 
 __all__ = ["PALLAS_ORACLES", "ingress_pack_kernel", "ingress_pack_pallas"]
 
@@ -52,42 +63,59 @@ __all__ = ["PALLAS_ORACLES", "ingress_pack_kernel", "ingress_pack_pallas"]
 #: by kernels/registry.py; statically enforced by tools/tmlint TM202).
 PALLAS_ORACLES = {"ingress_pack_pallas": "ingress_pack_ref"}
 
+_LANES = 128
 
-def ingress_pack_kernel(img_ref, pos_ref, out_ref, *, spec: PatchSpec):
+
+def ingress_pack_kernel(img_ref, sel_ref, pos_ref, out_ref, *, spec: PatchSpec):
     """Kernel body for one image block.
 
     Refs:
-      img_ref: uint8 [Bb, Y, X]       booleanized image bits
-      pos_ref: uint8 [P, max(pos,1)]  position-thermometer bits, pinned
-                                      (padded to >= 1 column; the real
-                                      width is recovered from ``spec``)
-      out_ref: uint32 [Bb, P, W]      packed literal words (LSB-first)
+      img_ref: f32 [Bb, N]             flat booleanized images (N = Y*X
+                                       padded to lanes)
+      sel_ref: bf16 [N, Pp]            one-hot: patch p's origin pixel
+      pos_ref: int32 [max(pos,1), 1, Pp]  position-thermometer bits,
+                                       pinned (padded to >= 1 row; the
+                                       real count comes from ``spec``)
+      out_ref: int32 [W, Bb, Pp]       packed literal words (LSB-first)
     """
-    img = img_ref[...]                              # (Bb, Y, X)
-    bb = img.shape[0]
-    n_pos = spec.n_pos_y_bits + spec.n_pos_x_bits
-    pos = pos_ref[...][:, :n_pos]                   # (P, pos_bits)
-    cols = []
+    img = img_ref[...]                              # (Bb, N)
+    bb, n = img.shape
+    rolled = []
     # Feature order: window bits row-major (wy, wx) — matches
     # core.patches._index_tables' meshgrid order exactly.
     for wy in range(spec.window_y):
-        ylim = wy + (spec.by - 1) * spec.stride_y + 1
         for wx in range(spec.window_x):
-            xlim = wx + (spec.bx - 1) * spec.stride_x + 1
-            v = img[:, wy:ylim:spec.stride_y, wx:xlim:spec.stride_x]
-            cols.append(v.reshape(bb, spec.n_patches))
-    win = jnp.stack(cols, axis=-1)                  # (Bb, P, Wy*Wx)
-    posb = jnp.broadcast_to(pos[None], (bb, spec.n_patches, n_pos))
-    feats = jnp.concatenate([win, posb], axis=-1)   # (Bb, P, o)
-    lits = jnp.concatenate([feats, 1 - feats], axis=-1).astype(jnp.uint32)
+            s = wy * spec.image_x + wx              # rolled[:, i] = img[:, i + s]
+            rolled.append(pltpu.roll(img, n - s, 1) if s else img)
+    stacked = jnp.concatenate(rolled, axis=0).astype(jnp.bfloat16)
+    win = jnp.dot(stacked, sel_ref[...], preferred_element_type=jnp.float32)
+    pp = win.shape[1]
+    feats = [win.astype(jnp.int32).reshape(len(rolled), bb, pp)]   # (Wy*Wx, Bb, Pp)
+    n_pos = spec.n_pos_y_bits + spec.n_pos_x_bits
+    if n_pos:
+        feats.append(jnp.broadcast_to(pos_ref[...], (n_pos, bb, pp)))
+    feats = jnp.concatenate(feats, axis=0)          # (o, Bb, Pp)
+    lits = [feats, 1 - feats]
     pad = spec.n_words * 32 - spec.n_literals
     if pad:
-        lits = jnp.concatenate(
-            [lits, jnp.zeros((bb, spec.n_patches, pad), jnp.uint32)], axis=-1
-        )
-    words = lits.reshape(bb, spec.n_patches, spec.n_words, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    out_ref[...] = jnp.sum(words << shifts, axis=-1, dtype=jnp.uint32)
+        lits.append(jnp.zeros((pad, bb, pp), jnp.int32))
+    words = jnp.concatenate(lits, axis=0).reshape(spec.n_words, 32, bb, pp)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, words.shape, 1)
+    out_ref[...] = jnp.sum(words << shifts, axis=1)
+
+
+def _tables(spec: PatchSpec):
+    """(one-hot origin selector [N, Pp] bf16, position bits [pos, 1, Pp])."""
+    iy, ix, pos = _index_tables(spec)
+    n = round_up(spec.image_y * spec.image_x, _LANES)
+    pp = round_up(spec.n_patches, _LANES)
+    origin = iy[:, 0] * spec.image_x + ix[:, 0]
+    sel = np.zeros((n, pp), np.float32)
+    sel[origin, np.arange(spec.n_patches)] = 1
+    n_pos = pos.shape[1]
+    posr = np.zeros((max(n_pos, 1), 1, pp), np.int32)   # whole-image window:
+    posr[:n_pos, 0, : spec.n_patches] = pos.T          # one zero row
+    return jnp.asarray(sel, jnp.bfloat16), jnp.asarray(posr)
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "block_b", "interpret"))
@@ -108,23 +136,22 @@ def ingress_pack_pallas(
         raise ValueError(
             f"image dims {(y, x)} != spec ({spec.image_y}, {spec.image_x})"
         )
-    _, _, pos = _index_tables(spec)     # the shared position-bit constants
-    if pos.shape[1] == 0:               # whole-image window: pad the pos
-        pos = jnp.zeros((spec.n_patches, 1), jnp.uint8)   # input to 1 col
-    else:
-        pos = jnp.asarray(pos, jnp.uint8)
-    grid = (grid_blocks(b, block_b, axis="B"),)
-    return pl.pallas_call(
+    sel, posr = _tables(spec)
+    n, pp = sel.shape
+    flat = bool_images.reshape(b, y * x).astype(jnp.float32)
+    flat = jnp.pad(flat, ((0, 0), (0, n - y * x)))
+    out = pl.pallas_call(
         functools.partial(ingress_pack_kernel, spec=spec),
-        grid=grid,
+        grid=(grid_blocks(b, block_b, axis="B"),),
         in_specs=[
-            pl.BlockSpec((block_b, y, x), lambda ib: (ib, 0, 0)),
-            # Position bits: pinned across image blocks (VMEM-resident).
-            pl.BlockSpec((spec.n_patches, pos.shape[1]), lambda ib: (0, 0)),
+            pl.BlockSpec((block_b, n), lambda ib: (ib, 0)),
+            # Geometry constants: pinned across image blocks (VMEM-resident).
+            pl.BlockSpec((n, pp), lambda ib: (0, 0)),
+            pl.BlockSpec(posr.shape, lambda ib: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(
-            (block_b, spec.n_patches, spec.n_words), lambda ib: (ib, 0, 0)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, spec.n_patches, spec.n_words), jnp.uint32),
+        out_specs=pl.BlockSpec((spec.n_words, block_b, pp), lambda ib: (0, ib, 0)),
+        out_shape=jax.ShapeDtypeStruct((spec.n_words, b, pp), jnp.int32),
         interpret=interpret,
-    )(bool_images, pos)
+    )(flat, sel, posr)
+    words = jax.lax.bitcast_convert_type(out[:, :, : spec.n_patches], jnp.uint32)
+    return jnp.transpose(words, (1, 2, 0))

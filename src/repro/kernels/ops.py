@@ -1,10 +1,15 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles the padding contract, picks block shapes, and falls back to the
-pure-jnp reference implementation where Pallas cannot run compiled (this
-container is CPU: the default backend is ``ref``; kernels execute with
-interpret=True only in tests / explicit ``backend='interpret'`` calls;
-on TPU they compile to Mosaic).
+Handles the padding contract, picks block shapes, and moves the packed
+operands into the kernels' layout (word-major literals ``[W, B, P]``,
+mask columns ``[W, C, 1]``; see kernels/clause_eval.py).  The backend:
+
+  * on a TPU the kernels compile to Mosaic, and a kernel that fails to
+    compile raises — there is no silent fallback;
+  * on any other backend the default is ``ref``, the pure-jnp oracle:
+    XLA:CPU cannot run a Mosaic kernel;
+  * ``backend='interpret'`` runs the Pallas body in the interpreter on
+    CPU; only tests ask for it.
 
 Padding safety (proved in tests/test_kernels.py):
   * patches pad with all-zero literal words  -> cannot fire any nonempty
@@ -25,6 +30,8 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.class_sum import class_sum_pallas
 from repro.kernels.clause_eval import clause_eval_pallas, clause_eval_sparse_pallas
+from repro.kernels.fused_infer import fused_infer_pallas, fused_infer_sparse_pallas
+from repro.kernels.ingress import ingress_pack_pallas
 from repro.kernels.shapes import clamp_block as _clamp_block
 from repro.kernels.shapes import pad_axis as _pad_axis
 from repro.kernels.shapes import pad_axis_ones as _pad_axis_ones
@@ -42,6 +49,9 @@ __all__ = [
 ]
 
 
+_LANES = 128
+
+
 def _pick_backend(backend: Optional[str]) -> str:
     """pallas on TPU, the pure-jnp reference elsewhere.
 
@@ -52,6 +62,25 @@ def _pick_backend(backend: Optional[str]) -> str:
     if backend is not None:
         return backend
     return "pallas" if jax.default_backend() == "tpu" else "ref"
+
+
+def _patch_block(block_p: int, p: int) -> int:
+    """Patch chunk on the lane axis: a multiple of 128, at most the
+    padded patch count."""
+    return _clamp_block(_round_up(block_p, _LANES), p, _LANES)
+
+
+def _word_major(lit_packed: jax.Array, b_to: int, p_to: int) -> jax.Array:
+    """uint32 [B, P, W] -> zero-padded kernel layout [W, b_to, p_to]."""
+    x = _pad_axis(_pad_axis(lit_packed, 0, b_to), 1, p_to)
+    return jnp.transpose(x, (2, 0, 1))
+
+
+def _mask_columns(mask: jax.Array, c_to: int, *, ones: bool = False) -> jax.Array:
+    """uint32 [C, W] -> kernel layout [W, c_to, 1]; clause rows pad with
+    zero words, or all-ones words for the sparse exclude masks."""
+    x = (_pad_axis_ones if ones else _pad_axis)(mask, 0, c_to)
+    return jnp.transpose(x)[:, :, None]
 
 
 @functools.partial(
@@ -65,7 +94,7 @@ def clause_eval(
     backend: Optional[str] = None,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
 ) -> jax.Array:
     """Sequential-OR clause outputs uint8 [B, C] from packed inputs.
@@ -81,14 +110,11 @@ def clause_eval(
     c = include_packed.shape[0]
     block_b = _clamp_block(block_b, b, 8)
     block_c = _clamp_block(block_c, c, 128)
-    block_p = _clamp_block(block_p, p, 8)
-    bp = _pad_axis(lit_packed, 0, _round_up(b, block_b))
-    bp = _pad_axis(bp, 1, _round_up(p, block_p))
-    ip = _pad_axis(include_packed, 0, _round_up(c, block_c))
+    block_p = _patch_block(block_p, p)
     ne = _pad_axis(nonempty.astype(jnp.int32), 0, _round_up(c, block_c))
     out = clause_eval_pallas(
-        bp,
-        ip,
+        _word_major(lit_packed, _round_up(b, block_b), _round_up(p, block_p)),
+        _mask_columns(include_packed, _round_up(c, block_c)),
         ne,
         block_b=block_b,
         block_c=block_c,
@@ -120,8 +146,6 @@ def ingress_pack(
     if bk == "ref":
         return ref.ingress_pack_ref(bool_images, spec)
 
-    from repro.kernels.ingress import ingress_pack_pallas
-
     b = bool_images.shape[0]
     block_b = _clamp_block(block_b, b, 8)
     imgs = _pad_axis(bool_images, 0, _round_up(b, block_b))
@@ -145,7 +169,7 @@ def fused_infer_from_images(
     backend: Optional[str] = None,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
 ) -> jax.Array:
     """Booleanized images -> class sums with no dense literals in HBM.
@@ -200,7 +224,7 @@ def fused_infer(
     backend: Optional[str] = None,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
 ) -> jax.Array:
     """Single-kernel clause_eval + class_sum, returns int32 [B, M].
@@ -212,20 +236,17 @@ def fused_infer(
     if bk == "ref":
         return ref.fused_infer_ref(lit_packed, include_packed, nonempty, weights)
 
-    from repro.kernels.fused_infer import fused_infer_pallas
-
     b, p, w = lit_packed.shape
     c = include_packed.shape[0]
     block_b = _clamp_block(block_b, b, 8)
     block_c = _clamp_block(block_c, c, 128)
-    block_p = _clamp_block(block_p, p, 8)
-    bp = _pad_axis(lit_packed, 0, _round_up(b, block_b))
-    bp = _pad_axis(bp, 1, _round_up(p, block_p))
-    ip = _pad_axis(include_packed, 0, _round_up(c, block_c))
-    ne = _pad_axis(nonempty.astype(jnp.int32), 0, _round_up(c, block_c))
-    wp = _pad_axis(weights, 1, _round_up(c, block_c))
+    block_p = _patch_block(block_p, p)
+    c_to = _round_up(c, block_c)
     out = fused_infer_pallas(
-        bp, ip, ne, wp,
+        _word_major(lit_packed, _round_up(b, block_b), _round_up(p, block_p)),
+        _mask_columns(include_packed, c_to),
+        _pad_axis(nonempty.astype(jnp.int32), 0, c_to),
+        _pad_axis(weights, 1, c_to).T,
         block_b=block_b, block_c=block_c, block_p=block_p,
         csrf=csrf, interpret=(bk == "interpret"),
     )
@@ -257,7 +278,7 @@ def clause_eval_sparse(
     backend: Optional[str] = None,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
 ) -> jax.Array:
     """Active-clause sequential-OR outputs uint8 [B, C_a] from packed
@@ -272,13 +293,10 @@ def clause_eval_sparse(
 
     block_b = _clamp_block(block_b, b, 8)
     block_c = _clamp_block(block_c, c, 128)
-    block_p = _clamp_block(block_p, p, 8)
-    bp = _pad_axis(lit_packed, 0, _round_up(b, block_b))
-    bp = _pad_axis(bp, 1, _round_up(p, block_p))
-    ep = _pad_axis_ones(exclude_packed, 0, _round_up(c, block_c))
+    block_p = _patch_block(block_p, p)
     out = clause_eval_sparse_pallas(
-        bp,
-        ep,
+        _word_major(lit_packed, _round_up(b, block_b), _round_up(p, block_p)),
+        _mask_columns(exclude_packed, _round_up(c, block_c), ones=True),
         block_b=block_b,
         block_c=block_c,
         block_p=block_p,
@@ -299,7 +317,7 @@ def fused_infer_sparse(
     backend: Optional[str] = None,
     block_b: int = 8,
     block_c: int = 128,
-    block_p: int = 64,
+    block_p: int = 128,
     csrf: bool = True,
 ) -> jax.Array:
     """Single-kernel sparse clause-eval + class-sum, int32 [B, M]."""
@@ -312,17 +330,14 @@ def fused_infer_sparse(
     if bk == "ref":
         return ref.sparse_infer_ref(lit_packed, exclude_packed, weights_active)
 
-    from repro.kernels.fused_infer import fused_infer_sparse_pallas
-
     block_b = _clamp_block(block_b, b, 8)
     block_c = _clamp_block(block_c, c, 128)
-    block_p = _clamp_block(block_p, p, 8)
-    bp = _pad_axis(lit_packed, 0, _round_up(b, block_b))
-    bp = _pad_axis(bp, 1, _round_up(p, block_p))
-    ep = _pad_axis_ones(exclude_packed, 0, _round_up(c, block_c))
-    wp = _pad_axis(weights_active, 1, _round_up(c, block_c))
+    block_p = _patch_block(block_p, p)
+    c_to = _round_up(c, block_c)
     out = fused_infer_sparse_pallas(
-        bp, ep, wp,
+        _word_major(lit_packed, _round_up(b, block_b), _round_up(p, block_p)),
+        _mask_columns(exclude_packed, c_to, ones=True),
+        _pad_axis(weights_active, 1, c_to).T,
         block_b=block_b, block_c=block_c, block_p=block_p,
         csrf=csrf, interpret=(bk == "interpret"),
     )

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.configs import get_config, reduced_config
 from repro.launch import specs as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import encdec as ed
 from repro.models import transformer as tfm
 from repro.models.base import init_params
@@ -399,6 +400,7 @@ def main():
                          "walks away; their futures must still resolve "
                          "(--service)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.configs.convcotm import COTM_CONFIGS
 

@@ -29,6 +29,7 @@ from repro.checkpoint.checkpointer import Checkpointer, latest_step
 from repro.configs import TrainConfig, get_config, reduced_config
 from repro.distributed.fault_tolerance import StragglerPolicy
 from repro.launch import specs as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.base import init_params, param_count
 from repro.train.train_step import init_train_state, make_train_step
 
@@ -235,6 +236,7 @@ def main():
     ap.add_argument("--epochs", type=int, default=5)
     ap.add_argument("--mode", default="batch", choices=["batch", "scan"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.configs.convcotm import COTM_CONFIGS
 

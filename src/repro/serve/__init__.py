@@ -52,11 +52,12 @@ from repro.serve.faults import (
     PoisonedPayload,
     ServiceExpired,
     ServiceHealth,
+    StepCompileError,
     WorkerCrashed,
     chaos_soak,
 )
 from repro.serve.loadgen import LoadReport, poisson_open_loop
-from repro.serve.mesh import ServeMesh, classify_step_clause_sharded, make_serve_mesh
+from repro.serve.mesh import ServeMesh, classify_step_meshed, make_serve_mesh
 from repro.serve.paths import (
     DENSE,
     PACKED,
@@ -127,6 +128,7 @@ __all__ = [
     "ServiceStopped",
     "ServingEngine",
     "ServingService",
+    "StepCompileError",
     "TunedPlan",
     "WorkerCrashed",
     "active_pad",
@@ -136,7 +138,7 @@ __all__ = [
     "chaos_soak",
     "classify_raw_step",
     "classify_step",
-    "classify_step_clause_sharded",
+    "classify_step_meshed",
     "degraded_fallback",
     "freeze",
     "make_serve_mesh",

@@ -235,9 +235,8 @@ def autotune_servable(
     """Measure every admissible candidate per (form, bucket); return the
     winning :class:`TunedPlan` plus the full :class:`AutotuneReport`.
 
-    ``smesh`` (a ServeMesh) measures through the meshed steps the engine
-    will actually dispatch; clause-sharded meshes restrict candidates to
-    default params (the shard_map step takes none).  ``max_seconds``
+    ``smesh`` (a ServeMesh) measures through the meshed step the engine
+    will actually dispatch, at default params only.  ``max_seconds``
     bounds wall clock: once exceeded, remaining candidates are skipped
     (the best-so-far still wins — noted in the report) and remaining
     (form, bucket) cells keep the registered path.  Leave it None for
@@ -245,10 +244,9 @@ def autotune_servable(
     """
     # Engine-layer steps imported here (engine imports this module too).
     from repro.serve.engine import classify_raw_step, classify_step
-    from repro.serve.mesh import classify_step_clause_sharded
+    from repro.serve.mesh import classify_step_meshed
 
     backend = jax.default_backend()
-    clause_sharded = smesh is not None and smesh.shard_clauses
     sweep = backend == "tpu" and smesh is None
     registered = sp.get_path(path_name)
     sparsity_key = None if servable.sparsity is None else servable.sparsity.n_active
@@ -282,19 +280,11 @@ def autotune_servable(
                     )
                     if smesh is not None:
                         x = smesh.place_batch(arr)
-                        if clause_sharded:
-                            step = lambda: classify_step_clause_sharded(
-                                servable, x, smesh=smesh, path_name=name,
-                                ingress=ingress if form == "raw" else None,
-                            )
-                        elif form == "raw":
-                            step = lambda: classify_raw_step(
-                                servable, x, name, ingress
-                            )
-                        else:
-                            step = lambda: classify_step(
-                                servable, x, name, params=params
-                            )
+                        step = lambda: classify_step_meshed(
+                            servable, x, smesh=smesh, path_name=name,
+                            ingress=ingress if form == "raw" else None,
+                            params=params,
+                        )
                     elif form == "raw":
                         x = arr
                         step = lambda: classify_raw_step(

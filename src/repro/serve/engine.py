@@ -79,7 +79,8 @@ from repro.core.cotm import CoTMConfig, CoTMModel
 from repro.core.ingress import IngressSpec, raw_trailing_shape
 from repro.data.pipeline import preprocess_for_serving
 from repro.serve.autotune import TunedPlan, autotune_servable
-from repro.serve.mesh import ServeMesh, classify_step_clause_sharded
+from repro.serve.faults import StepCompileError
+from repro.serve.mesh import ServeMesh, classify_step_meshed
 from repro.serve.paths import PACKED, Params, get_path, run_path, run_path_raw
 from repro.serve.servable import (
     ServableModel,
@@ -928,34 +929,37 @@ class ServingEngine:
         # path's input form (autotune admissibility), so ``arr`` is
         # always in the right form already.
         path_name, params = entry.resolve(form, bucket)
-        if self.mesh is not None:
-            # One placed (data-sharded) buffer; the jitted step runs as a
-            # single program across the mesh and GSPMD/shard_map gathers
-            # nothing until .result() reads the global output.
-            x = self.mesh.place_batch(arr)
-            if self.mesh.shard_clauses:
-                preds, sums = classify_step_clause_sharded(
-                    entry.servable, x,
+        # The first dispatch of a (form, bucket) traces and compiles its
+        # step; a failure there is a defect on this backend (a Pallas
+        # kernel Mosaic refuses), raised as such and never degraded around.
+        fresh = (form, bucket) not in entry.compiled
+        try:
+            if self.mesh is not None:
+                # One placed (data-sharded) buffer; the per-shard program
+                # runs across the mesh and nothing gathers until .result()
+                # reads the global output.
+                preds, sums = classify_step_meshed(
+                    entry.servable, self.mesh.place_batch(arr),
                     smesh=self.mesh,
                     path_name=path_name,
                     ingress=entry.ingress if form == "raw" else None,
+                    params=params,
                 )
             elif form == "raw":
                 preds, sums = classify_raw_step(
-                    entry.servable, x, path_name, entry.ingress, params
+                    entry.servable, jnp.asarray(arr), path_name, entry.ingress, params
                 )
             else:
                 preds, sums = classify_step(
-                    entry.servable, x, path_name, params=params
+                    entry.servable, jnp.asarray(arr), path_name, params=params
                 )
-        elif form == "raw":
-            preds, sums = classify_raw_step(
-                entry.servable, jnp.asarray(arr), path_name, entry.ingress, params
-            )
-        else:
-            preds, sums = classify_step(
-                entry.servable, jnp.asarray(arr), path_name, params=params
-            )
+        except Exception as e:
+            if not fresh:
+                raise
+            raise StepCompileError(
+                f"{path_name!r} {form} step for bucket {bucket} failed to "
+                f"compile on {jax.default_backend()}: {e}"
+            ) from e
         st = entry.stats
         if record_hit:
             st.bucket_hits[bucket] = st.bucket_hits.get(bucket, 0) + 1

@@ -29,8 +29,9 @@ module is the serving analogue (ARCHITECTURE.md §Faults):
     and fault counters; exposed through ``ServiceStats`` snapshots.
 
 Structured errors (``WorkerCrashed``, ``PoisonedPayload``,
-``DeviceLost``, ``ServiceExpired``) are what request futures resolve
-with when their request cannot be served: the request-lifetime guarantee
+``DeviceLost``, ``StepCompileError``, ``ServiceExpired``) are what
+request futures resolve with when their request cannot be served: the
+request-lifetime guarantee
 is that every admitted future resolves — with a result or one of these —
 never hangs (``tests/test_faults.py`` chaos suite).
 
@@ -52,6 +53,7 @@ __all__ = [
     "PoisonedPayload",
     "DeviceLost",
     "InjectedEngineError",
+    "StepCompileError",
     "ServiceExpired",
     "FaultPlan",
     "DegradationPolicy",
@@ -100,6 +102,16 @@ class InjectedEngineError(FaultError):
     a real XLA/runtime error at dispatch)."""
 
     kind = "engine_error"
+
+
+class StepCompileError(FaultError):
+    """The classify step for a new (form, bucket) failed to trace or
+    compile — on a TPU, typically a Pallas kernel Mosaic refuses.  A
+    defect of the program on this backend, not a runtime fault: the
+    circuit breaker does not degrade around it, and the service fails
+    the microbatch with it."""
+
+    kind = "compile_error"
 
 
 class ServiceExpired(Exception):

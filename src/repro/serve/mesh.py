@@ -6,9 +6,13 @@ replicates the same TM datapath across independent tiles.  The software
 analogue is a :class:`ServeMesh`: each registered
 :class:`~repro.serve.servable.ServableModel` is placed across a
 ``("data", "model")`` :class:`jax.sharding.Mesh` and request batches are
-sharded along the **data** axis inside the engine's existing bucketed jit
-steps, so ``classify_step`` / ``classify_raw_step`` execute one program
-across N devices and return a single gathered result.
+sharded along the **data** axis.  One jitted step,
+:data:`classify_step_meshed`, runs every placement as an explicit
+``shard_map`` (:data:`repro.distributed.collectives.shard_map`): each
+device takes its batch shard through the path's ingress and evaluation,
+so one program spans N devices and returns a single gathered result.
+The per-shard program is required, not a style: a Pallas kernel on a TPU
+(a Mosaic custom call) cannot be partitioned by GSPMD.
 
 Two placement contracts, both **bit-identical** to the single-device
 engine (asserted in ``tests/test_serve_mesh.py``):
@@ -18,16 +22,13 @@ engine (asserted in ``tests/test_serve_mesh.py``):
     ~5.6 KiB/device) and only the batch is sharded over "data".  The
     datapath has no cross-batch interaction, so each device classifies
     its batch shard independently and the gathered result equals the
-    unsharded run bit for bit.  GSPMD partitions the existing jitted
-    steps from the input shardings alone.
+    unsharded run bit for bit.
   * **clause-sharded** (``shard_clauses=True``, for large-clause
     configs): the clause axis ``C`` of ``include``/``include_packed``/
     ``nonempty`` (and the ``C`` column axis of ``weights [m, C]``) is
     additionally split over "model" via the ``"clause"`` logical rule in
-    ``sharding/partition.py``.  Evaluation runs as an explicit
-    ``shard_map`` (:func:`repro.distributed.collectives.shard_map_compat`):
-    each device evaluates its clause shard and computes partial class
-    sums with its weight slice; an exact int32
+    ``sharding/partition.py``.  Each device evaluates its clause shard
+    and computes partial class sums with its weight slice; an exact int32
     :func:`~repro.distributed.collectives.psum_tree` over "model"
     combines them — integer addition reorders associatively, so Eq. (3)
     class sums stay bit-identical.
@@ -54,18 +55,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import clauses as cl
 from repro.core.ingress import IngressSpec
-from repro.distributed.collectives import psum_tree, shard_map_compat
+from repro.distributed.collectives import psum_tree, shard_map
+from repro.serve.paths import Params, get_path, run_path, run_path_raw
 from repro.serve.servable import ServableModel
 from repro.sharding import partition
 
-__all__ = ["ServeMesh", "make_serve_mesh", "classify_step_clause_sharded"]
+__all__ = ["ServeMesh", "make_serve_mesh", "classify_step_meshed"]
 
 
 @dataclasses.dataclass(frozen=True)
 class ServeMesh:
     """A serving placement: device mesh + sharding mode.
 
-    Hashable (the jit static key of the clause-sharded step).  ``mesh``
+    Hashable (the jit static key of :data:`classify_step_meshed`).  ``mesh``
     must carry a "data" axis; ``shard_clauses=True`` additionally
     requires a "model" axis, over which every registered model's clause
     pool is split (``n_clauses`` must divide evenly — validated at
@@ -200,62 +202,62 @@ def make_serve_mesh(
     return ServeMesh(make_serve_device_mesh(data, model), shard_clauses=shard_clauses)
 
 
-def _classify_clause_sharded(
+def _classify_meshed(
     servable: ServableModel,
     arr: jax.Array,
     smesh: ServeMesh,
     path_name: str,
     ingress: Optional[IngressSpec],
+    params: Params = (),
 ):
-    """Explicit per-shard program: each device evaluates its clause shard
-    of its batch shard and psums partial class sums over "model"."""
-    from repro.serve.paths import PACKED, get_path, resolve_path
-
+    """Explicit per-shard program: each device runs its batch shard (raw
+    ingress included) through the path — over its clause shard, with
+    partial class sums psummed over "model", when clause-sharded."""
     # Clause-sharded servables carry no sparsity analysis (placement
-    # drops it), so sparse path names resolve to their dense fallbacks.
-    path = resolve_path(get_path(path_name), servable)
+    # drops it), so sparse path names resolve to their dense fallbacks
+    # inside run_path.
+    path = get_path(path_name)
     mesh = smesh.mesh
-    if ingress is not None:
-        # The ingress must produce literals in the EVALUATED path's form
-        # (which can differ from the registered spec when the autotuner
-        # measures cross-form candidates on the raw form).
-        ingress = dataclasses.replace(ingress, packed=path.input_form == PACKED)
-        # Raw form: the ingress runs OUTSIDE the shard_map, once per
-        # batch shard under GSPMD (pinned to the "data" sharding) — not
-        # replicated across every model-axis device holding that shard.
-        # Only clause evaluation depends on the "model" axis.
-        arr = jax.lax.with_sharding_constraint(
-            path.ingress_fn(ingress, arr),
-            smesh.batch_sharding(3),           # literals [B, P, 2o|W]
+    if smesh.shard_clauses:
+        clause = partition.spec(("clause", None), mesh)
+        model_spec = dataclasses.replace(
+            servable,
+            include=clause,
+            include_packed=clause,
+            nonempty=partition.spec(("clause",), mesh),
+            weights=partition.spec((None, "clause"), mesh),
         )
-    clause = partition.spec(("clause", None), mesh)
+    else:
+        model_spec = P()
     batch = partition.spec(("batch",) + (None,) * (arr.ndim - 1), mesh)
 
-    def body(inc, incp, ne, w, x):
-        v = path.fn(x, inc, incp, ne, w)          # [B_local, m] partial sums
-        return psum_tree(v, "model")
+    def body(sv, x):
+        if ingress is None:
+            v = run_path(path, sv, x, params)
+        else:
+            # Raw form: the ingress runs on the device's batch shard, in
+            # the evaluated path's literal form (run_path_raw adapts it).
+            v = run_path_raw(path, sv, x, ingress, params)
+        if smesh.shard_clauses:
+            v = psum_tree(v, "model")          # [B_local, m] partials
+        return cl.argmax_predict(v), v
 
-    v = shard_map_compat()(
+    return shard_map(
         body,
         mesh=mesh,
-        in_specs=(
-            clause,                                # include [C, 2o]
-            clause,                                # include_packed [C, W]
-            partition.spec(("clause",), mesh),     # nonempty [C]
-            partition.spec((None, "clause"), mesh),  # weights [m, C]
-            batch,
+        in_specs=(model_spec, batch),
+        out_specs=(
+            partition.spec(("batch",), mesh),
+            partition.spec(("batch", None), mesh),
         ),
-        out_specs=partition.spec(("batch", None), mesh),
-    )(servable.include, servable.include_packed, servable.nonempty,
-      servable.weights, arr)
-    return cl.argmax_predict(v), v
+    )(servable, arr)
 
 
-#: The clause-sharded classify step: (placed servable, placed batch) ->
+#: The meshed classify step: (placed servable, placed batch) ->
 #: (predictions, class_sums), jit-cached per (bucket shape, model config,
-#: path, ServeMesh, IngressSpec) — ``ingress=None`` is the literal form,
-#: an IngressSpec the raw form (ingress once per batch shard under GSPMD
-#: outside the shard_map, then clause-shard evaluation + psum inside it).
-classify_step_clause_sharded = jax.jit(
-    _classify_clause_sharded, static_argnames=("smesh", "path_name", "ingress")
+#: path, ServeMesh, IngressSpec, params) — ``ingress=None`` is the
+#: literal form, an IngressSpec the raw form.
+classify_step_meshed = jax.jit(
+    _classify_meshed,
+    static_argnames=("smesh", "path_name", "ingress", "params"),
 )

@@ -95,8 +95,8 @@ RAW = "raw"
 _KERNEL_TUNABLE: Tuple[Params, ...] = (
     (),
     (("block_b", 16),),
-    (("block_p", 128),),
-    (("block_b", 16), ("block_p", 128)),
+    (("block_p", 256),),
+    (("block_b", 16), ("block_p", 256)),
     (("csrf", False),),
 )
 

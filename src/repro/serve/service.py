@@ -104,6 +104,7 @@ from repro.serve.faults import (
     PoisonedPayload,
     ServiceExpired,
     ServiceHealth,
+    StepCompileError,
     WorkerCrashed,
 )
 from repro.serve.scheduler import (
@@ -753,6 +754,16 @@ class ServingService:
                 if not r.payload.done():
                     r.payload.set_exception(err)
             await self._restart_worker(err)
+            return
+        except StepCompileError as e:
+            # The step did not compile: no fallback hides that (the breaker
+            # is for runtime faults).  The whole microbatch fails with it.
+            self._inflight.release()
+            self._health.dispatch_failures += 1
+            self._health.note_fault(e)
+            for r in batch:
+                if not r.payload.done():
+                    r.payload.set_exception(e)
             return
         except DeviceLost as e:
             # Simulated mesh-device loss: re-place every servable on a

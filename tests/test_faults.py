@@ -32,6 +32,7 @@ from repro.serve import (
     ServiceStopped,
     ServingEngine,
     ServingService,
+    StepCompileError,
     WorkerCrashed,
     chaos_soak,
     degraded_fallback,
@@ -427,6 +428,50 @@ class TestDegradation:
         want_preds, want_sums = _oracle_classify(ref, imgs)
         np.testing.assert_array_equal(res.predictions, want_preds)
         np.testing.assert_array_equal(res.class_sums, want_sums)
+
+    def test_compile_failure_raises_and_never_degrades(self, monkeypatch):
+        # A step that fails to trace/compile (a Pallas kernel Mosaic
+        # refuses, on a TPU) is a defect, not a runtime fault: warmup
+        # raises it, the service fails the batch with it, and the
+        # breaker walks no fallback chain around it.
+        from repro.serve import paths
+
+        def _uncompilable(lits, include, include_packed, nonempty, weights):
+            raise NotImplementedError("Unimplemented primitive in Mosaic")
+
+        name = "uncompilable_test_path"
+        monkeypatch.setitem(
+            paths._REGISTRY, name,
+            paths.EvalPath(name=name, input_form=paths.PACKED, fn=_uncompilable),
+        )
+        engine, _ = _pair(path=name)
+        with pytest.raises(StepCompileError, match="failed to compile"):
+            engine.warmup("glyphs", buckets=[1], forms=("raw",))
+        service = ServingService(
+            engine,
+            ServiceConfig(max_delay_us=100.0),
+            policy=DegradationPolicy(failure_threshold=1),
+        )
+
+        async def run():
+            await service.start()
+            errs = []
+            for _ in range(3):
+                try:
+                    await service.submit("glyphs", _images(1))
+                except StepCompileError as e:
+                    errs.append(e)
+            state = service.health().state
+            await service.stop(drain=True)
+            return errs, state
+
+        errs, state = asyncio.run(run())
+        assert len(errs) == 3 and errs[0].kind == "compile_error"
+        assert state == "healthy"
+        h = service.health()
+        assert h.fallback_path is None and h.dispatch_failures == 3
+        st = engine.stats("glyphs")
+        assert st.fallback_path is None and st.degrade_steps == 0
 
 
 # --------------------------------------------------------------------------

@@ -93,8 +93,14 @@ def check_donation(
 #: Primitive names that imply a host round trip inside the jitted step.
 #: ``device_put`` is NOT here: it appears benignly for weight constants
 #: staged into the trace and does not stall dispatch.
-_HOST_PRIM_EXACT = frozenset({"infeed", "outfeed", "outside_call"})
+_HOST_PRIM_EXACT = frozenset({"infeed", "outfeed", "outside_call", "debug_print"})
 _HOST_PRIM_SUBSTRING = "callback"
+#: Effects that only a host transfer carries (``jax.debug.print`` /
+#: ``debug.callback`` carry the debug effect, ``io_callback`` the IO
+#: one), matched by type name so a renamed primitive is still caught.
+_HOST_EFFECTS = frozenset(
+    {"DebugEffect", "OrderedDebugEffect", "IOEffect", "OrderedIOEffect"}
+)
 
 
 def iter_eqns(jaxpr) -> Iterator:
@@ -125,7 +131,11 @@ def forbidden_primitives(jaxpr) -> List[str]:
     bad = []
     for eqn in iter_eqns(jaxpr):
         name = eqn.primitive.name
-        if name in _HOST_PRIM_EXACT or _HOST_PRIM_SUBSTRING in name:
+        if (
+            name in _HOST_PRIM_EXACT
+            or _HOST_PRIM_SUBSTRING in name
+            or any(type(e).__name__ in _HOST_EFFECTS for e in eqn.effects)
+        ):
             bad.append(name)
     return bad
 
