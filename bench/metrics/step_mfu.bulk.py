@@ -1,0 +1,17 @@
+"""The whole window's share of the chips' int8 peak: the frames the step
+programs classified in the traced window, times the counted operations
+per frame, over the window's seconds, the chips and the peak."""
+
+from tracefile import STEP_MODULES, step_events
+
+
+def read(record):
+    if record["kind"] != "engine" or not record.get("trace"):
+        return None
+    n, _ = step_events(record, STEP_MODULES)
+    if not n:
+        return None
+    frames = n * record["frames_per_step_event"]
+    ops = frames * record["work"]["ops_per_frame"]
+    peak = record["chips"] * record["peaks"]["int8_ops_per_s"]
+    return 100.0 * ops / (record["trace"]["window_s"] * peak)
