@@ -140,9 +140,10 @@ def bench_ingress(
                     "us_per_call": round(us, 1),
                     "derived": (
                         f"{b / us * 1e6:,.0f} cls/s end-to-end raw ({mode} "
-                        f"ingress) | split so far: ingress "
-                        f"{st.mean_ingress_us:,.0f} us / device "
-                        f"{st.mean_device_us:,.0f} us per request"
+                        f"ingress) | p50 so far: dispatch "
+                        f"{st.dispatch.quantile(0.5):,.0f} / wait "
+                        f"{st.wait.quantile(0.5):,.0f} / fetch "
+                        f"{st.fetch.quantile(0.5):,.0f} us per request"
                     ),
                     "fields": {
                         "kind": "classify_raw",

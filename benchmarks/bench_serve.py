@@ -15,7 +15,8 @@ Two raw-request ingress modes are measured:
 
 Rows carry machine-readable ``fields`` for ``benchmarks/run.py
 --emit-json`` (-> ``BENCH_serve.json``); per-request latency is split
-into ingress vs device components (EXPERIMENTS.md §Ingress).  Every
+into the engine's ingress, dispatch, wait and fetch stages
+(``ClassifyResult``; EXPERIMENTS.md §Ingress).  Every
 ``serve_engine`` row also carries the analytic roofline columns from
 ``roofline.analysis.tm_path_roofline`` — the v5e ceiling for the path
 that actually ran (``resolved_path``: the autotuned winner, or a sparse
@@ -29,7 +30,7 @@ per-bucket autotuner so rows report the tuned winner per (form, bucket).
 ``bench_sparsity_sweep`` measures the sparse-vs-dense crossover: for a
 range of active-clause fractions (empty clauses forced by zeroing TA
 rows — no include => empty, Sec. IV-D) it times each dense path against
-its sparse twin and reports the device-side speedup per fraction
+its sparse twin and reports the step's speedup per fraction
 (EXPERIMENTS.md §Sparsity).
 
 ``bench_serve_mesh`` adds per-device-count rows (the ``serve_mesh``
@@ -125,7 +126,7 @@ def bench_serve(
     autotune: bool = False,
 ) -> List[Dict]:
     """One CSV row per (path, ingress mode, batch bucket): us/request +
-    classifications/s + the ingress/device latency split + the roofline
+    classifications/s + the ingress/dispatch/wait/fetch split + the roofline
     ceiling/fraction for the path that actually ran."""
     rows = []
     for p in paths if paths is not None else (path,):
@@ -155,12 +156,14 @@ def _bench_serve_one(
             # this shape; the jitted classify step itself was compiled by
             # engine.warmup above.
             engine.classify("mnist", imgs, ingress=mode)
-            t = t_in = t_dev = 0.0
+            t = t_in = t_disp = t_wait = t_fetch = 0.0
             for _ in range(n_requests):
                 res = engine.classify("mnist", imgs, ingress=mode)
                 t += res.latency_s
                 t_in += res.ingress_s
-                t_dev += res.device_s
+                t_disp += res.dispatch_s
+                t_wait += res.wait_s
+                t_fetch += res.fetch_s
             n = n_requests * bucket
             rate = n / t
             us = t / n_requests * 1e6
@@ -178,8 +181,10 @@ def _bench_serve_one(
                         f"{rate:,.0f} class/s = {rate / PAPER_RATE:.3f}x ASIC "
                         f"({PAPER_RATE}/s); per-image {us / bucket:.1f} us "
                         f"vs chip {PAPER_LATENCY_US} us | split ingress "
-                        f"{t_in / n_requests * 1e6:,.0f} us / device "
-                        f"{t_dev / n_requests * 1e6:,.0f} us | "
+                        f"{t_in / n_requests * 1e6:,.0f} / dispatch "
+                        f"{t_disp / n_requests * 1e6:,.0f} / wait "
+                        f"{t_wait / n_requests * 1e6:,.0f} / fetch "
+                        f"{t_fetch / n_requests * 1e6:,.0f} us | "
                         f"ran {rl['resolved_path']} at "
                         f"{rl['roofline_fraction']:.1e} of "
                         f"{rl['roofline_bound']}-bound ceiling"
@@ -193,7 +198,9 @@ def _bench_serve_one(
                         "cls_per_s": rate,
                         "x_asic": rate / PAPER_RATE,
                         "ingress_us": t_in / n_requests * 1e6,
-                        "device_us": t_dev / n_requests * 1e6,
+                        "dispatch_us": t_disp / n_requests * 1e6,
+                        "wait_us": t_wait / n_requests * 1e6,
+                        "fetch_us": t_fetch / n_requests * 1e6,
                         "autotuned": autotune,
                         **rl,
                     },
@@ -267,9 +274,9 @@ def bench_sparsity_sweep(
 ) -> List[Dict]:
     """Sparse-vs-dense crossover: per active-clause fraction, time each
     dense path against its sparse twin on the same model and report the
-    device-side speedup.  The crossover point (where the sparse win
-    exceeds its gather overhead) is what the autotuner discovers
-    empirically per (bucket, geometry)."""
+    speedup of the step (launch, wait and copy back).  The crossover
+    point (where the sparse win exceeds its gather overhead) is what the
+    autotuner discovers empirically per (bucket, geometry)."""
     cfg = _config(tiny)
     side = cfg.patch.image_y
     rng = np.random.default_rng(0)
@@ -277,32 +284,34 @@ def bench_sparsity_sweep(
     for fraction in active_fractions:
         model, n_active = _model_with_active_fraction(cfg, fraction)
         imgs = rng.integers(0, 256, (bucket, side, side)).astype(np.uint8)
-        dense_dev_us: Dict[str, float] = {}
+        dense_step_us: Dict[str, float] = {}
         for dense_name, sparse_name in pairs:
             for p in (dense_name, sparse_name):
                 engine, _ = _engine(p, max_batch=bucket, tiny=tiny, model=model)
                 engine.warmup("mnist", buckets=(bucket,), forms=("raw",))
                 engine.classify("mnist", imgs)      # host-cache warmup
-                t = t_dev = 0.0
+                t = t_step = 0.0
                 for _ in range(n_requests):
                     res = engine.classify("mnist", imgs)
                     t += res.latency_s
-                    t_dev += res.device_s
+                    # Everything after validation: the step's launch,
+                    # the wait on it and the copy back.
+                    t_step += res.latency_s - res.ingress_s
                 rate = n_requests * bucket / t
-                dev_us = t_dev / n_requests * 1e6
+                step_us = t_step / n_requests * 1e6
                 if p == dense_name:
-                    dense_dev_us[dense_name] = dev_us
+                    dense_step_us[dense_name] = step_us
                 speedup = (
-                    dense_dev_us[dense_name] / dev_us if p == sparse_name else 1.0
+                    dense_step_us[dense_name] / step_us if p == sparse_name else 1.0
                 )
                 rl = _roofline_fields(engine, cfg, "raw", bucket)
                 rows.append(
                     {
                         "name": f"sparsity_{p}_a{fraction:g}_b{bucket}",
-                        "us_per_call": round(dev_us, 1),
+                        "us_per_call": round(step_us, 1),
                         "derived": (
                             f"{n_active} active clauses ({fraction:.0%}): "
-                            f"{rate:,.0f} class/s, device {dev_us:,.0f} us"
+                            f"{rate:,.0f} class/s, step {step_us:,.0f} us"
                             + (
                                 f" = {speedup:.2f}x vs {dense_name}"
                                 if p == sparse_name
@@ -317,7 +326,7 @@ def bench_sparsity_sweep(
                             "n_active": n_active,
                             "bucket": bucket,
                             "cls_per_s": rate,
-                            "device_us": dev_us,
+                            "step_us": step_us,
                             "speedup_vs_dense": speedup,
                             **rl,
                         },

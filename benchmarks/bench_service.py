@@ -132,8 +132,8 @@ async def run_load(
         "p99_us": st.p99_latency_us,
         "mean_occupancy": st.mean_occupancy,
         "batches": st.batches,
-        "ingress_us_per_image": st.ingress_us_per_image,
-        "device_us_per_image": st.device_us_per_image,
+        "queue_p50_us": st.queue.quantile(0.5),
+        "dispatch_p50_us": st.dispatch.quantile(0.5),
     }
 
 
@@ -150,8 +150,8 @@ def _row(name: str, r: Dict, derived: str, **fields) -> Dict:
             "mean_occupancy": r["mean_occupancy"],
             "rejected": r["rejected"],
             "expired": r.get("expired", 0),
-            "ingress_us_per_image": r["ingress_us_per_image"],
-            "device_us_per_image": r["device_us_per_image"],
+            "queue_p50_us": r["queue_p50_us"],
+            "dispatch_p50_us": r["dispatch_p50_us"],
             **fields,
         },
     }
@@ -221,8 +221,8 @@ def bench_service(
                     f"{r['achieved_per_s']:,.0f}/s "
                     f"({r['achieved_per_s'] / PAPER_RATE:.3f}x ASIC) | "
                     f"p50 {r['p50_us']:,.0f} us p99 {r['p99_us']:,.0f} us | "
-                    f"split ingress {r['ingress_us_per_image']:,.0f} / device "
-                    f"{r['device_us_per_image']:,.0f} us/img"
+                    f"p50 queue {r['queue_p50_us']:,.0f} / dispatch "
+                    f"{r['dispatch_p50_us']:,.0f} us"
                 ),
                 kind="raw_ingress", ingress=mode, rate=rate, path=path,
             ))

@@ -4,8 +4,8 @@ derived`` CSV rows per the repo contract, then the table reproductions.
 
 ``--emit-json DIR`` instead runs the serving/ingress regression harness
 and writes machine-readable ``BENCH_serve.json`` and
-``BENCH_ingress.json`` (cls/s per path and bucket, ingress vs device
-latency split) so the perf trajectory is comparable across PRs; CI
+``BENCH_ingress.json`` (cls/s per path and bucket, the ingress,
+dispatch, wait and fetch latency split) so the perf trajectory is comparable across PRs; CI
 smoke-runs it at ``--tiny`` geometry and uploads the artifact.
 
 Run:  PYTHONPATH=src python -m benchmarks.run [--quick]

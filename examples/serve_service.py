@@ -104,8 +104,10 @@ async def main():
             f"{name}: {st.completed} requests / {st.images} images in "
             f"{st.batches} microbatches | occupancy {st.mean_occupancy:.2f} | "
             f"p50 {st.p50_latency_us:,.0f} us p99 {st.p99_latency_us:,.0f} us | "
-            f"split ingress {st.ingress_us_per_image:,.0f} / device "
-            f"{st.device_us_per_image:,.0f} us/img (raw pixels ride the "
+            f"p50 queue {st.queue.quantile(0.5):,.0f} / slot "
+            f"{st.slot.quantile(0.5):,.0f} / dispatch "
+            f"{st.dispatch.quantile(0.5):,.0f} / complete "
+            f"{st.complete.quantile(0.5):,.0f} us (raw pixels ride the "
             f"fused device-ingress graph)"
         )
     await service.stop(drain=True)

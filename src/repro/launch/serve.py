@@ -216,9 +216,9 @@ def serve_tm(
     print(
         f"{arch}: {st.images} images in {st.requests} requests | "
         f"{st.classifications_per_s:,.0f} classifications/s | "
-        f"mean latency {st.mean_latency_us:,.0f} us "
-        f"(ingress {st.mean_ingress_us:,.0f} + device "
-        f"{st.mean_device_us:,.0f}) | "
+        f"mean latency {st.mean_latency_us:,.0f} us | p50 dispatch "
+        f"{st.dispatch.quantile(0.5):,.0f} / wait {st.wait.quantile(0.5):,.0f} / "
+        f"fetch {st.fetch.quantile(0.5):,.0f} us | "
         f"buckets compiled {sorted(st.compiled_buckets)} "
         f"hits {dict(sorted(st.bucket_hits.items()))}"
     )
@@ -262,7 +262,7 @@ async def serve_tm_service(
         ingress (the pre-device-ingress baseline).
 
     Prints the per-model ServiceStats snapshot (p50/p99 latency,
-    ingress/device split, batch-occupancy histogram, rejections).
+    stage p50s, batch-occupancy histogram, rejections).
 
     The adversarial knobs (ARCHITECTURE.md §Faults) ride the same load:
     ``deadline_s`` stamps every request (past it, requests shed with
@@ -321,8 +321,9 @@ async def serve_tm_service(
         f"{arch}: offered {offered:,.0f} req/s | completed {st.completed} "
         f"({st.completed / wall:,.0f}/s), rejected {rejected} | "
         f"p50 {st.p50_latency_us:,.0f} us p99 {st.p99_latency_us:,.0f} us | "
-        f"split ingress {st.ingress_us_per_image:,.0f} / device "
-        f"{st.device_us_per_image:,.0f} us/img | "
+        f"p50 queue {st.queue.quantile(0.5):,.0f} / slot "
+        f"{st.slot.quantile(0.5):,.0f} / dispatch {st.dispatch.quantile(0.5):,.0f} / "
+        f"complete {st.complete.quantile(0.5):,.0f} us | "
         f"mean occupancy {st.mean_occupancy:.2f} | "
         f"occupancy hist {st.occupancy_hist}"
     )

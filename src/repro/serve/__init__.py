@@ -11,7 +11,7 @@
 ``engine``    — :class:`ServingEngine`, batched multi-dataset serving with
                 power-of-two batch bucketing, the fused device-resident
                 raw classify step, async dispatch handles and
-                ingress/device latency accounting (the synchronous
+                per-stage latency histograms (the synchronous
                 library layer).
 ``scheduler`` — :class:`MicrobatchScheduler`, the latency-aware
                 microbatching policy (per-model queues, round-robin,
@@ -32,6 +32,9 @@
                 :class:`ServiceHealth` state, the structured fault errors
                 every request future resolves with, and the chaos-soak
                 driver (ARCHITECTURE.md §Faults).
+``telemetry`` — ``span`` (profiler host spans) and :class:`Histogram`
+                (stage durations) of the serving path
+                (ARCHITECTURE.md §Telemetry).
 """
 
 from repro.serve.autotune import AutotuneReport, TunedPlan, autotune_servable

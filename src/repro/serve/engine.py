@@ -40,11 +40,16 @@ the device crunches batch k while the caller pads/dispatches batch k+1
 (the ``ServingService`` worker does exactly this).  ``classify`` is
 ``dispatch(...).result()``.
 
-Per-request latency is split into ``ingress`` (host-side preprocessing /
-validation) and ``device`` (dispatch -> results ready) components so the
-bottleneck is visible per model; throughput is compared against the
-paper's 60.3k classifications/s (measured numbers in EXPERIMENTS.md
-§Serve and §Ingress).
+Per-request latency is split at the host's own boundaries: ``ingress``
+(validation, or the host pipeline on ``ingress='host'``), ``dispatch``
+(padding, H2D put and the jitted step's launch over every chunk),
+``wait`` (``block_until_ready``) and ``fetch`` (the D2H copies, slicing
+and concatenation).  :class:`ServeStats` keeps a histogram of each of
+the last three per model, and the same stages open profiler spans
+(``serve.engine.*``, ARCHITECTURE.md §Telemetry), so a trace shows which
+one the device waited on.  Throughput is compared against the paper's
+60.3k classifications/s (measured numbers in EXPERIMENTS.md §Serve and
+§Ingress).
 
 Multi-device serving
 --------------------
@@ -89,6 +94,7 @@ from repro.serve.servable import (
     freeze,
     servable_digest,
 )
+from repro.serve.telemetry import Histogram, span
 
 __all__ = [
     "ClassifyResult",
@@ -107,10 +113,12 @@ class ClassifyResult:
 
     predictions: np.ndarray   # int32 [n]
     class_sums: np.ndarray    # int32 [n, m]
-    latency_s: float          # wall clock incl. ingress
+    latency_s: float          # wall clock, dispatch() entry -> result in hand
     bucket: int               # largest padded batch size executed
-    ingress_s: float = 0.0    # host-side ingress / validation share
-    device_s: float = 0.0     # dispatch -> device results ready share
+    ingress_s: float = 0.0    # validation, or the host pipeline (ingress='host')
+    dispatch_s: float = 0.0   # padding, H2D put and launch over every chunk
+    wait_s: float = 0.0       # block_until_ready on the device results
+    fetch_s: float = 0.0      # D2H copies, slicing and concatenation
     version: int = 0          # monotonic id of the version that computed it
 
 
@@ -121,13 +129,20 @@ class ServeStats:
     ``devices`` is the mesh size the model serves on (1 unmeshed);
     buckets are *global* batch sizes — on a mesh each device executes
     ``bucket // data_shards`` rows (:attr:`per_device_bucket_hits`).
+
+    ``dispatch`` is recorded once per ``dispatch()`` call (validation
+    excluded), ``wait`` and ``fetch`` once per ``result()`` call, each on
+    the thread that ran that stage; ``compiles`` counts first dispatches
+    of a (form, bucket) on the installed image.
     """
 
     requests: int = 0
     images: int = 0
     total_latency_s: float = 0.0
-    ingress_s: float = 0.0            # host ingress share of the latency
-    device_s: float = 0.0             # device share of the latency
+    dispatch: Histogram = dataclasses.field(default_factory=Histogram)
+    wait: Histogram = dataclasses.field(default_factory=Histogram)
+    fetch: Histogram = dataclasses.field(default_factory=Histogram)
+    compiles: int = 0
     bucket_hits: Dict[int, int] = dataclasses.field(default_factory=dict)
     compiled_buckets: Tuple[int, ...] = ()
     devices: int = 1                  # mesh size (1 = unmeshed)
@@ -150,17 +165,20 @@ class ServeStats:
         return self.total_latency_s / self.requests * 1e6 if self.requests else 0.0
 
     @property
-    def mean_ingress_us(self) -> float:
-        return self.ingress_s / self.requests * 1e6 if self.requests else 0.0
-
-    @property
-    def mean_device_us(self) -> float:
-        return self.device_s / self.requests * 1e6 if self.requests else 0.0
-
-    @property
     def per_device_bucket_hits(self) -> Dict[int, int]:
         """Bucket hits keyed by the rows each device actually executed."""
         return {b // self.data_shards: h for b, h in self.bucket_hits.items()}
+
+    def snapshot(self) -> "ServeStats":
+        """A copy that shares no mutable state with the live counters."""
+        return dataclasses.replace(
+            self,
+            dispatch=self.dispatch.copy(),
+            wait=self.wait.copy(),
+            fetch=self.fetch.copy(),
+            bucket_hits=dict(self.bucket_hits),
+            autotune=dict(self.autotune),
+        )
 
     def as_dict(self) -> Dict:
         return {
@@ -168,8 +186,10 @@ class ServeStats:
             "images": self.images,
             "classifications_per_s": self.classifications_per_s,
             "mean_latency_us": self.mean_latency_us,
-            "mean_ingress_us": self.mean_ingress_us,
-            "mean_device_us": self.mean_device_us,
+            "dispatch": self.dispatch.summary(),
+            "wait": self.wait.summary(),
+            "fetch": self.fetch.summary(),
+            "compiles": self.compiles,
             "bucket_hits": dict(self.bucket_hits),
             "compiled_buckets": list(self.compiled_buckets),
             "devices": self.devices,
@@ -288,7 +308,8 @@ class InFlightClassify:
 
     ``result()`` blocks until the device arrays are ready, slices off the
     bucket padding, records the request's stats and returns the
-    :class:`ClassifyResult`; it is idempotent.
+    :class:`ClassifyResult`; it is idempotent.  The ``wait`` and ``fetch``
+    stages are timed (and spanned) on the thread that calls it.
     """
 
     def __init__(
@@ -296,15 +317,14 @@ class InFlightClassify:
         entry: _Entry,
         parts,
         n: int,
-        t0: float,
-        t_dispatch: float,
+        marks: Tuple[float, float, float],
         version: int = 0,
     ):
         self._entry = entry
         self._parts = parts            # [(preds, sums, n_i, bucket)], lazy
         self._n = n
-        self._t0 = t0
-        self._t_dispatch = t_dispatch  # ingress done / device dispatch start
+        # perf_counter at dispatch() entry, after validation, after launch.
+        self._marks = marks
         # Version id captured atomically at dispatch: a swap after this
         # point cannot retroactively change which weights computed us.
         self.version = version
@@ -313,25 +333,30 @@ class InFlightClassify:
     def result(self) -> ClassifyResult:
         if self._result is not None:
             return self._result
-        jax.block_until_ready([(p, s) for p, s, _, _ in self._parts])
-        t2 = time.perf_counter()
-        preds = np.concatenate([np.asarray(p)[:ni] for p, _, ni, _ in self._parts])
-        sums = np.concatenate([np.asarray(s)[:ni] for _, s, ni, _ in self._parts])
-        ingress_s = self._t_dispatch - self._t0
-        device_s = t2 - self._t_dispatch
+        t_wait = time.perf_counter()
+        with span("serve.engine.wait"):
+            jax.block_until_ready([(p, s) for p, s, _, _ in self._parts])
+        t_fetch = time.perf_counter()
+        with span("serve.engine.fetch"):
+            preds = np.concatenate([np.asarray(p)[:ni] for p, _, ni, _ in self._parts])
+            sums = np.concatenate([np.asarray(s)[:ni] for _, s, ni, _ in self._parts])
+        t_end = time.perf_counter()
+        t0, t_ingress, t_launched = self._marks
         st = self._entry.stats
         st.requests += 1
         st.images += self._n
-        st.total_latency_s += t2 - self._t0
-        st.ingress_s += ingress_s
-        st.device_s += device_s
+        st.total_latency_s += t_end - t0
+        st.wait.record((t_fetch - t_wait) * 1e6)
+        st.fetch.record((t_end - t_fetch) * 1e6)
         self._result = ClassifyResult(
             predictions=preds,
             class_sums=sums,
-            latency_s=t2 - self._t0,
+            latency_s=t_end - t0,
             bucket=max(b for _, _, _, b in self._parts),
-            ingress_s=ingress_s,
-            device_s=device_s,
+            ingress_s=t_ingress - t0,
+            dispatch_s=t_launched - t_ingress,
+            wait_s=t_fetch - t_wait,
+            fetch_s=t_end - t_fetch,
             version=self.version,
         )
         return self._result
@@ -566,7 +591,8 @@ class ServingEngine:
         return tuple(sorted(self._servables))
 
     def stats(self, name: str) -> ServeStats:
-        return self._servables[name].stats
+        """A snapshot of one model's stats (no alias of the live counters)."""
+        return self._servables[name].stats.snapshot()
 
     def ingress_spec(self, name: str) -> IngressSpec:
         """The registered model's raw-form ingress description."""
@@ -916,14 +942,16 @@ class ServingEngine:
     def _submit_bucket(
         self, entry: _Entry, arr: np.ndarray, form: str, record_hit: bool = True
     ):
-        """Pad one <= max_batch chunk to its bucket and dispatch the jitted
-        step WITHOUT blocking; returns ``(preds, sums, n, bucket)`` with
-        lazy device arrays.  Records bucket hit/compile accounting."""
+        """Pad one <= max_batch chunk to its bucket, put it on the device(s)
+        and launch the jitted step WITHOUT blocking; returns ``(preds,
+        sums, n, bucket)`` with lazy device arrays.  Records bucket
+        hit/compile accounting."""
         n = arr.shape[0]
         bucket = self.bucket_for(n)
-        if bucket != n:
-            pad = np.zeros((bucket - n,) + arr.shape[1:], arr.dtype)
-            arr = np.concatenate([arr, pad], axis=0)
+        with span("serve.engine.pad"):
+            if bucket != n:
+                pad = np.zeros((bucket - n,) + arr.shape[1:], arr.dtype)
+                arr = np.concatenate([arr, pad], axis=0)
         # The autotuned winner for this (form, bucket), or the registered
         # path at defaults.  Literal-form winners share the registered
         # path's input form (autotune admissibility), so ``arr`` is
@@ -934,25 +962,33 @@ class ServingEngine:
         # kernel Mosaic refuses), raised as such and never degraded around.
         fresh = (form, bucket) not in entry.compiled
         try:
-            if self.mesh is not None:
-                # One placed (data-sharded) buffer; the per-shard program
-                # runs across the mesh and nothing gathers until .result()
-                # reads the global output.
-                preds, sums = classify_step_meshed(
-                    entry.servable, self.mesh.place_batch(arr),
-                    smesh=self.mesh,
-                    path_name=path_name,
-                    ingress=entry.ingress if form == "raw" else None,
-                    params=params,
-                )
-            elif form == "raw":
-                preds, sums = classify_raw_step(
-                    entry.servable, jnp.asarray(arr), path_name, entry.ingress, params
-                )
-            else:
-                preds, sums = classify_step(
-                    entry.servable, jnp.asarray(arr), path_name, params=params
-                )
+            with span("serve.engine.put"):
+                # One H2D copy; on a mesh one placed (data-sharded) buffer,
+                # and nothing gathers until .result() reads the output.
+                if self.mesh is not None:
+                    x = self.mesh.place_batch(arr)
+                else:
+                    x = jax.device_put(arr)
+            with span(
+                "serve.engine.compile" if fresh else "serve.engine.launch",
+                bucket=bucket, form=form,
+            ):
+                if self.mesh is not None:
+                    preds, sums = classify_step_meshed(
+                        entry.servable, x,
+                        smesh=self.mesh,
+                        path_name=path_name,
+                        ingress=entry.ingress if form == "raw" else None,
+                        params=params,
+                    )
+                elif form == "raw":
+                    preds, sums = classify_raw_step(
+                        entry.servable, x, path_name, entry.ingress, params
+                    )
+                else:
+                    preds, sums = classify_step(
+                        entry.servable, x, path_name, params=params
+                    )
         except Exception as e:
             if not fresh:
                 raise
@@ -963,7 +999,9 @@ class ServingEngine:
         st = entry.stats
         if record_hit:
             st.bucket_hits[bucket] = st.bucket_hits.get(bucket, 0) + 1
-        entry.compiled.add((form, bucket))
+        if fresh:
+            st.compiles += 1
+            entry.compiled.add((form, bucket))
         if bucket not in st.compiled_buckets:
             st.compiled_buckets = st.compiled_buckets + (bucket,)
         return preds, sums, n, bucket
@@ -1096,7 +1134,9 @@ class ServingEngine:
                 self._submit_bucket(entry, arr[i : i + self.max_batch], form)
                 for i in range(0, n, self.max_batch)
             ]
-        return InFlightClassify(entry, parts, n, t0, t1, version=ver)
+        t2 = time.perf_counter()
+        entry.stats.dispatch.record((t2 - t1) * 1e6)
+        return InFlightClassify(entry, parts, n, (t0, t1, t2), version=ver)
 
     def classify(
         self,

@@ -17,8 +17,8 @@ figure includes the DMA/frame system overhead, not just the datapath:
     servables;
   * graceful drain (``stop(drain=True)`` flushes every queued request
     before shutdown) and per-model :class:`ServiceStats` snapshots
-    (queue depth, batch-occupancy histogram, p50/p99 latency, and the
-    ingress vs device latency split).
+    (queue depth, batch-occupancy histogram, p50/p99 latency, and a
+    histogram of each stage of a request's life, below).
 
 Raw-pixel fast path
 -------------------
@@ -42,6 +42,22 @@ overlap this way — the asyncio analogue of the ASIC's double-buffered
 image registers (frame k classifies while frame k+1 streams in), now
 actually overlapping device compute with coalescing AND with the next
 batch's dispatch.
+
+Stages and spans
+----------------
+A request's latency (admission -> results in hand on the loop) is the
+sum of four stages, timed on the loop clock at shared boundaries:
+``queue`` (admission -> popped into a microbatch), ``slot`` (popped ->
+in-flight slot acquired), ``dispatch`` (the round trip to the dispatch
+worker) and ``complete`` (the worker's return -> results in hand: task
+scheduling plus the completion worker's round trip); ``resolve`` (results
+in hand -> the last member's future resolved) follows it.  Each
+:class:`ServiceResult` carries its own four, and :class:`ServiceStats`
+keeps a histogram of each (``queue`` and ``latency`` per request, the
+others per microbatch weighted by its members).  The dispatch worker,
+the completion worker and the loop open the profiler spans
+``serve.dispatch``, ``serve.complete`` and ``serve.resolve`` once per
+microbatch (ARCHITECTURE.md §Telemetry).
 
 Results are **bit-identical** to direct ``engine.classify`` calls no
 matter how requests were coalesced: every form runs the engine's own
@@ -89,7 +105,6 @@ Typical lifecycle::
 from __future__ import annotations
 
 import asyncio
-import collections
 import dataclasses
 import functools
 from concurrent.futures import BrokenExecutor, ThreadPoolExecutor
@@ -113,6 +128,7 @@ from repro.serve.scheduler import (
     QueueFull,
     SchedulerConfig,
 )
+from repro.serve.telemetry import Histogram, span
 
 __all__ = [
     "ServiceConfig",
@@ -137,15 +153,12 @@ class ServiceConfig:
                         largest bucket — used as-is).
     ``max_inflight``  — microbatches allowed between dispatch and device
                         completion (2 = double buffering).
-    ``latency_window``— per-model ring buffer of request latencies the
-                        p50/p99 snapshot is computed over.
     """
 
     max_delay_us: float = 200.0
     high_water: int = 4096
     max_coalesce: Optional[int] = None
     max_inflight: int = 2
-    latency_window: int = 8192
 
     def __post_init__(self):
         # max_delay_us / high_water are re-validated by SchedulerConfig.
@@ -153,8 +166,6 @@ class ServiceConfig:
             raise ValueError("max_coalesce must be >= 1 (or None)")
         if self.max_inflight < 1:
             raise ValueError("max_inflight must be >= 1")
-        if self.latency_window < 1:
-            raise ValueError("latency_window must be >= 1")
 
 
 class ServiceOverloaded(Exception):
@@ -184,6 +195,9 @@ class ServiceResult:
     microbatch it rode in — all members of one microbatch share a
     ``batch_id`` and, by the scheduler's version-boundary rule plus the
     dispatch-time swap guard, a single ``version``.
+
+    ``queue_s + slot_s + dispatch_s + complete_s == latency_s``: the
+    stages of the module docstring, on the loop clock.
     """
 
     predictions: np.ndarray   # int32 [n]
@@ -194,11 +208,28 @@ class ServiceResult:
     batch_images: int         # images in that microbatch
     version: int = 0          # model version id that computed it
     batch_id: int = 0         # service-wide microbatch sequence number
+    queue_s: float = 0.0      # admission -> popped into the microbatch
+    slot_s: float = 0.0       # popped -> in-flight slot acquired
+    dispatch_s: float = 0.0   # round trip to the dispatch worker
+    complete_s: float = 0.0   # dispatch worker's return -> results in hand
+
+
+#: The histograms of a request's life (module docstring), in order.
+STAGES = ("queue", "slot", "dispatch", "complete", "resolve", "latency")
+
+
+def _histogram():
+    return dataclasses.field(default_factory=Histogram)
 
 
 @dataclasses.dataclass
 class ServiceStats:
-    """Per-model service-level snapshot (engine stats stay separate)."""
+    """Per-model service-level snapshot (engine stats stay separate).
+
+    ``p50_latency_us`` / ``p99_latency_us`` are nearest-rank quantiles of
+    ``latency`` (admission -> result, every request over the service's
+    life), read as bucket upper edges; the stage histograms are copies.
+    """
 
     submitted: int = 0        # admission attempts (includes rejected)
     rejected: int = 0
@@ -216,16 +247,24 @@ class ServiceStats:
     mean_occupancy: float = 0.0
     p50_latency_us: float = 0.0
     p99_latency_us: float = 0.0
-    # Where microbatch time goes, per image: host-side ingress/validation
-    # vs device execution (the serving bottleneck, made visible).
-    ingress_us_per_image: float = 0.0
-    device_us_per_image: float = 0.0
+    queue: Histogram = _histogram()
+    slot: Histogram = _histogram()
+    dispatch: Histogram = _histogram()
+    complete: Histogram = _histogram()
+    resolve: Histogram = _histogram()
+    latency: Histogram = _histogram()
     # Service-wide ServiceHealth snapshot (serve/faults.py): state,
     # last fault, fallback path, restart/fault counters.
     health: Dict = dataclasses.field(default_factory=dict)
 
     def as_dict(self) -> Dict:
-        return dataclasses.asdict(self)
+        out = {
+            f.name: getattr(self, f.name)
+            for f in dataclasses.fields(self)
+            if f.name not in STAGES
+        }
+        out.update((k, getattr(self, k).summary()) for k in STAGES)
+        return out
 
 
 @dataclasses.dataclass
@@ -240,12 +279,16 @@ class _ModelStats:
     expired: int = 0
     quarantined: int = 0
     busy_s: float = 0.0
-    ingress_s: float = 0.0
-    device_s: float = 0.0
     occupancy_hist: Dict[int, Dict[str, int]] = dataclasses.field(
         default_factory=dict
     )
-    latencies: Optional[object] = None   # collections.deque, set on init
+    # Written on the event loop only (one writer per histogram).
+    queue: Histogram = _histogram()
+    slot: Histogram = _histogram()
+    dispatch: Histogram = _histogram()
+    complete: Histogram = _histogram()
+    resolve: Histogram = _histogram()
+    latency: Histogram = _histogram()
 
 
 class ServingService:
@@ -556,7 +599,6 @@ class ServingService:
         if name not in self._mstats:
             self.engine.servable(name)   # KeyError on unknown models
         ms = self._model_stats(name)
-        lat = np.asarray(ms.latencies, np.float64) if ms.latencies else None
         occ_w = sum(
             h["batches"] * b for b, h in ms.occupancy_hist.items()
         )
@@ -573,19 +615,10 @@ class ServingService:
                 b: dict(h) for b, h in sorted(ms.occupancy_hist.items())
             },
             mean_occupancy=ms.images / occ_w if occ_w else 0.0,
-            p50_latency_us=(
-                float(np.percentile(lat, 50) * 1e6) if lat is not None else 0.0
-            ),
-            p99_latency_us=(
-                float(np.percentile(lat, 99) * 1e6) if lat is not None else 0.0
-            ),
-            ingress_us_per_image=(
-                ms.ingress_s / ms.images * 1e6 if ms.images else 0.0
-            ),
-            device_us_per_image=(
-                ms.device_s / ms.images * 1e6 if ms.images else 0.0
-            ),
+            p50_latency_us=ms.latency.quantile(0.50),
+            p99_latency_us=ms.latency.quantile(0.99),
             health=self._health.as_dict(),
+            **{k: getattr(ms, k).copy() for k in STAGES},
         )
 
     def health(self) -> ServiceHealth:
@@ -596,9 +629,7 @@ class ServingService:
     def _model_stats(self, name: str) -> _ModelStats:
         ms = self._mstats.get(name)
         if ms is None:
-            ms = _ModelStats(
-                latencies=collections.deque(maxlen=self.config.latency_window)
-            )
+            ms = _ModelStats()
             self._mstats[name] = ms
         return ms
 
@@ -641,7 +672,7 @@ class ServingService:
                     await self._wait_arrival(max(deadline - now, 0.0))
                 continue
             batch = self._sched.pop_batch(model)
-            await self._execute(loop, model, batch)
+            await self._execute(loop, model, batch, loop.time())
 
     # --- request lifetime (ARCHITECTURE.md §Faults) -----------------------
 
@@ -684,7 +715,7 @@ class ServingService:
         return list(merged.items())
 
     async def _execute(
-        self, loop, model: str, batch: List[PendingRequest]
+        self, loop, model: str, batch: List[PendingRequest], t_pop: float
     ) -> None:
         """Dispatch one coalesced microbatch (pad + submit, no device
         wait) on the dispatch thread, then hand completion to the
@@ -709,6 +740,7 @@ class ServingService:
             return
         batch = live
         await self._inflight.acquire()
+        t_slot = loop.time()
         groups = self._form_groups(batch)
         self._batch_seq += 1
         batch_id = self._batch_seq
@@ -724,7 +756,7 @@ class ServingService:
             # One version across ALL form groups of this microbatch: the
             # guard (the engine lock) pins the entry so a concurrent swap
             # lands strictly before or strictly after the whole batch.
-            with self.engine.swap_guard():
+            with span("serve.dispatch"), self.engine.swap_guard():
                 for preprocessed, reqs in groups:
                     if len(reqs) == 1:
                         arr = reqs[0].literals
@@ -737,7 +769,6 @@ class ServingService:
                     )
             return out
 
-        t0 = loop.time()
         try:
             inflights = await loop.run_in_executor(self._executor, _dispatch)
         except (WorkerCrashed, BrokenExecutor) as e:
@@ -773,7 +804,7 @@ class ServingService:
             self._health.device_losses += 1
             self._health.degrade(e)
             await asyncio.to_thread(self.engine.shrink_mesh)
-            await self._dispatch_isolated(loop, model, batch)
+            await self._dispatch_isolated(loop, model, batch, t_pop)
             return
         except Exception as e:
             self._inflight.release()
@@ -789,18 +820,19 @@ class ServingService:
             # Quarantine: the failure could belong to ONE member of the
             # coalesced batch (poisoned/malformed input) — retry each
             # request alone so only the culprit fails.
-            await self._dispatch_isolated(loop, model, batch)
+            await self._dispatch_isolated(loop, model, batch, t_pop)
             return
+        marks = (t_pop, t_slot, loop.time())
         self._consec_failures.pop(model, None)
         task = loop.create_task(
-            self._complete(loop, model, batch, inflights, t0, batch_id),
+            self._complete(loop, model, batch, inflights, marks, batch_id),
             name=f"serve-complete-{model}",
         )
         self._completions.add(task)
         task.add_done_callback(self._completions.discard)
 
     async def _dispatch_isolated(
-        self, loop, model: str, batch: List[PendingRequest]
+        self, loop, model: str, batch: List[PendingRequest], t_pop: float
     ) -> None:
         """Dispatch each member of a failed microbatch alone.
 
@@ -811,7 +843,8 @@ class ServingService:
         ``on_service_dispatch`` counter — an injection plan is a script
         over the primary dispatch sequence, not a feedback loop over its
         own retries — but still honor payload poison (a property of the
-        request, not of the schedule).
+        request, not of the schedule).  A retried member's ``slot`` stage
+        runs from its microbatch's pop, so it holds the failed attempt.
         """
         for r in batch:
             if r.payload.done():
@@ -821,13 +854,14 @@ class ServingService:
                 self._fail_expired(r, now)
                 continue
             await self._inflight.acquire()
+            t_slot = loop.time()
             self._batch_seq += 1
             batch_id = self._batch_seq
 
             def _one(req=r):
                 if self._faults is not None:
                     self._faults.check_payload(req.literals, model)
-                with self.engine.swap_guard():
+                with span("serve.dispatch"), self.engine.swap_guard():
                     return [(
                         [req],
                         self.engine.dispatch(
@@ -835,7 +869,6 @@ class ServingService:
                         ),
                     )]
 
-            t0 = loop.time()
             try:
                 inflights = await loop.run_in_executor(self._executor, _one)
             except Exception as e:
@@ -847,8 +880,9 @@ class ServingService:
                 if not r.payload.done():
                     r.payload.set_exception(e)
                 continue
+            marks = (t_pop, t_slot, loop.time())
             task = loop.create_task(
-                self._complete(loop, model, [r], inflights, t0, batch_id),
+                self._complete(loop, model, [r], inflights, marks, batch_id),
                 name=f"serve-complete-{model}",
             )
             self._completions.add(task)
@@ -911,16 +945,19 @@ class ServingService:
         model: str,
         batch: List[PendingRequest],
         inflights: List[Tuple[List[PendingRequest], InFlightClassify]],
-        t0: float,
+        marks: Tuple[float, float, float],
         batch_id: int = 0,
     ) -> None:
         """Block on device results (completion thread) and slice them back
-        to the member requests."""
+        to the member requests.  ``marks``: loop time at the pop, at the
+        slot and at the dispatch worker's return."""
+
+        def collect():
+            with span("serve.complete"):
+                return [(reqs, h.result()) for reqs, h in inflights]
+
         try:
-            results = await loop.run_in_executor(
-                self._completer,
-                lambda: [(reqs, h.result()) for reqs, h in inflights],
-            )
+            results = await loop.run_in_executor(self._completer, collect)
         except Exception as e:
             self._health.note_fault(e)
             for r in batch:
@@ -930,40 +967,51 @@ class ServingService:
         finally:
             self._inflight.release()
         t1 = loop.time()
+        t_pop, t_slot, t_disp = marks
+        slot_s, dispatch_s, complete_s = t_slot - t_pop, t_disp - t_slot, t1 - t_disp
 
         n = sum(r.n for r in batch)
         ms = self._model_stats(model)
         ms.batches += 1
         ms.images += n
-        ms.busy_s += t1 - t0
-        for reqs, res in results:
-            ms.ingress_s += res.ingress_s
-            ms.device_s += res.device_s
-            ng = sum(r.n for r in reqs)
-            # Histogram by *engine slice*: a group larger than max_batch
-            # (one oversized request) executes as several buckets, and
-            # occupancy must stay a <= 1 fraction of each executed bucket.
-            for off in range(0, ng, self.engine.max_batch):
-                m = min(self.engine.max_batch, ng - off)
-                hist = ms.occupancy_hist.setdefault(
-                    self.engine.bucket_for(m), {"batches": 0, "images": 0}
-                )
-                hist["batches"] += 1
-                hist["images"] += m
-            off = 0
-            for r in reqs:
-                out = ServiceResult(
-                    predictions=res.predictions[off : off + r.n],
-                    class_sums=res.class_sums[off : off + r.n],
-                    latency_s=t1 - r.enqueue_t,
-                    bucket=res.bucket,
-                    batch_requests=len(batch),
-                    batch_images=n,
-                    version=res.version,
-                    batch_id=batch_id,
-                )
-                off += r.n
-                ms.completed += 1
-                ms.latencies.append(out.latency_s)
-                if not r.payload.done():
-                    r.payload.set_result(out)
+        ms.busy_s += t1 - t_slot
+        with span("serve.resolve"):
+            for reqs, res in results:
+                ng = sum(r.n for r in reqs)
+                # Histogram by *engine slice*: a group larger than max_batch
+                # (one oversized request) executes as several buckets, and
+                # occupancy must stay a <= 1 fraction of each executed bucket.
+                for off in range(0, ng, self.engine.max_batch):
+                    m = min(self.engine.max_batch, ng - off)
+                    hist = ms.occupancy_hist.setdefault(
+                        self.engine.bucket_for(m), {"batches": 0, "images": 0}
+                    )
+                    hist["batches"] += 1
+                    hist["images"] += m
+                off = 0
+                for r in reqs:
+                    out = ServiceResult(
+                        predictions=res.predictions[off : off + r.n],
+                        class_sums=res.class_sums[off : off + r.n],
+                        latency_s=t1 - r.enqueue_t,
+                        bucket=res.bucket,
+                        batch_requests=len(batch),
+                        batch_images=n,
+                        version=res.version,
+                        batch_id=batch_id,
+                        queue_s=t_pop - r.enqueue_t,
+                        slot_s=slot_s,
+                        dispatch_s=dispatch_s,
+                        complete_s=complete_s,
+                    )
+                    off += r.n
+                    ms.completed += 1
+                    ms.queue.record(out.queue_s * 1e6)
+                    ms.latency.record(out.latency_s * 1e6)
+                    if not r.payload.done():
+                        r.payload.set_result(out)
+        k = len(batch)
+        ms.slot.record(slot_s * 1e6, k)
+        ms.dispatch.record(dispatch_s * 1e6, k)
+        ms.complete.record(complete_s * 1e6, k)
+        ms.resolve.record((loop.time() - t1) * 1e6, k)

@@ -189,15 +189,19 @@ class TestEngineRawPath:
     def test_latency_split_recorded(self):
         engine, _ = self._engine()
         res = engine.classify("m", _raw(4, seed=1))
-        assert res.device_s > 0.0 and res.ingress_s >= 0.0
-        assert res.latency_s == pytest.approx(res.ingress_s + res.device_s, rel=0.05)
+        stages = (res.ingress_s, res.dispatch_s, res.wait_s, res.fetch_s)
+        assert res.dispatch_s > 0.0 and min(stages) >= 0.0
+        assert res.latency_s == pytest.approx(sum(stages), rel=0.05)
         st = engine.stats("m")
-        assert st.mean_device_us > 0.0
-        assert st.total_latency_s == pytest.approx(st.ingress_s + st.device_s, rel=0.05)
+        assert (st.dispatch.count, st.wait.count, st.fetch.count) == (1, 1, 1)
+        assert st.dispatch.quantile(0.5) > 0.0
         # Host ingress dominates its split; device path keeps ingress ~free.
-        engine.classify("m", _raw(4, seed=2), ingress="host")
-        st = engine.stats("m")
-        assert st.ingress_s > 0.0
+        host = engine.classify("m", _raw(4, seed=2), ingress="host")
+        assert host.ingress_s > res.ingress_s
+        assert host.latency_s == pytest.approx(
+            host.ingress_s + host.dispatch_s + host.wait_s + host.fetch_s, rel=0.05
+        )
+        assert engine.stats("m").dispatch.count == 2
 
     def test_raw_shape_validated(self):
         engine, _ = self._engine()
@@ -341,8 +345,11 @@ class TestServiceRawPath:
 
         asyncio.run(run())
         st = service.stats("m")
-        assert st.device_us_per_image > 0.0
-        assert st.ingress_us_per_image >= 0.0
+        assert st.queue.count == st.latency.count == 1
+        assert st.dispatch.count == st.complete.count == 1
+        assert st.p50_latency_us >= st.dispatch.quantile(0.5) > 0.0
+        est = engine.stats("m")
+        assert est.dispatch.count == est.wait.count == est.fetch.count == 1
 
     def test_raw_shape_error_propagates_without_enqueue(self):
         engine, _ = self._pair()

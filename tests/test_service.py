@@ -478,8 +478,13 @@ class TestServingService:
     def test_service_config_validation(self):
         with pytest.raises(ValueError, match="max_coalesce"):
             ServiceConfig(max_coalesce=0)
-        with pytest.raises(ValueError, match="latency_window"):
+        # The latency quantiles come from a histogram over the service's
+        # whole life, so no window size is configured.
+        with pytest.raises(TypeError, match="latency_window"):
             ServiceConfig(latency_window=0)
+        engine, _ = _serving_pair()
+        st = ServingService(engine).stats("glyphs")
+        assert st.latency.count == 0 and st.p99_latency_us == 0.0
 
     def test_double_start_rejected(self):
         engine, _ = _serving_pair()
