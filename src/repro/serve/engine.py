@@ -40,16 +40,28 @@ the device crunches batch k while the caller pads/dispatches batch k+1
 (the ``ServingService`` worker does exactly this).  ``classify`` is
 ``dispatch(...).result()``.
 
+Copy-back begins at launch: right after each chunk's step is launched,
+the device-to-host copy of its ``preds`` and ``sums`` is queued behind
+it (``copy_to_host_async``; on a mesh, of every addressable shard), so
+the results cross to the host while later chunks are still being put,
+launched and computed, and ``result()`` reads host copies that are in
+flight or done.  ``result()`` always reads every output it was handed,
+so this moves no byte that would not move anyway; a handle dropped
+without ``result()`` wastes its copies.  :class:`ServeStats` counts
+``copies_started`` (output arrays whose host copy began at launch) and
+``copies_read`` (output arrays read by ``result()``); they are equal
+once every dispatched request has been read.
+
 Per-request latency is split at the host's own boundaries: ``ingress``
 (validation, or the host pipeline on ``ingress='host'``), ``dispatch``
 (padding, H2D put and the jitted step's launch over every chunk),
-``wait`` (``block_until_ready``) and ``fetch`` (the D2H copies, slicing
-and concatenation).  :class:`ServeStats` keeps a histogram of each of
-the last three per model, and the same stages open profiler spans
-(``serve.engine.*``, ARCHITECTURE.md §Telemetry), so a trace shows which
-one the device waited on.  Throughput is compared against the paper's
-60.3k classifications/s (measured numbers in EXPERIMENTS.md §Serve and
-§Ingress).
+``wait`` (``block_until_ready``) and ``fetch`` (reading the host
+copies, slicing and concatenation).  :class:`ServeStats` keeps a
+histogram of each of the last three per model, and the same stages open
+profiler spans (``serve.engine.*``, ARCHITECTURE.md §Telemetry), so a
+trace shows which one the device waited on.  Throughput is compared
+against the paper's 60.3k classifications/s (measured numbers in
+EXPERIMENTS.md §Serve and §Ingress).
 
 Multi-device serving
 --------------------
@@ -118,7 +130,7 @@ class ClassifyResult:
     ingress_s: float = 0.0    # validation, or the host pipeline (ingress='host')
     dispatch_s: float = 0.0   # padding, H2D put and launch over every chunk
     wait_s: float = 0.0       # block_until_ready on the device results
-    fetch_s: float = 0.0      # D2H copies, slicing and concatenation
+    fetch_s: float = 0.0      # reading the host copies, slicing, concatenation
     version: int = 0          # monotonic id of the version that computed it
 
 
@@ -133,7 +145,9 @@ class ServeStats:
     ``dispatch`` is recorded once per ``dispatch()`` call (validation
     excluded), ``wait`` and ``fetch`` once per ``result()`` call, each on
     the thread that ran that stage; ``compiles`` counts first dispatches
-    of a (form, bucket) on the installed image.
+    of a (form, bucket) on the installed image.  ``copies_started``
+    counts output arrays whose host copy began at launch (warmup
+    excluded), ``copies_read`` output arrays read by ``result()``.
     """
 
     requests: int = 0
@@ -143,6 +157,8 @@ class ServeStats:
     wait: Histogram = dataclasses.field(default_factory=Histogram)
     fetch: Histogram = dataclasses.field(default_factory=Histogram)
     compiles: int = 0
+    copies_started: int = 0
+    copies_read: int = 0
     bucket_hits: Dict[int, int] = dataclasses.field(default_factory=dict)
     compiled_buckets: Tuple[int, ...] = ()
     devices: int = 1                  # mesh size (1 = unmeshed)
@@ -190,6 +206,8 @@ class ServeStats:
             "wait": self.wait.summary(),
             "fetch": self.fetch.summary(),
             "compiles": self.compiles,
+            "copies_started": self.copies_started,
+            "copies_read": self.copies_read,
             "bucket_hits": dict(self.bucket_hits),
             "compiled_buckets": list(self.compiled_buckets),
             "devices": self.devices,
@@ -309,7 +327,11 @@ class InFlightClassify:
     ``result()`` blocks until the device arrays are ready, slices off the
     bucket padding, records the request's stats and returns the
     :class:`ClassifyResult`; it is idempotent.  The ``wait`` and ``fetch``
-    stages are timed (and spanned) on the thread that calls it.
+    stages are timed (and spanned) on the thread that calls it.  The
+    host copies of the outputs were started at launch, so ``fetch``
+    mostly finds them done; a handle dropped without ``result()`` (a
+    service shutdown, a failed request) has paid for one copy of its
+    outputs that nobody reads.
     """
 
     def __init__(
@@ -346,6 +368,7 @@ class InFlightClassify:
         st.requests += 1
         st.images += self._n
         st.total_latency_s += t_end - t0
+        st.copies_read += 2 * len(self._parts)
         st.wait.record((t_fetch - t_wait) * 1e6)
         st.fetch.record((t_end - t_fetch) * 1e6)
         self._result = ClassifyResult(
@@ -942,10 +965,10 @@ class ServingEngine:
     def _submit_bucket(
         self, entry: _Entry, arr: np.ndarray, form: str, record_hit: bool = True
     ):
-        """Pad one <= max_batch chunk to its bucket, put it on the device(s)
-        and launch the jitted step WITHOUT blocking; returns ``(preds,
-        sums, n, bucket)`` with lazy device arrays.  Records bucket
-        hit/compile accounting."""
+        """Pad one <= max_batch chunk to its bucket, put it on the device(s),
+        launch the jitted step and start the outputs' host copy, all
+        WITHOUT blocking; returns ``(preds, sums, n, bucket)`` with lazy
+        device arrays.  Records bucket hit/compile accounting."""
         n = arr.shape[0]
         bucket = self.bucket_for(n)
         with span("serve.engine.pad"):
@@ -996,9 +1019,14 @@ class ServingEngine:
                 f"{path_name!r} {form} step for bucket {bucket} failed to "
                 f"compile on {jax.default_backend()}: {e}"
             ) from e
+        # Queued behind the step, so the copy-back overlaps the chunks
+        # launched after this one.
+        preds.copy_to_host_async()
+        sums.copy_to_host_async()
         st = entry.stats
         if record_hit:
             st.bucket_hits[bucket] = st.bucket_hits.get(bucket, 0) + 1
+            st.copies_started += 2
         if fresh:
             st.compiles += 1
             entry.compiled.add((form, bucket))

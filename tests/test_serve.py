@@ -140,6 +140,50 @@ class TestEngine:
         want_p, _ = infer(model, imgs, EDGE_CFG)
         np.testing.assert_array_equal(res.predictions, np.asarray(want_p))
 
+    @pytest.mark.parametrize("form", ["raw", "literals"])
+    @pytest.mark.parametrize("n", [1, 2 * 8 + 3], ids=["one", "three_chunks"])
+    def test_copy_back_started_at_launch(self, form, n, monkeypatch):
+        """Results are bit-identical to the per-chunk jitted step, every
+        output's host copy began at launch and was read once, and a second
+        ``result()`` reads nothing more."""
+        from repro.serve import classify_raw_step, classify_step
+
+        engine, _ = self._engine(max_batch=8)
+        imgs = np.asarray(_images(EDGE_CFG, n, seed=n))
+        arr = imgs if form == "raw" else engine.preprocess("glyphs", imgs)
+        array_type = type(jnp.zeros(()))
+        copy, started = array_type.copy_to_host_async, []
+        monkeypatch.setattr(
+            array_type, "copy_to_host_async",
+            lambda a: (started.append(a.shape), copy(a))[1],
+        )
+        handle = engine.dispatch("glyphs", arr, preprocessed=form == "literals")
+        chunks = -(-n // 8)
+        assert len(started) == 2 * chunks       # before anyone asked
+        res = handle.result()
+
+        sm, spec = engine.servable("glyphs"), engine.ingress_spec("glyphs")
+        want_p, want_v = [], []
+        for i in range(0, n, 8):
+            chunk = arr[i : i + 8]
+            b = engine.bucket_for(len(chunk))
+            pad = np.zeros((b - len(chunk),) + chunk.shape[1:], chunk.dtype)
+            x = jnp.asarray(np.concatenate([chunk, pad]))
+            path, params = engine.resolved_path("glyphs", form, b)
+            if form == "raw":
+                p, v = classify_raw_step(sm, x, path, spec, params)
+            else:
+                p, v = classify_step(sm, x, path, params=params)
+            want_p.append(np.asarray(p)[: len(chunk)])
+            want_v.append(np.asarray(v)[: len(chunk)])
+        np.testing.assert_array_equal(res.predictions, np.concatenate(want_p))
+        np.testing.assert_array_equal(res.class_sums, np.concatenate(want_v))
+
+        st = engine.stats("glyphs")
+        assert st.copies_started == st.copies_read == 2 * chunks
+        assert handle.result() is res
+        assert engine.stats("glyphs").copies_read == 2 * chunks
+
     def test_bounded_recompiles(self):
         from repro.serve import engine as engine_mod
         from tools.recompile_guard import no_recompiles

@@ -307,6 +307,53 @@ class TestServiceOnMesh:
         assert big._sched.max_coalesce == 64
 
 
+def test_copy_back_started_at_launch_4x1_subprocess():
+    """On a virtual-CPU 4x1 data mesh, the meshed engine is bit-identical
+    to the single-device engine in both request forms, one chunk and
+    three, and every output's host copy began at launch and was read
+    once (subprocess: the device count is set before jax initializes)."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys; sys.path.insert(0, "src")
+import jax, numpy as np
+from repro.core.cotm import CoTMConfig, init_boundary_model
+from repro.core.patches import PatchSpec
+from repro.serve import ServingEngine, make_serve_mesh
+
+spec = PatchSpec(image_x=11, image_y=11, window_x=5, window_y=5)
+cfg = CoTMConfig(n_clauses=40, n_classes=10, patch=spec)
+model = init_boundary_model(jax.random.PRNGKey(0), cfg)
+ref = ServingEngine(max_batch=8)
+ref.register("m", model, cfg)
+eng = ServingEngine(max_batch=8, mesh=make_serve_mesh(4, 1))
+eng.register("m", model, cfg)
+assert eng.devices == 4 and eng.data_shards == 4
+
+reads = 0
+for n in (1, 2 * 8 + 3):
+    imgs = np.random.default_rng(n).integers(0, 256, (n, 11, 11)).astype(np.uint8)
+    for preprocessed in (False, True):
+        x = eng.preprocess("m", imgs) if preprocessed else imgs
+        want = ref.classify("m", x, preprocessed=preprocessed)
+        handle = eng.dispatch("m", x, preprocessed=preprocessed)
+        got = handle.result()
+        np.testing.assert_array_equal(want.predictions, got.predictions)
+        np.testing.assert_array_equal(want.class_sums, got.class_sums)
+        assert handle.result() is got
+        reads += 2 * -(-n // 8)
+        st = eng.stats("m")
+        assert st.copies_started == st.copies_read == reads, st.as_dict()
+print("OK")
+"""
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=REPO, timeout=300, env={**os.environ, "PYTHONPATH": "src"},
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
 @pytest.mark.slow
 def test_sharded_serve_8dev_subprocess():
     """The full 1/2/8-device bit-identity sweep from a plain run: the

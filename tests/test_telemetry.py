@@ -147,6 +147,11 @@ class TestEngineStages:
         assert snap.dispatch.count == snap.fetch.count == 1
         assert engine.stats("m").dispatch.count == 2
         assert snap.as_dict()["fetch"]["count"] == 1
+        # One chunk: preds and sums, copied at launch and read once.
+        assert snap.copies_started == snap.copies_read == 2
+        assert engine.stats("m").copies_read == 4
+        d = snap.as_dict()
+        assert d["copies_started"] == d["copies_read"] == 2
 
 
 class TestServiceStages:
