@@ -14,10 +14,7 @@ import numpy as np
 
 import harness
 import load
-import reference
-import system
 import tracefile
-import work
 from harness import say
 
 
@@ -56,10 +53,10 @@ class _Window:
             self.jax.profiler.stop_trace()
 
 
-def _run_service(engine, arch, cfg, traffic, rng, seconds, trace, window):
+def _run_service(engine, arch, cfg, family, traffic, rng, seconds, trace, window):
     from repro.serve import ServiceConfig, ServingService
 
-    pool = system.make_frames(rng, traffic["pool_frames"], cfg["image_y"], cfg["image_x"])
+    pool = family.make_frames(rng, traffic["pool_frames"], cfg)
     engine.warmup(arch, forms=("raw",))       # every bucket a microbatch can hit
 
     async def serve():
@@ -85,10 +82,9 @@ def _run_service(engine, arch, cfg, traffic, rng, seconds, trace, window):
     return asyncio.run(serve())
 
 
-def _run_engine(engine, arch, cfg, traffic, rng, seconds, trace, window):
+def _run_engine(engine, arch, cfg, family, traffic, rng, seconds, trace, window):
     k = traffic["frames_per_request"]
-    batches = [system.make_frames(rng, k, cfg["image_y"], cfg["image_x"])
-               for _ in range(traffic["pool_requests"])]
+    batches = [family.make_frames(rng, k, cfg) for _ in range(traffic["pool_requests"])]
     chunks = {min(engine.max_batch, k - i) for i in range(0, k, engine.max_batch)}
     engine.warmup(arch, buckets=sorted(chunks), forms=("raw",))
     engine.classify(arch, batches[0])
@@ -98,8 +94,9 @@ def _run_engine(engine, arch, cfg, traffic, rng, seconds, trace, window):
     return rec
 
 
-def compare(checked, cfg, ta, weights, weight_bits=8):
-    """Rows whose class sums or prediction differ from the reference's,
+def compare(checked, cfg, family, model, weight_bits=8):
+    """Rows whose class sums (of any trailing shape) or prediction differ
+    from those of the family's reference on ``model`` (NumPy arrays),
     among the answers kept; frames the reference calls ambiguous are left
     out and counted."""
     keys = {}
@@ -109,9 +106,8 @@ def compare(checked, cfg, ta, weights, weight_bits=8):
         return {"rows": 0, "rows_wrong": 0, "preds_wrong": 0, "ambiguous": 0}
     order = list(keys)
     sizes = [len(keys[k]) for k in order]
-    sums, preds, amb = reference.class_sums(
-        np.concatenate([keys[k] for k in order]), cfg, ta, weights,
-        weight_bits=weight_bits)
+    sums, preds, amb = family.reference(
+        np.concatenate([keys[k] for k in order]), cfg, model, weight_bits=weight_bits)
     offs = dict(zip(order, np.cumsum([0] + sizes[:-1])))
     rows = rows_wrong = preds_wrong = ambiguous = 0
     for key, frames, got_sums, got_preds in checked:
@@ -120,21 +116,24 @@ def compare(checked, cfg, ta, weights, weight_bits=8):
         ok = ~amb[sl]
         rows += int(ok.sum())
         ambiguous += int((~ok).sum())
-        rows_wrong += int(((got_sums != sums[sl]).any(axis=1) & ok).sum())
+        differ = (got_sums != sums[sl]).reshape(len(frames), -1).any(axis=1)
+        rows_wrong += int((differ & ok).sum())
         preds_wrong += int(((got_preds != preds[sl]) & ok).sum())
     return {"rows": rows, "rows_wrong": rows_wrong, "preds_wrong": preds_wrong,
             "ambiguous": ambiguous}
 
 
-def run_cell(name, seed, seconds, trace, devices, t_start, *, traffic=None,
-             peaks=None):
-    """Run the cell ``name`` once on ``devices`` and print its lines; the
-    last line of standard output is the result."""
+def run_cell(name, seed, seconds, trace, devices, t_start, *, spec=None,
+             traffic=None, peaks=None):
+    """Run the cell ``name`` of ``spec`` (default ``BENCHMARK.json``) once on
+    ``devices`` and print its lines; the last line of standard output is
+    the result."""
     import jax
 
-    spec = harness.load_spec()
+    spec = spec or harness.load_spec()
     wl = harness.find_workload(spec, name)
     cfg = harness.load_config(spec, wl["config"])
+    family = harness.load_family(spec, wl["config"])
     traffic = traffic or harness.load_traffic(wl["traffic"])
     dev = devices[0]
     say(f"device: platform={dev.platform} kind={dev.device_kind} count={len(devices)}")
@@ -145,24 +144,17 @@ def run_cell(name, seed, seconds, trace, devices, t_start, *, traffic=None,
 
     trace_dir = harness.ROOT / ".bench_trace" / name if trace else None
     window = _Window(jax, meter, trace_dir, t_start)
-    ta, weights = system.make_model_arrays(jax, cfg, seed)
-    engine = system.build_engine(cfg, traffic, ta, weights)
+    model = family.make_model(jax, cfg, seed)
+    engine, arch = family.build_engine(cfg, traffic, model)
     window.mark("model and engine")
-    arch = cfg["arch"]
-    ta, weights = np.asarray(ta), np.asarray(weights)
-    nonempty = int(np.asarray(engine.servable(arch).nonempty).sum())
-    if nonempty != int((ta >= 128).any(axis=1).sum()):
-        raise RuntimeError(f"served model has {nonempty} nonempty clauses, the "
-                           f"model made has {int((ta >= 128).any(axis=1).sum())}")
-    work_ = work.frame_work(cfg, nonempty)
-    say(f"model: nonempty clauses={nonempty}/{cfg['n_clauses']} "
-        f"ops/frame={work_['ops_per_frame']} bytes/frame={work_['bytes_per_frame']}")
+    model = jax.tree.map(np.asarray, model)
+    work_ = family.served_work(engine, arch, cfg, model)
 
     kind = traffic["entry"]
     runner = {"service": _run_service, "engine": _run_engine}[kind]
-    rec = runner(engine, arch, cfg, traffic, rng, seconds, trace, window)
+    rec = runner(engine, arch, cfg, family, traffic, rng, seconds, trace, window)
     rec.update(kind=kind, work=work_, peaks=peaks, chips=len(devices),
-               setup_s=window.setup_s,
+               setup_s=window.setup_s, step_modules=tuple(family.STEP_MODULES),
                frames_per_step_event=engine.max_batch // engine.data_shards)
     device = harness.device_info(jax, devices)
 
@@ -201,7 +193,7 @@ def run_cell(name, seed, seconds, trace, devices, t_start, *, traffic=None,
             breakdown = {"device_ops": t["device_ops"], "idle_gaps": t["idle_gaps"]}
 
     t0 = time.monotonic()
-    cmp = compare(rec["checked"], cfg, ta, weights)
+    cmp = compare(rec["checked"], cfg, family, model)
     say(f"reference: {cmp['rows']} rows compared ({cmp['ambiguous']} ambiguous left "
         f"out) in {time.monotonic() - t0:.3f} s")
     checks = {

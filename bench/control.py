@@ -1,7 +1,8 @@
-"""The control of a cell's comparison: the reference with its int8 weights
-cut to int4 (the next precision below the configuration's), put in the
-program's place, on the frames and rows a run of that cell with the same
-seed checks.  The comparison has to reject it:
+"""The control of a cell's comparison: the configuration's reference with
+its int8 weights cut to int4 (the next precision below the
+configuration's), put in the program's place, on the frames and rows a
+run of that cell with the same seed checks.  The comparison has to
+reject it:
 
     python bench/control.py --workload mnist-bulk --seeds 11,12,13
 
@@ -23,32 +24,30 @@ def control_readings(name: str, seed: int, seconds: float, bits: int = 4) -> dic
 
     import harness
     import load
-    import reference
-    import system
     from cell import compare
 
     spec = harness.load_spec()
     wl = harness.find_workload(spec, name)
     cfg = harness.load_config(spec, wl["config"])
+    family = harness.load_family(spec, wl["config"])
     traffic = harness.load_traffic(wl["traffic"])
     rng = np.random.default_rng(seed)
-    ta, weights = (np.asarray(x) for x in system.make_model_arrays(jax, cfg, seed))
-    h, w = cfg["image_y"], cfg["image_x"]
+    model = jax.tree.map(np.asarray, family.make_model(jax, cfg, seed))
     if traffic["entry"] == "service":
-        pool = system.make_frames(rng, traffic["pool_frames"], h, w)
+        pool = family.make_frames(rng, traffic["pool_frames"], cfg)
         _, start, sampled = load.plan_open(rng, traffic, seconds, len(pool))
         k = traffic["frames_per_request"]
         keyed = [(int(s), pool[s:s + k]) for s in start[sampled]]
     else:
         k = traffic["frames_per_request"]
-        batches = [system.make_frames(rng, k, h, w) for _ in range(traffic["pool_requests"])]
+        batches = [family.make_frames(rng, k, cfg) for _ in range(traffic["pool_requests"])]
         rows = load.plan_closed(rng, traffic, batches)
         keyed = [(b, batches[b][r]) for b, r in enumerate(rows)]
     checked = []
     for key, frames in keyed:
-        sums, preds, _ = reference.class_sums(frames, cfg, ta, weights, weight_bits=bits)
+        sums, preds, _ = family.reference(frames, cfg, model, weight_bits=bits)
         checked.append((key, frames, sums, preds))
-    out = compare(checked, cfg, ta, weights)
+    out = compare(checked, cfg, family, model)
     return {"workload": name, "seed": seed, "weight_bits": bits, **out}
 
 

@@ -5,21 +5,45 @@ Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under ``bench/``, found by
 the name ``BENCHMARK.json`` gives it:
 
-  * ``bench/configs/<config>.json``  — the served model's geometry and source;
+  * ``bench/configs/<config>.json``  — the served model's geometry and source
+    (the path is the configuration's ``file`` in ``BENCHMARK.json``);
   * ``bench/traffic/<traffic>.json`` — the parameters the one general
     generator (``bench/load.py``) reads;
-  * ``bench/metrics/<metric>.py``    — one reader per metric, ``read(record)``.
+  * ``bench/metrics/<metric>.py``    — one reader per metric, ``read(record)``;
+  * ``bench/configs/<config>.py``    — optional, beside the configuration's
+    file with the same stem: the configuration's own code, as hooks that
+    replace the defaults in ``bench/family.py`` (:func:`load_family`):
+
+      - ``make_model(jax, cfg, seed)`` -> the model pytree; default
+        ``system.make_model_arrays``, ``(ta, weights)``;
+      - ``make_frames(rng, n, cfg)`` -> uint8 ``[n, ...]`` raw frames;
+        default ``system.make_frames`` at ``image_y`` x ``image_x``;
+      - ``build_engine(cfg, traffic, model)`` -> ``(engine, arch)``;
+        default ``system.build_engine`` under the file's ``arch``;
+      - ``served_work(engine, arch, cfg, model)`` -> the work dict; default
+        the nonempty-clause check, then ``work.frame_work``;
+      - ``reference(frames, cfg, model, weight_bits=8)`` -> ``(sums,
+        preds, ambiguous)`` in NumPy; default ``reference.class_sums``;
+      - ``STEP_MODULES`` -> the jitted step names the step metrics count;
+        default ``tracefile.STEP_MODULES``.
+
+    Any other public function, class or value the module defines is an
+    error at start (a misspelled hook would fall back to the default);
+    its helpers start with ``_``.
 """
 
 from __future__ import annotations
 
+import __future__
 import gc
 import importlib.util
+import inspect
 import json
 import math
 import os
 import sys
 import time
+import types
 from pathlib import Path
 
 import numpy as np
@@ -143,12 +167,60 @@ def find_workload(spec: dict, name: str) -> dict:
     )
 
 
-def load_config(spec: dict, name: str) -> dict:
+def config_entry(spec: dict, name: str) -> dict:
     for c in spec["configs"]:
         if c["name"] == name:
-            with open(ROOT / c["file"]) as f:
-                return json.load(f)
+            return c
     raise KeyError(f"unknown config {name!r}")
+
+
+def load_config(spec: dict, name: str) -> dict:
+    with open(ROOT / config_entry(spec, name)["file"]) as f:
+        return json.load(f)
+
+
+#: What a configuration's own module may define; ``bench/family.py`` has
+#: the default of each.
+HOOKS = ("make_model", "make_frames", "build_engine", "served_work", "reference",
+         "STEP_MODULES")
+
+
+def _load_module(name: str, path: Path):
+    mod_spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    return mod
+
+
+def _own_names(mod) -> set:
+    """The public names ``mod`` defines itself: its functions and classes,
+    and every other value but a module or a ``__future__`` feature."""
+    own = set()
+    for k, v in vars(mod).items():
+        if k.startswith("_") or inspect.ismodule(v) or isinstance(v, __future__._Feature):
+            continue
+        if (inspect.isfunction(v) or inspect.isclass(v)) and v.__module__ != mod.__name__:
+            continue                   # imported, not defined here
+        own.add(k)
+    return own
+
+
+def load_family(spec: dict, name: str) -> types.SimpleNamespace:
+    """The hooks of configuration ``name``: the defaults of
+    ``bench/family.py``, each replaced by the one of the same name in the
+    module beside the configuration's file, where there is one."""
+    import family
+
+    hooks = {h: getattr(family, h) for h in HOOKS}
+    path = (ROOT / config_entry(spec, name)["file"]).with_suffix(".py")
+    if path.exists():
+        mod = _load_module(f"bench_config_{name}", path)
+        unknown = sorted(_own_names(mod) - set(HOOKS))
+        if unknown:
+            raise ValueError(f"{path}: {unknown} are not hooks; a configuration's "
+                             f"module defines only {list(HOOKS)} and helpers named _*")
+        hooks.update((h, getattr(mod, h)) for h in HOOKS if hasattr(mod, h))
+    return types.SimpleNamespace(**hooks)
 
 
 def load_traffic(name: str) -> dict:
@@ -167,10 +239,7 @@ def read_metric(name: str, record: dict):
     """Run ``bench/metrics/<name>.py``'s ``read(record)``; None when the
     reader finds nothing to read."""
     path = BENCH_DIR / "metrics" / f"{name}.py"
-    mod_spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read(record)
+    return _load_module(f"bench_metric_{name}", path).read(record)
 
 
 def device_info(jax, devices) -> dict:

@@ -2,13 +2,13 @@
 programs classified in the traced window, times the counted operations
 per frame, over the window's seconds, the chips and the peak."""
 
-from tracefile import STEP_MODULES, step_events
+from tracefile import step_events
 
 
 def read(record):
     if record["kind"] != "engine" or not record.get("trace"):
         return None
-    n, _ = step_events(record, STEP_MODULES)
+    n, _ = step_events(record, record["step_modules"])
     if not n:
         return None
     frames = n * record["frames_per_step_event"]

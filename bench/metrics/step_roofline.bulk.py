@@ -2,14 +2,14 @@
 counted work of its runs in the traced window could take, over the summed
 device time of those runs (``bench/work.py``, ``harness.PEAKS``)."""
 
-from tracefile import STEP_MODULES, step_events
+from tracefile import step_events
 from work import least_step_s
 
 
 def read(record):
     if record["kind"] != "engine":
         return None
-    n, device_s = step_events(record, STEP_MODULES)
+    n, device_s = step_events(record, record["step_modules"])
     if not n or device_s <= 0:
         return None
     least = n * least_step_s(record["work"], record["peaks"],

@@ -11,8 +11,8 @@ Before the result line it prints ``stages {...}``: count and p50/p95/p99
 {...}``: the first device's idle seconds per ``serve.*`` span and the
 longest gaps so labelled (``bench/spans.py``).  The cell, its window,
 its comparison with the reference and its result line are
-``cell.run_cell``'s own; the probe only wraps the window, the engine's
-construction and the service's, to read them.
+``cell.run_cell``'s own; the probe only wraps the window, the family's
+``build_engine`` and the service's construction, to read them.
 """
 
 import time
@@ -44,19 +44,26 @@ def _snapshot(found: dict) -> dict:
 def probe_cell(name, seed, seconds, trace, devices, t_start, **kw):
     """``cell.run_cell`` with the probe's lines printed at the window's end."""
     import cell
+    import harness
     import spans
     import stages
-    import system
     from harness import say
 
     import repro.serve as serve
 
     found = {}
-    build_engine = system.build_engine
+    load_family = harness.load_family
 
-    def build(*a, **k):
-        found["engine"] = build_engine(*a, **k)
-        return found["engine"]
+    def family_of(*a, **k):
+        family = load_family(*a, **k)
+        build_engine = family.build_engine
+
+        def build(*a, **k):
+            found["engine"], arch = build_engine(*a, **k)
+            return found["engine"], arch
+
+        family.build_engine = build
+        return family
 
     class Service(serve.ServingService):
         def __init__(self, *a, **k):
@@ -77,7 +84,7 @@ def probe_cell(name, seed, seconds, trace, devices, t_start, **kw):
             if self.trace_dir is not None:
                 say("idle by span " + json.dumps(spans.reduce_trace(str(self.trace_dir))))
 
-    patches = [(system, "build_engine", build), (serve, "ServingService", Service),
+    patches = [(harness, "load_family", family_of), (serve, "ServingService", Service),
                (cell, "_Window", Window)]
     saved = [(mod, attr, getattr(mod, attr)) for mod, attr, _ in patches]
     for mod, attr, new in patches:

@@ -38,7 +38,6 @@ def main(argv=None) -> int:
 
     import harness
     import load
-    import system
 
     spec = harness.load_spec()
     wl = harness.find_workload(spec, args.workload)
@@ -54,10 +53,9 @@ def main(argv=None) -> int:
         return 2
     harness.enable_compile_cache(jax)
     rng = np.random.default_rng(args.seed)
-    ta, weights = system.make_model_arrays(jax, cfg, args.seed)
-    engine = system.build_engine(cfg, traffic, ta, weights)
-    arch = cfg["arch"]
-    pool = system.make_frames(rng, traffic["pool_frames"], cfg["image_y"], cfg["image_x"])
+    family = harness.load_family(spec, wl["config"])
+    engine, arch = family.build_engine(cfg, traffic, family.make_model(jax, cfg, args.seed))
+    pool = family.make_frames(rng, traffic["pool_frames"], cfg)
     engine.warmup(arch, forms=("raw",))
     print(f"device: platform={devices[0].platform} kind={devices[0].device_kind} "
           f"count={len(devices)}; set-up {time.monotonic() - T_START:.3f} s", flush=True)

@@ -7,6 +7,7 @@ import jax
 import numpy as np
 import pytest
 
+import family
 import harness
 import reference
 import system
@@ -32,7 +33,7 @@ def test_program_on_cpu_equals_reference(name):
     frames = system.make_frames(np.random.default_rng(4), 300, 28, 28)
     res = engine.classify(cfg["arch"], frames)
     out = compare([(0, frames, res.class_sums, res.predictions)], cfg,
-                  np.asarray(ta), np.asarray(w))
+                  family, (np.asarray(ta), np.asarray(w)))
     assert out["rows_wrong"] == 0 and out["preds_wrong"] == 0
     assert out["rows"] >= 290
 
