@@ -16,7 +16,7 @@ from repro.core.clauses import (
     patch_clause_outputs,
     patch_clause_outputs_matmul,
 )
-from repro.core.composites import CompositeConfig, CompositeModel, composite_infer
+from repro.core.composites import CompositeConfig, CompositeModel, composite_vote
 from repro.core.cotm import CoTMConfig, CoTMModel, infer, infer_packed, init_model
 from repro.core.ingress import (
     IngressSpec,
@@ -56,7 +56,7 @@ __all__ = [
     "booleanize",
     "class_sums",
     "clause_nonempty",
-    "composite_infer",
+    "composite_vote",
     "device_ingress",
     "eval_clauses_bitpacked",
     "eval_clauses_dense",

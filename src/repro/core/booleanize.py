@@ -56,11 +56,12 @@ def gaussian_kernel1d(size: int, sigma: Optional[float] = None) -> np.ndarray:
     return (k / k.sum()).astype(np.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_size",))
+@functools.partial(jax.jit, static_argnames=("block_size", "channels_last"))
 def adaptive_gaussian_booleanize(
     images: jax.Array,
     block_size: int = 11,
     c: float = 2.0,
+    channels_last: bool = False,
 ) -> jax.Array:
     """Adaptive Gaussian thresholding (paper's FMNIST/KMNIST setting).
 
@@ -69,36 +70,44 @@ def adaptive_gaussian_booleanize(
     what ``cv2.adaptiveThreshold(..., ADAPTIVE_THRESH_GAUSSIAN_C,
     THRESH_BINARY, block_size, c)`` does.
 
+    Both passes run at ``Precision.HIGHEST``: at the default precision the
+    TPU rounds the float32 convolution through bfloat16, which moves
+    pixels near ``local_mean - c`` across the threshold.
+
     Args:
-      images: ``[..., H, W]`` uint8/float.
+      images: ``[..., H, W]`` uint8/float, or ``[..., H, W, Z]`` with
+        ``channels_last``, where each channel is smoothed on its own over
+        H and W (as ``adaptiveThreshold`` on each plane).
       block_size: odd window size.
       c: constant subtracted from the local mean.
+      channels_last: the last axis holds channels, not columns.
     """
     if block_size % 2 != 1:
         raise ValueError(f"block_size must be odd, got {block_size}")
     x = images.astype(jnp.float32)
+    if channels_last:
+        x = jnp.moveaxis(x, -1, -3)
     batch_shape = x.shape[:-2]
     h, w = x.shape[-2:]
     x2 = x.reshape((-1, h, w))
 
     k = jnp.asarray(gaussian_kernel1d(block_size))
     pad = block_size // 2
+    conv = functools.partial(
+        jnp.convolve, mode="valid", precision=jax.lax.Precision.HIGHEST
+    )
 
     # Separable convolution with edge replication.
     xp = jnp.pad(x2, ((0, 0), (pad, pad), (0, 0)), mode="edge")
     # Convolve rows (axis 1).
     xr = jax.vmap(
-        lambda img: jax.vmap(
-            lambda col: jnp.convolve(col, k, mode="valid"), in_axes=1, out_axes=1
-        )(img)
+        lambda img: jax.vmap(lambda col: conv(col, k), in_axes=1, out_axes=1)(img)
     )(xp)
     xp2 = jnp.pad(xr, ((0, 0), (0, 0), (pad, pad)), mode="edge")
-    local_mean = jax.vmap(
-        lambda img: jax.vmap(lambda row: jnp.convolve(row, k, mode="valid"))(img)
-    )(xp2)
+    local_mean = jax.vmap(lambda img: jax.vmap(lambda row: conv(row, k))(img))(xp2)
 
-    out = (x2 > (local_mean - c)).astype(jnp.uint8)
-    return out.reshape(batch_shape + (h, w))
+    out = (x2 > (local_mean - c)).astype(jnp.uint8).reshape(batch_shape + (h, w))
+    return jnp.moveaxis(out, -3, -1) if channels_last else out
 
 
 def thermometer_thresholds(levels: int, lo: float = 0.0, hi: float = 255.0) -> np.ndarray:
@@ -128,19 +137,22 @@ def booleanize(
     block_size: int = 11,
     c: float = 2.0,
     levels: int = 1,
+    channels_last: bool = False,
 ) -> jax.Array:
     """Dataset-appropriate booleanization dispatch.
 
     ``method``: 'threshold' (MNIST), 'adaptive' (alias
     'adaptive_gaussian'; FMNIST/KMNIST), 'thermometer' (multi-bit,
-    scaled-up configs).
+    scaled-up configs).  ``channels_last`` says the last axis holds
+    channels; only the adaptive method, which smooths over H and W,
+    needs to know.
     Returns ``[..., H, W]`` for U=1 methods, ``[..., H, W, U]`` for
     thermometer with levels > 1.
     """
     if method == "threshold":
         return threshold_booleanize(images, threshold)
     if method in ("adaptive", "adaptive_gaussian"):
-        return adaptive_gaussian_booleanize(images, block_size, c)
+        return adaptive_gaussian_booleanize(images, block_size, c, channels_last)
     if method == "thermometer":
         out = thermometer_encode(images, levels)
         if levels == 1:

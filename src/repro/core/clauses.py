@@ -154,13 +154,16 @@ def class_sums(fired: jax.Array, weights: jax.Array) -> jax.Array:
     Args:
       fired:   uint8/int ``[B, C]`` clause outputs.
       weights: int ``[m, C]`` signed clause weights (int8 range on the ASIC).
+        int16 weights (frozen from a configuration wider than 8 bits) are
+        kept at int16; any other dtype is cast to int8, as it always was.
 
     Returns:
       int32 ``[B, m]`` class sums.
     """
+    op = jnp.int16 if weights.dtype == jnp.int16 else jnp.int8
     return jax.lax.dot_general(
-        fired.astype(jnp.int8),
-        weights.astype(jnp.int8),
+        fired.astype(op),
+        weights.astype(op),
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.int32,
     )

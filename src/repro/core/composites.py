@@ -5,23 +5,24 @@ sequentially on one configurable TM module: each specialist is a ConvCoTM
 with its own booleanization and window geometry; per image the specialists'
 class sums are normalized, summed, and argmax'd.
 
-We implement the composite as a first-class model so the scaled-up
-configuration can be dry-run, benchmarked (benchmarks/table3_scaledup.py)
-and trained end-to-end on small data. Normalization follows [17]:
-v_i <- v_i / max_i |v_i| per specialist (scale-free vote merging).
+The composite is served like any other model: ``ServingEngine.register``
+freezes each member (``serve/servable.py:CompositeServable``) and one
+jitted step runs every member's eval path on its own ingress of the same
+raw frame, then :func:`composite_vote`.  Normalization follows [17]:
+v_k <- v_k / max_i |v_k,i| per specialist (scale-free vote merging).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.cotm import CoTMConfig, CoTMModel, infer
+from repro.core.cotm import CoTMConfig, CoTMModel
 
-__all__ = ["CompositeConfig", "CompositeModel", "composite_infer"]
+__all__ = ["CompositeConfig", "CompositeModel", "composite_vote"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,27 +40,16 @@ class CompositeModel:
     members: Tuple[CoTMModel, ...]
 
 
-def composite_infer(
-    model: CompositeModel,
-    views: Sequence[jax.Array],
-    config: CompositeConfig,
-) -> Tuple[jax.Array, jax.Array]:
-    """Composite prediction.
+def composite_vote(sums: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The specialists' vote on per-specialist class sums ``int32 [B, K, m]``.
 
-    Args:
-      views: one booleanized input per specialist (each specialist may use a
-        different booleanization/window, so inputs differ per member).
+    ``Σ_k v_k / max(max_i |v_k,i|, 1)`` in float32 (a specialist whose sums
+    are all 0 adds nothing), then the first class with the largest total.
 
     Returns:
-      (predictions [B], composite class sums float32 [B, m]).
+      (predictions int32 ``[B]``, votes float32 ``[B, m]``).
     """
-    if len(views) != len(config.specialists):
-        raise ValueError("one view per specialist required")
-    total = None
-    for member, view, cfg in zip(model.members, views, config.specialists):
-        _, v = infer(member, view, cfg)
-        v = v.astype(jnp.float32)
-        denom = jnp.maximum(jnp.max(jnp.abs(v), axis=-1, keepdims=True), 1.0)
-        vn = v / denom
-        total = vn if total is None else total + vn
+    v = sums.astype(jnp.float32)
+    denom = jnp.maximum(jnp.max(jnp.abs(v), axis=-1, keepdims=True), 1.0)
+    total = jnp.sum(v / denom, axis=1)
     return jnp.argmax(total, axis=-1).astype(jnp.int32), total

@@ -7,7 +7,8 @@ The model is a pytree matching the ASIC's programmable state (Sec. IV-B):
     keeps only these action bits in its 34 816 model flops; we keep the full
     counters so the same object trains and serves.
   * ``weights``: int32 ``[m, C]`` signed clause weights, clamped to the
-    ASIC's int8 range at all times.
+    ASIC's int8 range at all times (``CoTMConfig.weight_bits`` widens the
+    served clamp: Table III's composites use 10-bit weights).
 
 Inference follows Algorithm 1: booleanize -> patches/literals -> parallel
 clause evaluation with sequential OR -> class sums -> argmax.
@@ -34,11 +35,24 @@ __all__ = [
     "init_boundary_model",
     "infer",
     "infer_packed",
+    "weight_dtype",
+    "weight_limit",
 ]
 
 TA_HALF = 128          # N: include iff state >= N (8-bit TA, Fig. 1)
 WEIGHT_MAX = 127       # int8 two's-complement clamp (Sec. IV-B)
 WEIGHT_MIN = -127
+
+
+def weight_limit(bits: int) -> int:
+    """The symmetric clamp of ``bits``-bit signed weights: 127 at 8 bits
+    (the ASIC), 511 at 10 (Table III)."""
+    return (1 << (bits - 1)) - 1
+
+
+def weight_dtype(bits: int):
+    """The narrowest integer type that holds ``bits``-bit weights."""
+    return jnp.int8 if bits <= 8 else jnp.int16
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,6 +103,9 @@ class CoTMConfig:
     s: float = 10.0              # specificity
     boost_true_positive: bool = True
     max_included_literals: Optional[int] = None   # literal budget [42]
+    # Width of the served clause weights: 8 on the ASIC (int8 register
+    # image), 10 in Table III's composites; frozen to weight_dtype(bits).
+    weight_bits: int = 8
     # Any path registered in repro.serve.paths:
     # 'dense' | 'bitpacked' | 'matmul' | 'kernel' | 'fused' | plugins.
     eval_path: str = "matmul"
@@ -99,6 +116,8 @@ class CoTMConfig:
     train_eval: str = "matmul"
 
     def __post_init__(self):
+        if not 2 <= self.weight_bits <= 16:
+            raise ValueError(f"weight_bits={self.weight_bits} outside [2, 16]")
         if not MAX_GEOMETRY.admits(
             self.n_clauses, self.n_classes,
             self.patch.n_literals, self.patch.n_patches,
@@ -117,8 +136,11 @@ class CoTMConfig:
 
     @property
     def model_bits(self) -> int:
-        """Register-image size: TA actions + 8-bit weights (45 056 for paper)."""
-        return self.n_clauses * self.n_literals + self.n_classes * self.n_clauses * 8
+        """Register-image size: TA actions + weights (45 056 for paper)."""
+        return (
+            self.n_clauses * self.n_literals
+            + self.n_classes * self.n_clauses * self.weight_bits
+        )
 
 
 @jax.tree_util.register_dataclass

@@ -128,7 +128,9 @@ def apply_booleanize(spec: IngressSpec, raw: jax.Array) -> jax.Array:
     if m == "threshold":
         return threshold_booleanize(raw, spec.threshold)
     if m == "adaptive":
-        return adaptive_gaussian_booleanize(raw, spec.block_size, spec.c)
+        return adaptive_gaussian_booleanize(
+            raw, spec.block_size, spec.c, channels_last=spec.patch.channels > 1
+        )
     # thermometer: appends the U axis (kept even for levels == 1 here;
     # _with_feature_axes normalizes against the patch spec below).
     out = thermometer_encode(raw, spec.levels)

@@ -102,7 +102,9 @@ def preprocess_for_serving(
     """
     x = np.asarray(raw_images)
     if method != "none":
-        x = booleanize_split(x, method, **booleanize_kw)
+        x = booleanize_split(
+            x, method, channels_last=spec.channels > 1, **booleanize_kw
+        )
     x = x.astype(np.uint8)
     if packed:
         return pack_literals_host(x, spec)

@@ -18,6 +18,7 @@ __all__ = [
     "clause_eval_sparse_ref",
     "sparse_infer_ref",
     "matmul_sparse_infer_ref",
+    "composite_infer_ref",
 ]
 
 
@@ -123,3 +124,40 @@ def matmul_sparse_infer_ref(
     )                                                        # [B, P, C_a]
     fired = jnp.any(viol == 0, axis=1).astype(jnp.uint8)
     return class_sum_ref(fired, weights_active)
+
+
+# --- TM Composites (several specialists voting on one frame) ---------------
+
+
+def composite_infer_ref(literals, includes, weights, weight_bits: int = 8):
+    """Plain-jnp oracle of a TM Composite's served step (Table III).
+
+    Per specialist k, from its dense literals ``uint8 [B, P_k, 2o_k]``, its
+    include mask ``[C_k, 2o_k]`` and its weights ``int [m, C_k]``: a
+    clause holds on a patch iff every literal it includes is 1 (Eq. 2),
+    it fires iff it is nonempty and holds on some patch (Eq. 6), and
+    ``v_k = Σ_j w_jk c_jk`` in int32 with the weights clamped to
+    ``±(2**(weight_bits - 1) - 1)``.  The vote is
+    ``Σ_k v_k / max(max_i |v_k,i|, 1)`` in float32 and the prediction the
+    first class with the largest vote.
+
+    Returns (predictions int32 ``[B]``, per-specialist class sums int32
+    ``[B, K, m]``, votes float32 ``[B, m]``).
+    """
+    lim = (1 << (weight_bits - 1)) - 1
+    with jax.default_matmul_precision("highest"):
+        sums = []
+        for lits, inc, w in zip(literals, includes, weights):
+            inc = jnp.asarray(inc) > 0                               # [C, 2o]
+            missing = inc[None, None] & (jnp.asarray(lits)[:, :, None, :] == 0)
+            holds = ~jnp.any(missing, axis=-1)                       # [B, P, C]
+            fired = jnp.any(holds, axis=1) & jnp.any(inc, axis=-1)[None]
+            w = jnp.clip(jnp.asarray(w).astype(jnp.int32), -lim, lim)
+            sums.append(
+                jnp.sum(fired[:, None, :].astype(jnp.int32) * w[None], axis=-1)
+            )
+        sums = jnp.stack(sums, axis=1)                               # [B, K, m]
+        v = sums.astype(jnp.float32)
+        scale = jnp.maximum(jnp.max(jnp.abs(v), axis=-1, keepdims=True), 1.0)
+        votes = jnp.sum(v / scale, axis=1)
+        return jnp.argmax(votes, axis=-1).astype(jnp.int32), sums, votes

@@ -3,7 +3,8 @@
 ``servable``  — :class:`ServableModel`, the software image of the ASIC's
                 45k-bit register file (frozen include bits, packed include
                 words, nonempty mask, int8-clamped weights), prepared
-                exactly once per model.
+                exactly once per model; :class:`CompositeServable`, one
+                frozen image per specialist of a TM Composite.
 ``paths``     — registry of functionally identical evaluation paths
                 (dense / bitpacked / matmul / kernel / fused), each
                 owning its full raw->sums graph via an ``ingress_fn``;
@@ -43,6 +44,7 @@ from repro.serve.engine import (
     InFlightClassify,
     ServeStats,
     ServingEngine,
+    classify_composite_step,
     classify_raw_step,
     classify_step,
 )
@@ -82,11 +84,13 @@ from repro.serve.scheduler import (
 )
 from repro.serve.servable import (
     ClauseSparsity,
+    CompositeServable,
     ServableModel,
     ServableVersion,
     active_pad,
     analyze_sparsity,
     freeze,
+    freeze_composite,
     servable_digest,
 )
 from repro.serve.service import (
@@ -105,6 +109,7 @@ __all__ = [
     "AutotuneReport",
     "ClassifyResult",
     "ClauseSparsity",
+    "CompositeServable",
     "DegradationPolicy",
     "DeviceLost",
     "EvalPath",
@@ -139,11 +144,13 @@ __all__ = [
     "autotune_servable",
     "available_paths",
     "chaos_soak",
+    "classify_composite_step",
     "classify_raw_step",
     "classify_step",
     "classify_step_meshed",
     "degraded_fallback",
     "freeze",
+    "freeze_composite",
     "make_serve_mesh",
     "get_path",
     "poisson_open_loop",

@@ -74,6 +74,20 @@ bit-identical to the single-device engine (``serve/mesh.py``,
 ARCHITECTURE.md §ServeMesh).  Buckets are clamped from below to the
 data-axis size so padding always splits evenly.
 
+TM Composites
+-------------
+A composite (``core/composites.py``: several ConvCoTM specialists voting
+on one frame, Table III) registers like a single bank, with one
+booleanization per specialist.  Each member is frozen and analysed on
+its own and gets its own :class:`IngressSpec`; one jitted step
+(:func:`classify_composite_step`) runs every member's registered eval
+path on its own ingress of the same raw buffer, each under
+``jax.named_scope("specialist<k>")``, then the vote.  Its
+``class_sums`` are the members' exact sums ``int32 [n, K, m]`` and its
+``predictions`` the vote's argmax.  A composite serves raw frames on one
+device; mesh placement, autotuning and host or literal request forms
+raise an error that names it.
+
 This is the synchronous library layer.  Online serving — request queue,
 admission control, latency-aware microbatching across concurrent
 submitters, multi-model fairness — lives one layer up in
@@ -92,6 +106,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import clauses as cl
+from repro.core.composites import CompositeConfig, CompositeModel, composite_vote
 from repro.core.cotm import CoTMConfig, CoTMModel
 from repro.core.ingress import IngressSpec, raw_trailing_shape
 from repro.data.pipeline import preprocess_for_serving
@@ -100,10 +115,12 @@ from repro.serve.faults import StepCompileError
 from repro.serve.mesh import ServeMesh, classify_step_meshed
 from repro.serve.paths import PACKED, Params, get_path, run_path, run_path_raw
 from repro.serve.servable import (
+    CompositeServable,
     ServableModel,
     ServableVersion,
     analyze_sparsity,
     freeze,
+    freeze_composite,
     servable_digest,
 )
 from repro.serve.telemetry import Histogram, span
@@ -114,7 +131,9 @@ __all__ = [
     "ServeStats",
     "ServingEngine",
     "classify_step",
+    "classify_composite_step",
     "classify_raw_step",
+    "composite_step_jit",
     "raw_step_jit",
 ]
 
@@ -124,7 +143,7 @@ class ClassifyResult:
     """One request's outcome."""
 
     predictions: np.ndarray   # int32 [n]
-    class_sums: np.ndarray    # int32 [n, m]
+    class_sums: np.ndarray    # int32 [n, m]; a composite's [n, K, m] per member
     latency_s: float          # wall clock, dispatch() entry -> result in hand
     bucket: int               # largest padded batch size executed
     ingress_s: float = 0.0    # validation, or the host pipeline (ingress='host')
@@ -148,6 +167,9 @@ class ServeStats:
     of a (form, bucket) on the installed image.  ``copies_started``
     counts output arrays whose host copy began at launch (warmup
     excluded), ``copies_read`` output arrays read by ``result()``.
+    ``active_clauses`` holds the nonempty clauses of each bank the
+    installed image serves (one per specialist of a composite), set at
+    register, swap and rollback.
     """
 
     requests: int = 0
@@ -159,6 +181,7 @@ class ServeStats:
     compiles: int = 0
     copies_started: int = 0
     copies_read: int = 0
+    active_clauses: Tuple[int, ...] = ()
     bucket_hits: Dict[int, int] = dataclasses.field(default_factory=dict)
     compiled_buckets: Tuple[int, ...] = ()
     devices: int = 1                  # mesh size (1 = unmeshed)
@@ -208,6 +231,7 @@ class ServeStats:
             "compiles": self.compiles,
             "copies_started": self.copies_started,
             "copies_read": self.copies_read,
+            "active_clauses": list(self.active_clauses),
             "bucket_hits": dict(self.bucket_hits),
             "compiled_buckets": list(self.compiled_buckets),
             "devices": self.devices,
@@ -241,6 +265,14 @@ class _Entry:
     # Memo of the stamped image servable() hands out, so repeated reads of
     # an unchanged version return the identical object (pack-once contract).
     stamped: Optional[ServableModel] = None
+    # One booleanization ({"method": ..., **knobs}) per specialist of a
+    # composite, and the arguments its spans carry; empty for one bank.
+    members_booleanize: Tuple[Dict, ...] = ()
+    span_args: Dict = dataclasses.field(default_factory=dict)
+
+    @property
+    def composite(self) -> bool:
+        return isinstance(self.servable, CompositeServable)
 
     def resolve(self, form: str, bucket: int) -> Tuple[str, Params]:
         """The (path, params) this entry dispatches for a (form, bucket):
@@ -252,6 +284,59 @@ class _Entry:
             if hit is not None:
                 return hit
         return self.path_name, ()
+
+
+def _active_clauses(servable) -> Tuple[int, ...]:
+    """The nonempty clauses of each bank ``servable`` serves."""
+    members = getattr(servable, "members", (servable,))
+    nonempty = jax.device_get([m.nonempty for m in members])    # one copy
+    return tuple(map(int, (n.sum() for n in nonempty)))
+
+
+def _map_banks(servable, fn):
+    """``fn`` applied to the one bank, or to each member of a composite."""
+    if isinstance(servable, CompositeServable):
+        return dataclasses.replace(
+            servable, members=tuple(fn(m) for m in servable.members)
+        )
+    return fn(servable)
+
+
+def _raw_shape(ingress) -> Tuple[int, ...]:
+    """Trailing dims of a raw batch; a composite's members share one."""
+    if isinstance(ingress, tuple):
+        ingress = ingress[0]
+    return raw_trailing_shape(ingress)
+
+
+def _ingress_for(
+    path_name: str, servable, method: str, kw: Dict, members_booleanize
+):
+    """The registered ingress: one :class:`IngressSpec` for a single bank,
+    a tuple of one per specialist for a composite."""
+    path = get_path(path_name)
+    if isinstance(servable, CompositeServable):
+        return tuple(
+            path.ingress_spec(m.config.patch, **b)
+            for m, b in zip(servable.members, members_booleanize)
+        )
+    return path.ingress_spec(servable.config.patch, method=method, **kw)
+
+
+#: Eval paths whose class sums take weights wider than int8 (the ones
+#: built on ``core.clauses.class_sums``); the Pallas class-sum kernels and
+#: their oracles take int8 weights only.
+_WIDE_WEIGHT_PATHS = ("dense", "matmul", "bitpacked", "kernel", "sparse")
+
+
+def _check_weight_width(path_name: str, servable) -> None:
+    for m in getattr(servable, "members", (servable,)):
+        bits = m.config.weight_bits
+        if bits > 8 and path_name not in _WIDE_WEIGHT_PATHS:
+            raise ValueError(
+                f"eval path {path_name!r} serves int8 weights only; a "
+                f"{bits}-bit configuration needs one of {_WIDE_WEIGHT_PATHS}"
+            )
 
 
 def _classify_step(
@@ -306,6 +391,51 @@ def raw_step_jit():
     return _raw_step_jit
 
 
+def _classify_composite_step(
+    servable: CompositeServable,
+    raw: jax.Array,
+    path_name: str,
+    ingress: Tuple[IngressSpec, ...],
+    params: Params = (),
+):
+    path = get_path(path_name)
+    sums = []
+    for k, (member, spec) in enumerate(zip(servable.members, ingress)):
+        with jax.named_scope(f"specialist{k}"):
+            sums.append(run_path_raw(path, member, raw, spec, params))
+    sums = jnp.stack(sums, axis=1)                      # [B, K, m]
+    preds, _ = composite_vote(sums)
+    return preds, sums
+
+
+_composite_step_jit = None
+
+
+def composite_step_jit():
+    """Build (once) and return the composite's jitted raw step, with the
+    same static keys and donation as :func:`raw_step_jit`."""
+    global _composite_step_jit
+    if _composite_step_jit is None:
+        _composite_step_jit = jax.jit(
+            _classify_composite_step,
+            static_argnames=("path_name", "ingress", "params"),
+            donate_argnums=() if jax.default_backend() == "cpu" else (1,),
+        )
+    return _composite_step_jit
+
+
+def classify_composite_step(
+    servable, raw, path_name: str, ingress: Tuple[IngressSpec, ...], params: Params = ()
+):
+    """The composite's raw-form step: every member's ingress and eval
+    path on the same raw ``uint8 [B, Y, X, Z]`` buffer (donated), then the
+    vote, in one executable.  Returns (predictions ``[B]``, per-member
+    class sums ``int32 [B, K, m]``)."""
+    return composite_step_jit()(
+        servable, raw, path_name=path_name, ingress=ingress, params=params
+    )
+
+
 def classify_raw_step(
     servable, raw, path_name: str, ingress: IngressSpec, params: Params = ()
 ):
@@ -355,11 +485,12 @@ class InFlightClassify:
     def result(self) -> ClassifyResult:
         if self._result is not None:
             return self._result
+        args = self._entry.span_args
         t_wait = time.perf_counter()
-        with span("serve.engine.wait"):
+        with span("serve.engine.wait", **args):
             jax.block_until_ready([(p, s) for p, s, _, _ in self._parts])
         t_fetch = time.perf_counter()
-        with span("serve.engine.fetch"):
+        with span("serve.engine.fetch", **args):
             preds = np.concatenate([np.asarray(p)[:ni] for p, _, ni, _ in self._parts])
             sums = np.concatenate([np.asarray(s)[:ni] for _, s, ni, _ in self._parts])
         t_end = time.perf_counter()
@@ -476,16 +607,17 @@ class ServingEngine:
     def register(
         self,
         name: str,
-        model: CoTMModel | ServableModel,
-        config: Optional[CoTMConfig] = None,
+        model: CoTMModel | ServableModel | CompositeModel | CompositeServable,
+        config: Optional[CoTMConfig | CompositeConfig] = None,
         *,
         booleanize_method: str = "threshold",
         path: Optional[str] = None,
         booleanize_kw: Optional[Dict] = None,
+        booleanize: Optional[Tuple[Dict, ...]] = None,
         autotune: Optional[bool] = None,
         tuned: Optional[TunedPlan] = None,
         version: Optional[ServableVersion] = None,
-    ) -> ServableModel:
+    ) -> ServableModel | CompositeServable:
         """Freeze (if needed) and register a model under a dataset key.
 
         Freezing happens here, exactly once — ``classify`` reuses the
@@ -495,6 +627,13 @@ class ServingEngine:
         are available.  The model's :class:`IngressSpec` (booleanize
         method + knobs, literal form of the eval path) is also fixed
         here; it is the static key of the raw-form classify executable.
+
+        A composite (a ``CompositeModel`` with its ``CompositeConfig``, or
+        a ``CompositeServable``) takes ``booleanize``, one dict
+        ``{"method": ..., **knobs}`` per specialist, in place of
+        ``booleanize_method``/``booleanize_kw``: each member is frozen and
+        analysed on its own and gets its own ingress of the same raw
+        frame.  It serves on one device and is not autotuned.
 
         ``autotune`` (default: the engine's ``autotune`` flag) arms the
         per-bucket path autotuner — it runs at :meth:`warmup` (or via
@@ -509,28 +648,69 @@ class ServingEngine:
         :meth:`swap` would.  The dispatched image is stamp-stripped so
         version churn never touches jit cache keys.
         """
-        if isinstance(model, ServableModel):
-            servable = model
+        autotune = self.autotune_default if autotune is None else autotune
+        members_b: Tuple[Dict, ...] = ()
+        if isinstance(model, (CompositeModel, CompositeServable)):
+            servable = (
+                model if isinstance(model, CompositeServable)
+                else self._freeze_composite(name, model, config)
+            )
+            k = len(servable.members)
+            if self.mesh is not None:
+                raise ValueError(
+                    f"{name!r} is a composite of {k} specialists, which is "
+                    f"not served on a ServeMesh; register it on an engine "
+                    f"without a mesh"
+                )
+            if autotune or tuned is not None:
+                raise ValueError(
+                    f"{name!r} is a composite of {k} specialists, which is "
+                    f"not autotuned; register it with autotune=False"
+                )
+            if booleanize is None or len(booleanize) != k:
+                raise ValueError(
+                    f"{name!r} is a composite of {k} specialists: booleanize= "
+                    f"takes one dict per specialist"
+                )
+            members_b = tuple(dict(b) for b in booleanize)
         else:
-            if config is None:
-                raise ValueError("config required when registering a CoTMModel")
-            servable = freeze(model, config)
-        path_name = path or servable.config.eval_path
-        eval_path = get_path(path_name)  # fail fast on unknown paths
+            if booleanize is not None:
+                raise ValueError(
+                    "booleanize= is for composites; a single bank takes "
+                    "booleanize_method= and booleanize_kw="
+                )
+            if isinstance(model, ServableModel):
+                servable = model
+            else:
+                if config is None:
+                    raise ValueError("config required when registering a CoTMModel")
+                servable = freeze(model, config)
+        first = getattr(servable, "members", (servable,))[0]
+        path_name = path or first.config.eval_path
+        get_path(path_name)  # fail fast on unknown paths
+        _check_weight_width(path_name, servable)
         booleanize_kw = dict(booleanize_kw or {})
-        ingress = eval_path.ingress_spec(
-            servable.config.patch, method=booleanize_method, **booleanize_kw
+        ingress = _ingress_for(
+            path_name, servable, booleanize_method, booleanize_kw, members_b
         )
+        if members_b:
+            shapes = sorted({raw_trailing_shape(s) for s in ingress})
+            if len(shapes) != 1:
+                raise ValueError(
+                    f"composite {name!r}: its specialists take raw frames of "
+                    f"shapes {shapes}; the members must share one frame"
+                )
         source = version if version is not None else servable.version
         # Freeze-time sparsity analysis (skipped on clause-sharded meshes,
         # where the active set is not shard-uniform and placement drops it
         # anyway — sparse paths then resolve to their dense fallbacks).
         if self.mesh is None or not self.mesh.shard_clauses:
-            servable = analyze_sparsity(servable)
+            servable = _map_banks(servable, analyze_sparsity)
         if tuned is not None:
             servable = dataclasses.replace(servable, tuned=tuned)
         stamp = self._stamp(servable, source, self._next_version_id(name))
         servable = dataclasses.replace(servable, version=None)
+        active = _active_clauses(servable)
         if self.mesh is not None:
             # Placement happens once, here: replicated register image or
             # clause-sharded splits (validates n_clauses divisibility).
@@ -543,12 +723,24 @@ class ServingEngine:
                 path_name=path_name,
                 ingress=ingress,
                 stats=ServeStats(
-                    devices=self.devices, data_shards=self.data_shards
+                    devices=self.devices,
+                    data_shards=self.data_shards,
+                    active_clauses=active,
                 ),
-                autotune=self.autotune_default if autotune is None else autotune,
+                autotune=autotune,
                 version=stamp,
+                members_booleanize=members_b,
+                span_args={"specialists": len(members_b)} if members_b else {},
             )
         return servable
+
+    @staticmethod
+    def _freeze_composite(name: str, model: CompositeModel, config) -> CompositeServable:
+        if not isinstance(config, CompositeConfig):
+            raise ValueError(
+                f"composite {name!r}: a CompositeModel needs its CompositeConfig"
+            )
+        return freeze_composite(model, config)
 
     def _next_version_id(self, name: str) -> int:
         prev = self._servables.get(name)
@@ -659,7 +851,8 @@ class ServingEngine:
         resolved = get_path(path_name)
         from repro.serve.paths import resolve_path
 
-        final = resolve_path(resolved, entry.servable)
+        bank = getattr(entry.servable, "members", (entry.servable,))[0]
+        final = resolve_path(resolved, bank)
         return final.name, (params if final is resolved else ())
 
     # --- lifecycle (ARCHITECTURE.md §Lifecycle) ---------------------------
@@ -674,8 +867,8 @@ class ServingEngine:
     def swap(
         self,
         name: str,
-        model: CoTMModel | ServableModel,
-        config: Optional[CoTMConfig] = None,
+        model: CoTMModel | ServableModel | CompositeModel | CompositeServable,
+        config: Optional[CoTMConfig | CompositeConfig] = None,
         *,
         version: Optional[ServableVersion] = None,
         tuned: Optional[TunedPlan] = None,
@@ -703,9 +896,28 @@ class ServingEngine:
         tuned-for-a-prior-version); ``retune=True`` re-measures on the
         candidate instead.  Returns the freshly installed stamp; the
         displaced version is retained whole for :meth:`rollback`.
+
+        A composite slot takes a composite of the same ``CompositeConfig``
+        (every specialist's weights are replaced, each analysed on its
+        own); it carries no plan, so ``tuned`` and ``retune`` are refused.
         """
         entry = self._servables[name]   # KeyError for unknown slots
-        if isinstance(model, ServableModel):
+        incoming = isinstance(model, (CompositeModel, CompositeServable))
+        if entry.composite or incoming:
+            if not (entry.composite and incoming):
+                raise ValueError(
+                    f"swap({name!r}): a composite and a single bank do not "
+                    f"swap for each other (re-register the slot)"
+                )
+            if tuned is not None or retune:
+                raise ValueError(
+                    f"swap({name!r}): a composite is not autotuned"
+                )
+            candidate = (
+                model if isinstance(model, CompositeServable)
+                else self._freeze_composite(name, model, config)
+            )
+        elif isinstance(model, ServableModel):
             candidate = model
         else:
             if config is None:
@@ -713,29 +925,39 @@ class ServingEngine:
             candidate = freeze(model, config)
         live_cfg = entry.servable.config
         if candidate.config != live_cfg:
+            kind = "composite " if entry.composite else ""
             raise ValueError(
-                f"swap({name!r}) config mismatch: a swap replaces weights "
-                f"only — got {candidate.config!r}, serving {live_cfg!r} "
-                f"(re-register for a geometry change)"
+                f"swap({name!r}) {kind}config mismatch: a swap replaces "
+                f"weights only — got {candidate.config!r}, serving "
+                f"{live_cfg!r} (re-register for a geometry change)"
             )
         source = version if version is not None else candidate.version
-        candidate = dataclasses.replace(candidate, sparsity=None)
+        candidate = _map_banks(
+            candidate, lambda m: dataclasses.replace(m, sparsity=None)
+        )
         if self.mesh is None or not self.mesh.shard_clauses:
             # Per-version sparsity analysis (never cached across swaps —
             # the active set belongs to the weights), padded to pow2 bins
             # so the analysis *shape* is shared across versions.
-            candidate = analyze_sparsity(candidate, pad_to="pow2")
+            candidate = _map_banks(
+                candidate, lambda m: analyze_sparsity(m, pad_to="pow2")
+            )
         stamp = self._stamp(candidate, source, self._next_version_id(name))
-        carried = entry.servable.tuned if tuned is None and not retune else tuned
-        candidate = dataclasses.replace(
-            candidate, tuned=carried, version=None
-        )
+        if entry.composite:
+            candidate = dataclasses.replace(candidate, version=None)
+        else:
+            carried = entry.servable.tuned if tuned is None and not retune else tuned
+            candidate = dataclasses.replace(
+                candidate, tuned=carried, version=None
+            )
+        active = _active_clauses(candidate)
         if self.mesh is not None:
             candidate = self.mesh.place_servable(candidate)
         with self._lock:
             entry.previous = (entry.servable, entry.version)
             entry.servable = candidate
             entry.version = stamp
+            entry.stats.active_clauses = active
             # Bucket warmth is per register image: the sparsity bin may
             # differ, so let compile accounting re-observe what actually
             # compiles (usually nothing — shapes are shared).
@@ -764,6 +986,7 @@ class ServingEngine:
             prev_servable, prev_stamp = entry.previous
             entry.previous = (entry.servable, entry.version)
             entry.servable = prev_servable
+            entry.stats.active_clauses = _active_clauses(prev_servable)
             entry.version = ServableVersion(
                 version=entry.version.version + 1,
                 epoch=prev_stamp.epoch,
@@ -796,14 +1019,13 @@ class ServingEngine:
             nxt = degraded_fallback(entry.path_name)
             if nxt is None:
                 return None
-            eval_path = get_path(nxt)
             entry.path_name = nxt
-            entry.ingress = eval_path.ingress_spec(
-                entry.servable.config.patch,
-                method=entry.booleanize_method,
-                **entry.booleanize_kw,
+            entry.ingress = _ingress_for(
+                nxt, entry.servable, entry.booleanize_method,
+                entry.booleanize_kw, entry.members_booleanize,
             )
-            entry.servable = dataclasses.replace(entry.servable, tuned=None)
+            if not entry.composite:
+                entry.servable = dataclasses.replace(entry.servable, tuned=None)
             entry.compiled = set()
             entry.stats.fallback_path = nxt
             entry.stats.degrade_steps += 1
@@ -876,6 +1098,11 @@ class ServingEngine:
         the servable (``servable(name).tuned``) for checkpointing.
         """
         entry = self._servables[name]
+        if entry.composite:
+            raise ValueError(
+                f"{name!r} is a composite of {len(entry.servable.members)} "
+                f"specialists, which is not autotuned"
+            )
         if buckets is None:
             buckets = dict.fromkeys((self.bucket_for(1), self.max_batch))
         buckets = [self.bucket_for(int(b)) for b in buckets]
@@ -900,13 +1127,13 @@ class ServingEngine:
         return plan
 
     def warmup(
-        self, name: str, buckets=None, *, forms=("literals", "raw")
+        self, name: str, buckets=None, *, forms=None
     ) -> Tuple[int, ...]:
         """Pre-compile buckets so request latency excludes jit compiles.
 
         By default warms BOTH request forms per bucket — the raw-form
         fused graph (ingress + eval) and the literal-form step — since
-        they compile separately; single-form workloads can pass
+        they compile separately (a composite has the raw form only); single-form workloads can pass
         ``forms=('raw',)`` or ``('literals',)`` to skip the other half's
         compile cost.  Default buckets: every power-of-two up to
         ``max_batch``.  Sizes are normalized through :meth:`bucket_for`
@@ -921,8 +1148,12 @@ class ServingEngine:
         (the no-recompile contract, tests/test_autotune.py).
         """
         entry = self._servables[name]
+        if forms is None:
+            forms = ("raw",) if entry.composite else ("literals", "raw")
         if unknown := set(forms) - {"literals", "raw"}:
             raise ValueError(f"unknown warmup forms: {sorted(unknown)}")
+        if entry.composite and "literals" in forms:
+            raise ValueError(f"composite {name!r} serves raw frames only")
         if entry.autotune and entry.servable.tuned is None:
             self.autotune(name, forms=forms)
         if buckets is None:
@@ -960,7 +1191,7 @@ class ServingEngine:
         return np.zeros((b, spec.n_patches, spec.n_literals), np.uint8)
 
     def _zero_raw(self, entry: _Entry, b: int) -> np.ndarray:
-        return np.zeros((b,) + raw_trailing_shape(entry.ingress), np.uint8)
+        return np.zeros((b,) + _raw_shape(entry.ingress), np.uint8)
 
     def _submit_bucket(
         self, entry: _Entry, arr: np.ndarray, form: str, record_hit: bool = True
@@ -971,7 +1202,8 @@ class ServingEngine:
         device arrays.  Records bucket hit/compile accounting."""
         n = arr.shape[0]
         bucket = self.bucket_for(n)
-        with span("serve.engine.pad"):
+        args = entry.span_args
+        with span("serve.engine.pad", **args):
             if bucket != n:
                 pad = np.zeros((bucket - n,) + arr.shape[1:], arr.dtype)
                 arr = np.concatenate([arr, pad], axis=0)
@@ -985,7 +1217,7 @@ class ServingEngine:
         # kernel Mosaic refuses), raised as such and never degraded around.
         fresh = (form, bucket) not in entry.compiled
         try:
-            with span("serve.engine.put"):
+            with span("serve.engine.put", **args):
                 # One H2D copy; on a mesh one placed (data-sharded) buffer,
                 # and nothing gathers until .result() reads the output.
                 if self.mesh is not None:
@@ -994,9 +1226,13 @@ class ServingEngine:
                     x = jax.device_put(arr)
             with span(
                 "serve.engine.compile" if fresh else "serve.engine.launch",
-                bucket=bucket, form=form,
+                bucket=bucket, form=form, **args,
             ):
-                if self.mesh is not None:
+                if entry.composite:
+                    preds, sums = classify_composite_step(
+                        entry.servable, x, path_name, entry.ingress, params
+                    )
+                elif self.mesh is not None:
                     preds, sums = classify_step_meshed(
                         entry.servable, x,
                         smesh=self.mesh,
@@ -1072,11 +1308,15 @@ class ServingEngine:
         raw = np.asarray(raw_images)
         if len(raw) == 0:
             raise ValueError("empty request")
-        want = raw_trailing_shape(entry.ingress)
+        want = _raw_shape(entry.ingress)
         if raw.shape[1:] != want:
+            method = (
+                [b.get("method") for b in entry.members_booleanize]
+                if entry.composite else entry.booleanize_method
+            )
             raise ValueError(
                 f"raw images for {name!r} must be [n, {', '.join(map(str, want))}] "
-                f"(method={entry.booleanize_method!r}); got {list(raw.shape)}"
+                f"(method={method!r}); got {list(raw.shape)}"
             )
         return raw
 
@@ -1093,6 +1333,11 @@ class ServingEngine:
         ``preprocessed=True`` many times.
         """
         entry = self._servables[name]
+        if entry.composite:
+            raise ValueError(
+                f"composite {name!r} serves raw frames only: its specialists "
+                f"have no one literal form"
+            )
         path = get_path(entry.path_name)
         if len(raw_images) == 0:
             raise ValueError("empty request")

@@ -65,11 +65,13 @@ from repro.core.patches import pack_bits
 
 __all__ = [
     "ClauseSparsity",
+    "CompositeServable",
     "ServableModel",
     "ServableVersion",
     "active_pad",
     "analyze_sparsity",
     "freeze",
+    "freeze_composite",
     "servable_digest",
 ]
 
@@ -127,8 +129,9 @@ def servable_digest(servable: "ServableModel") -> str:
     """
     h = hashlib.sha256()
     h.update(repr(servable.config).encode())
-    h.update(np.asarray(servable.include).tobytes())
-    h.update(np.asarray(servable.weights).tobytes())
+    for member in getattr(servable, "members", (servable,)):
+        h.update(np.asarray(member.include).tobytes())
+        h.update(np.asarray(member.weights).tobytes())
     return h.hexdigest()[:12]
 
 
@@ -195,7 +198,8 @@ class ServableModel:
     include: jax.Array         # uint8 0/1 [C, 2o] TA action signals
     include_packed: jax.Array  # uint32 [C, W] packed include masks
     nonempty: jax.Array        # bool [C] empty-clause mask (Sec. IV-D)
-    weights: jax.Array         # int8 [m, C] clamped clause weights
+    weights: jax.Array         # int8 [m, C] clamped clause weights (int16
+                               # where config.weight_bits > 8)
     config: "repro.core.cotm.CoTMConfig"
     sparsity: Optional[ClauseSparsity] = None
     tuned: Optional["repro.serve.autotune.TunedPlan"] = None
@@ -217,6 +221,41 @@ ServableModel = jax.tree_util.register_dataclass(
 )
 
 
+@dataclasses.dataclass(frozen=True)
+class CompositeServable:
+    """The frozen image of a TM Composite (``core/composites.py``): one
+    :class:`ServableModel` per specialist, each with its own geometry,
+    weight width and sparsity analysis, under one static
+    ``CompositeConfig``.  A pytree like :class:`ServableModel`; it carries
+    no tuned plan (a composite is not autotuned)."""
+
+    members: tuple             # ServableModel per specialist
+    config: "repro.core.composites.CompositeConfig"
+    version: Optional[ServableVersion] = None
+
+    tuned = None
+
+
+CompositeServable = jax.tree_util.register_dataclass(
+    CompositeServable,
+    data_fields=["members"],
+    meta_fields=["config", "version"],
+)
+
+
+def freeze_composite(model, config) -> CompositeServable:
+    """Freeze each specialist of a ``CompositeModel`` under its own config."""
+    if len(model.members) != len(config.specialists):
+        raise ValueError(
+            f"composite model has {len(model.members)} members, its config "
+            f"{len(config.specialists)} specialists"
+        )
+    return CompositeServable(
+        members=tuple(freeze(m, c) for m, c in zip(model.members, config.specialists)),
+        config=config,
+    )
+
+
 def freeze(model, config) -> ServableModel:
     """Prepare a trained ``CoTMModel`` for serving (one-time, per model).
 
@@ -226,14 +265,17 @@ def freeze(model, config) -> ServableModel:
     concrete values; attach it eagerly with :func:`analyze_sparsity`
     (``ServingEngine.register`` does).
     """
-    from repro.core.cotm import WEIGHT_MAX, WEIGHT_MIN
+    from repro.core.cotm import weight_dtype, weight_limit
 
     include = model.include
+    lim = weight_limit(config.weight_bits)
     return ServableModel(
         include=include,
         include_packed=pack_bits(include),
         nonempty=cl.clause_nonempty(include),
-        weights=jnp.clip(model.weights, WEIGHT_MIN, WEIGHT_MAX).astype(jnp.int8),
+        weights=jnp.clip(model.weights, -lim, lim).astype(
+            weight_dtype(config.weight_bits)
+        ),
         config=config,
     )
 

@@ -108,3 +108,47 @@ class TestAdaptiveGolden:
             np.asarray(booleanize(images, method="adaptive", block_size=bs, c=c)),
             np.asarray(adaptive_gaussian_booleanize(images, bs, c)),
         )
+
+
+class TestAdaptiveChannels:
+    """A channels-last frame is thresholded one channel plane at a time,
+    each smoothed over Y and X only, as ``adaptiveThreshold`` on each
+    plane; checked against the float64 NumPy local mean above."""
+
+    @pytest.mark.parametrize("shape", [(3, 16, 13), (3, 16, 13, 3)],
+                             ids=["gray", "rgb"])
+    @pytest.mark.parametrize("bs,c", [(5, 2.0), (11, 2.0)])
+    def test_each_channel_matches_numpy(self, shape, bs, c):
+        rng = np.random.default_rng(bs)
+        images = rng.integers(0, 256, shape).astype(np.uint8)
+        rgb = len(shape) == 4
+        got = np.asarray(adaptive_gaussian_booleanize(images, bs, c, channels_last=rgb))
+        assert got.shape == images.shape and got.dtype == np.uint8
+        planes = images if not rgb else np.moveaxis(images, -1, 1).reshape(-1, *shape[1:3])
+        outs = got if not rgb else np.moveaxis(got, -1, 1).reshape(-1, *shape[1:3])
+        for img, out in zip(planes, outs):
+            margin = img.astype(np.float64) - (_local_mean_reference(img, bs) - c)
+            decided = np.abs(margin) > 1e-3          # float32 against float64
+            np.testing.assert_array_equal(out[decided], (margin > 0)[decided])
+
+    def test_channels_do_not_mix(self):
+        """A channel's bits depend on that channel alone."""
+        rng = np.random.default_rng(0)
+        images = rng.integers(0, 256, (2, 12, 12, 3)).astype(np.uint8)
+        other = images.copy()
+        other[..., 1:] = 255 - other[..., 1:]
+        a = np.asarray(adaptive_gaussian_booleanize(images, 5, 2.0, channels_last=True))
+        b = np.asarray(adaptive_gaussian_booleanize(other, 5, 2.0, channels_last=True))
+        np.testing.assert_array_equal(a[..., 0], b[..., 0])
+
+    def test_convolutions_run_at_highest_precision(self):
+        """The TPU rounds a default-precision float32 convolution through
+        bfloat16; both passes ask for HIGHEST."""
+        import jax
+
+        jaxpr = str(jax.make_jaxpr(
+            lambda x: adaptive_gaussian_booleanize(x, 5, 2.0, channels_last=True)
+        )(np.zeros((1, 8, 8, 3), np.uint8)))
+        convs = jaxpr.count("conv_general_dilated[")
+        assert convs == 2
+        assert jaxpr.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == convs

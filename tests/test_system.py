@@ -162,22 +162,39 @@ class TestPipeline:
         np.testing.assert_array_equal(a, epoch_permutation(3, 0, n))
 
     def test_composite_inference(self):
-        from repro.core.composites import (
-            CompositeConfig,
-            CompositeModel,
-            composite_infer,
-        )
+        """Two specialists on one booleanized view, served through the
+        engine's composite step and checked against the jnp oracle."""
+        from repro.core.composites import CompositeConfig, CompositeModel
+        from repro.core.cotm import CoTMModel
+        from repro.core.patches import make_literals
+        from repro.kernels.ref import composite_infer_ref
+        from repro.serve import ServingEngine
 
         spec = PatchSpec(image_x=8, image_y=8, window_x=3, window_y=3)
         cfg = CoTMConfig(n_clauses=8, n_classes=3, patch=spec)
         comp = CompositeConfig(specialists=(cfg, cfg))
-        key = jax.random.PRNGKey(5)
-        m = CompositeModel(members=(init_model(key, cfg), init_model(key, cfg)))
-        views = [
-            (jax.random.uniform(key, (4, 8, 8)) > 0.5).astype(jnp.uint8)
-        ] * 2
-        pred, v = composite_infer(m, views, comp)
-        assert pred.shape == (4,) and v.shape == (4, 3)
+        def member(seed):
+            # two literals a clause, so clauses fire and the sums move
+            rng = np.random.default_rng(seed)
+            ta = np.full((8, cfg.n_literals), 50, np.uint8)
+            for j in range(8):
+                ta[j, rng.choice(cfg.n_literals, 2, replace=False)] = 200
+            w = rng.integers(-127, 128, (3, 8)).astype(np.int32)
+            return CoTMModel(ta_state=jnp.asarray(ta), weights=jnp.asarray(w))
+
+        m = CompositeModel(members=(member(5), member(6)))
+        view = (jax.random.uniform(jax.random.PRNGKey(5), (4, 8, 8)) > 0.5).astype(jnp.uint8)
+        engine = ServingEngine(max_batch=4)
+        engine.register("comp", m, comp, booleanize=({"method": "none"},) * 2)
+        res = engine.classify("comp", np.asarray(view))
+        assert res.predictions.shape == (4,) and res.class_sums.shape == (4, 2, 3)
+        lits = make_literals(extract_patch_features(view, spec))
+        pred, sums, _ = composite_infer_ref(
+            [lits, lits], [x.include for x in m.members], [x.weights for x in m.members]
+        )
+        np.testing.assert_array_equal(res.class_sums, np.asarray(sums))
+        np.testing.assert_array_equal(res.predictions, np.asarray(pred))
+        assert np.abs(res.class_sums).sum() > 0
 
 
 class TestDrivers:

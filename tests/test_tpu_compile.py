@@ -131,3 +131,36 @@ def test_fused_raw_step_compiles_for_v5e(one_chip, bucket):
         params=(("backend", "pallas"),),
     ).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_composite_step_compiles_for_v5e(one_chip):
+    """The Table III composite's step at its published widths (four
+    1000-clause specialists on 32x32x3 frames, 10-bit weights) on the
+    ``matmul`` path, bucket 8: the int16 class-sum operands and the
+    HIGHEST-precision adaptive booleanization compile for the chip."""
+    from repro.configs.convcotm import CIFAR10_COMPOSITES, COMPOSITE_BOOLEANIZE
+    from repro.core.composites import CompositeModel
+    from repro.core.cotm import CoTMModel
+    from repro.serve.engine import composite_step_jit
+    from repro.serve.paths import get_path
+    from repro.serve.servable import freeze_composite
+
+    comp = CIFAR10_COMPOSITES
+    model = CompositeModel(members=tuple(
+        CoTMModel(ta_state=jax.ShapeDtypeStruct((c.n_clauses, c.n_literals), jnp.uint8),
+                  weights=jax.ShapeDtypeStruct((c.n_classes, c.n_clauses), jnp.int32))
+        for c in comp.specialists))
+    servable = jax.eval_shape(lambda m: freeze_composite(m, comp), model)
+    on_chip = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), servable
+    )
+    path = get_path("matmul")
+    ingress = tuple(
+        path.ingress_spec(c.patch, **b)
+        for c, b in zip(comp.specialists, COMPOSITE_BOOLEANIZE["cifar10-composites"])
+    )
+    raw = jax.ShapeDtypeStruct((8, 32, 32, 3), jnp.uint8, sharding=one_chip)
+    compiled = composite_step_jit().lower(
+        on_chip, raw, path_name="matmul", ingress=ingress, params=()
+    ).compile()
+    assert "s16[10,1000]" in compiled.as_text()
