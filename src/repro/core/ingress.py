@@ -51,6 +51,7 @@ __all__ = [
     "IngressSpec",
     "apply_booleanize",
     "apply_ingress",
+    "feature_bits",
     "device_ingress",
     "raw_trailing_shape",
 ]
@@ -160,6 +161,13 @@ def _with_feature_axes(bits: jax.Array, patch: PatchSpec) -> jax.Array:
     )
 
 
+def feature_bits(spec: IngressSpec, raw: jax.Array) -> jax.Array:
+    """Raw pixels -> booleanized bits ``uint8 [B, Y, X, Z, U]``, the layout
+    the patch window reads (``extract_patch_features``, and the folded
+    clause check of ``core.clauses.eval_clauses_folded``)."""
+    return _with_feature_axes(apply_booleanize(spec, raw), spec.patch)
+
+
 def apply_ingress(spec: IngressSpec, raw: jax.Array) -> jax.Array:
     """Raw pixels -> literals in ``spec``'s form, composable under jit.
 
@@ -169,7 +177,7 @@ def apply_ingress(spec: IngressSpec, raw: jax.Array) -> jax.Array:
     jitted classify step fuses the whole raw->predictions path into one
     executable.
     """
-    bits = _with_feature_axes(apply_booleanize(spec, raw), spec.patch)
+    bits = feature_bits(spec, raw)
     if spec.packed and spec.patch.channels == 1 and spec.patch.therm_bits == 1:
         backend = spec.kernel_backend or (
             "pallas" if jax.default_backend() == "tpu" else "jnp"

@@ -113,7 +113,14 @@ from repro.data.pipeline import preprocess_for_serving
 from repro.serve.autotune import TunedPlan, autotune_servable
 from repro.serve.faults import StepCompileError
 from repro.serve.mesh import ServeMesh, classify_step_meshed
-from repro.serve.paths import PACKED, Params, get_path, run_path, run_path_raw
+from repro.serve.paths import (
+    PACKED,
+    Params,
+    folded_convolution,
+    get_path,
+    run_path,
+    run_path_raw,
+)
 from repro.serve.servable import (
     CompositeServable,
     ServableModel,
@@ -169,7 +176,10 @@ class ServeStats:
     excluded), ``copies_read`` output arrays read by ``result()``.
     ``active_clauses`` holds the nonempty clauses of each bank the
     installed image serves (one per specialist of a composite), set at
-    register, swap and rollback.
+    register, swap and rollback.  ``folded_checks`` counts bank-chunks
+    whose clause check ran as the folded convolution
+    (``core.clauses.eval_clauses_folded``; a composite chunk counts once
+    per member that took it; warmup excluded).
     """
 
     requests: int = 0
@@ -181,6 +191,7 @@ class ServeStats:
     compiles: int = 0
     copies_started: int = 0
     copies_read: int = 0
+    folded_checks: int = 0
     active_clauses: Tuple[int, ...] = ()
     bucket_hits: Dict[int, int] = dataclasses.field(default_factory=dict)
     compiled_buckets: Tuple[int, ...] = ()
@@ -231,6 +242,7 @@ class ServeStats:
             "compiles": self.compiles,
             "copies_started": self.copies_started,
             "copies_read": self.copies_read,
+            "folded_checks": self.folded_checks,
             "active_clauses": list(self.active_clauses),
             "bucket_hits": dict(self.bucket_hits),
             "compiled_buckets": list(self.compiled_buckets),
@@ -300,6 +312,13 @@ def _map_banks(servable, fn):
             servable, members=tuple(fn(m) for m in servable.members)
         )
     return fn(servable)
+
+
+def _folded_banks(servable, path_name: str) -> int:
+    """How many of ``servable``'s banks a raw chunk on ``path_name``
+    checks as the folded convolution."""
+    members = getattr(servable, "members", (servable,))
+    return sum(folded_convolution(path_name, m) for m in members)
 
 
 def _raw_shape(ingress) -> Tuple[int, ...]:
@@ -1263,6 +1282,8 @@ class ServingEngine:
         if record_hit:
             st.bucket_hits[bucket] = st.bucket_hits.get(bucket, 0) + 1
             st.copies_started += 2
+            if form == "raw":
+                st.folded_checks += _folded_banks(entry.servable, path_name)
         if fresh:
             st.compiles += 1
             entry.compiled.add((form, bucket))

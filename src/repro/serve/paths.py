@@ -19,7 +19,13 @@ literals -> pack -> clause eval -> class sums into ONE jitted graph with
 a single H2D copy (the serving engine's ``classify_raw_step``).  The
 default ``ingress_fn`` is :func:`repro.core.ingress.apply_ingress`;
 kernel-backed paths may substitute one that drops into the Pallas
-ingress kernel.
+ingress kernel.  A path may instead carry a ``raw_fn`` — raw -> class
+sums with no literal tensor at all — which :func:`run_path_raw` calls
+in place of ``ingress_fn`` then ``fn``.  ``matmul`` does: its raw form
+folds each literal into its negation and checks clauses with one
+convolution over the booleanized frame
+(:func:`repro.core.clauses.eval_clauses_folded`), so no patch is
+gathered; its literal form keeps the dot over ``[B, P, 2o]``.
 
 Sparse paths and fallbacks (ARCHITECTURE.md §Sparsity)
 ------------------------------------------------------
@@ -56,7 +62,7 @@ from typing import Callable, Optional, Tuple
 import jax
 
 from repro.core import clauses as cl
-from repro.core.ingress import IngressSpec, apply_ingress
+from repro.core.ingress import IngressSpec, apply_ingress, feature_bits
 
 __all__ = [
     "EvalPath",
@@ -68,6 +74,7 @@ __all__ = [
     "degraded_fallback",
     "run_path",
     "run_path_raw",
+    "folded_convolution",
     "DENSE",
     "PACKED",
     "RAW",
@@ -80,6 +87,10 @@ PathFn = Callable[..., jax.Array]
 
 #: ingress_fn(spec, raw) -> literals in the path's input form (pure jnp)
 IngressFn = Callable[[IngressSpec, jax.Array], jax.Array]
+
+#: raw_fn(spec, raw, include, include_packed, nonempty, weights,
+#:        **params) -> int32 [B, m], the raw form with no literal tensor.
+RawFn = Callable[..., jax.Array]
 
 #: A static parameter set: hashable ((name, value), ...) pairs.
 Params = Tuple[Tuple[str, object], ...]
@@ -110,12 +121,16 @@ class EvalPath:
     used when no sparsity analysis is attached (must share
     ``input_form``).  ``tunable`` lists static parameter sets the
     autotuner may sweep (the empty set — path defaults — always works).
+    ``raw_fn``, when set, is the path's whole raw form (raw -> class
+    sums), used by :func:`run_path_raw` in place of ``ingress_fn`` and
+    ``fn``.
     """
 
     name: str
     input_form: str          # DENSE | PACKED
     fn: PathFn
     ingress_fn: IngressFn = apply_ingress
+    raw_fn: Optional[RawFn] = None
     needs_sparsity: bool = False
     fallback: Optional[str] = None
     tunable: Tuple[Params, ...] = ((),)
@@ -143,6 +158,7 @@ def register_path(
     input_form: str,
     *,
     ingress_fn: Optional[IngressFn] = None,
+    raw_fn: Optional[RawFn] = None,
     needs_sparsity: bool = False,
     fallback: Optional[str] = None,
     tunable: Tuple[Params, ...] = ((),),
@@ -151,8 +167,9 @@ def register_path(
 
     ``ingress_fn`` overrides the default device ingress for this path
     (same contract: ``(IngressSpec, raw) -> literals`` in ``input_form``,
-    jit-composable).  ``fallback`` (required with ``needs_sparsity``)
-    must already be registered with the same input form.
+    jit-composable); ``raw_fn`` replaces the raw form whole.
+    ``fallback`` (required with ``needs_sparsity``) must already be
+    registered with the same input form.
     """
 
     def deco(fn: PathFn) -> PathFn:
@@ -170,6 +187,7 @@ def register_path(
             input_form=input_form,
             fn=fn,
             ingress_fn=ingress_fn or apply_ingress,
+            raw_fn=raw_fn,
             needs_sparsity=needs_sparsity,
             fallback=fallback,
             tunable=tunable,
@@ -232,6 +250,24 @@ def degraded_fallback(name: str) -> Optional[str]:
     return path.fallback or "dense"
 
 
+def _resolved(path: EvalPath, servable, params: Params):
+    """(the path evaluated, its params, its servable arguments)."""
+    resolved = resolve_path(path, servable)
+    if resolved is not path:
+        # Fallback substitution: tuned params belong to the sparse path,
+        # not its dense twin — run the twin at its defaults.
+        path, params = resolved, ()
+    args = (
+        servable.include,
+        servable.include_packed,
+        servable.nonempty,
+        servable.weights,
+    )
+    if path.needs_sparsity:
+        args = args + (servable.sparsity,)
+    return path, params, args
+
+
 def run_path(
     path: EvalPath, servable, literals: jax.Array, params: Params = ()
 ) -> jax.Array:
@@ -240,21 +276,8 @@ def run_path(
     ``params`` is a static parameter set from ``path.tunable`` (autotuner
     winners); ``()`` runs the path defaults.
     """
-    resolved = resolve_path(path, servable)
-    if resolved is not path:
-        # Fallback substitution: tuned params belong to the sparse path,
-        # not its dense twin — run the twin at its defaults.
-        path, params = resolved, ()
-    args = (
-        literals,
-        servable.include,
-        servable.include_packed,
-        servable.nonempty,
-        servable.weights,
-    )
-    if path.needs_sparsity:
-        args = args + (servable.sparsity,)
-    return path.fn(*args, **dict(params))
+    path, params, args = _resolved(path, servable, params)
+    return path.fn(literals, *args, **dict(params))
 
 
 def run_path_raw(
@@ -265,11 +288,23 @@ def run_path_raw(
     params: Params = (),
 ) -> jax.Array:
     """Class sums int32 [B, m] straight from raw pixels (the :data:`RAW`
-    form): the path's own ingress_fn then its eval fn, one traceable
-    graph with no host materialization in between."""
+    form): the path's ``raw_fn`` when it has one, else its own
+    ingress_fn then its eval fn — one traceable graph with no host
+    materialization in between."""
+    resolved, resolved_params, args = _resolved(path, servable, params)
+    if resolved.raw_fn is not None:
+        return resolved.raw_fn(ingress, raw, *args, **dict(resolved_params))
     if ingress.packed != (path.input_form == PACKED):
         ingress = dataclasses.replace(ingress, packed=path.input_form == PACKED)
     return run_path(path, servable, path.ingress_fn(ingress, raw), params)
+
+
+def folded_convolution(path_name: str, servable) -> bool:
+    """Whether the raw form of ``path_name`` checks ``servable``'s clauses
+    as the folded convolution of :func:`repro.core.clauses.
+    eval_clauses_folded` (the count behind ``ServeStats.folded_checks``)."""
+    path = resolve_path(get_path(path_name), servable)
+    return path.raw_fn is _matmul_raw
 
 
 # --- the built-in paths ----------------------------------------------------
@@ -280,7 +315,14 @@ def _dense(lits, include, include_packed, nonempty, weights):
     return cl.class_sums(fired, weights)
 
 
-@register_path("matmul", DENSE)
+def _matmul_raw(ingress, raw, include, include_packed, nonempty, weights):
+    fired = cl.eval_clauses_folded(
+        feature_bits(ingress, raw), ingress.patch, include, nonempty
+    )
+    return cl.class_sums(fired, weights)
+
+
+@register_path("matmul", DENSE, raw_fn=_matmul_raw)
 def _matmul(lits, include, include_packed, nonempty, weights):
     fired = cl.eval_clauses_matmul(lits, include, nonempty)
     return cl.class_sums(fired, weights)

@@ -107,6 +107,22 @@ def test_served_sums_equal_reference(n):
     assert engine.stats("cifar").bucket_hits == (
         {8: 1, 4: 1} if n == 11 else {engine.bucket_for(n): 1}
     )
+    # Every member's check ran as the folded convolution, in every chunk.
+    assert engine.stats("cifar").folded_checks == 4 * (2 if n == 11 else 1)
+
+
+def test_bucket_256_composite_equals_reference():
+    """A full 256-frame chunk through the folded composite step: every
+    specialist's sums exact, four folded checks counted."""
+    config = _config()
+    model = _model(config, seed=7)
+    engine = _engine(model, config, max_batch=256)
+    frames = _frames(256, seed=7)
+    res = engine.classify("cifar", frames)
+    preds, sums = _reference(model, config, frames)
+    np.testing.assert_array_equal(res.class_sums, sums)
+    np.testing.assert_array_equal(res.predictions, preds)
+    assert engine.stats("cifar").folded_checks == 4
 
 
 def test_service_submit_equals_reference():

@@ -392,3 +392,110 @@ class TestCotmDispatch:
         want_p, want_v = infer(model, imgs, EDGE_CFG)
         np.testing.assert_array_equal(np.asarray(v), np.asarray(want_v))
         np.testing.assert_array_equal(np.asarray(p), np.asarray(want_p))
+
+
+# --- matmul's raw form: the folded clause check ----------------------------
+
+#: (geometry, booleanization) per case: each booleanize method and
+#: thermometer depth, a stride-2 window and the whole-image window (P=1).
+FOLD_CASES = {
+    "threshold": (PatchSpec(image_x=28, image_y=28, window_x=10, window_y=10),
+                  {"method": "threshold"}),
+    "thermometer-1": (PatchSpec(image_x=12, image_y=12, window_x=5, window_y=5,
+                                channels=3),
+                      {"method": "thermometer", "levels": 1}),
+    "thermometer-3": (PatchSpec(image_x=12, image_y=12, window_x=3, window_y=3,
+                                channels=3, therm_bits=3),
+                      {"method": "thermometer", "levels": 3}),
+    "thermometer-4": (PatchSpec(image_x=10, image_y=10, window_x=4, window_y=4,
+                                therm_bits=4),
+                      {"method": "thermometer", "levels": 4}),
+    "adaptive-rgb": (PatchSpec(image_x=12, image_y=12, window_x=5, window_y=5,
+                               channels=3),
+                     {"method": "adaptive", "block_size": 5, "c": 2.0}),
+    "stride-2": (PatchSpec(image_x=13, image_y=13, window_x=3, window_y=3,
+                           stride_x=2, stride_y=2),
+                 {"method": "threshold"}),
+    "whole-image": (PatchSpec(image_x=12, image_y=12, window_x=12, window_y=12,
+                              channels=3, therm_bits=3),
+                    {"method": "thermometer", "levels": 3}),
+}
+
+
+def _fold_model(cfg, seed=0):
+    """Clauses of 1 to 6 features in random polarity; clause 0 is empty
+    and clause 1 includes a feature and its negation (it never fires)."""
+    from repro.core.cotm import CoTMModel
+
+    rng = np.random.default_rng(seed)
+    o = cfg.n_literals // 2
+    include = np.zeros((cfg.n_clauses, 2 * o), bool)
+    for j in range(2, cfg.n_clauses):
+        f = rng.choice(o, rng.integers(1, 7), replace=False)
+        include[j, np.where(rng.random(len(f)) < 0.5, f, f + o)] = True
+    include[1, [0, o]] = True
+    return CoTMModel(
+        ta_state=jnp.asarray(np.where(include, 200, 50).astype(np.uint8)),
+        weights=jnp.asarray(rng.integers(-127, 128, (cfg.n_classes, cfg.n_clauses)),
+                            jnp.int32),
+    )
+
+
+def _fold_frames(spec, n, seed):
+    shape = (n, spec.image_y, spec.image_x) + ((spec.channels,) if spec.channels > 1 else ())
+    return np.random.default_rng(seed).integers(0, 256, shape).astype(np.uint8)
+
+
+@pytest.mark.parametrize("bucket", [1, 256])
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_folded_raw_form_equals_dense_and_oracle(case, bucket):
+    """The matmul path's raw form (the folded convolution) gives the
+    dense path's class sums and the kernels/ref.py oracles', bit for
+    bit, and counts one folded check per chunk."""
+    from repro.core import clauses as cl
+    from repro.core.ingress import IngressSpec, feature_bits
+    from repro.data.pipeline import preprocess_for_serving
+    from repro.kernels.ref import clause_eval_ref, fused_infer_ref
+
+    spec, boolz = FOLD_CASES[case]
+    cfg = CoTMConfig(n_clauses=24, n_classes=10, patch=spec)
+    model = _fold_model(cfg, seed=bucket)
+    frames = _fold_frames(spec, bucket, seed=bucket)
+    kw = {k: v for k, v in boolz.items() if k != "method"}
+    got, want = ServingEngine(max_batch=256), ServingEngine(max_batch=256)
+    sv = got.register("m", model, cfg, booleanize_method=boolz["method"],
+                      booleanize_kw=kw, path="matmul")
+    want.register("m", model, cfg, booleanize_method=boolz["method"],
+                  booleanize_kw=kw, path="dense")
+    res = got.classify("m", frames)
+    ref = want.classify("m", frames)
+    np.testing.assert_array_equal(res.class_sums, ref.class_sums)
+    np.testing.assert_array_equal(res.predictions, ref.predictions)
+
+    lits = jnp.asarray(preprocess_for_serving(frames, spec, **boolz))
+    oracle = fused_infer_ref(lits, sv.include_packed, sv.nonempty, sv.weights)
+    np.testing.assert_array_equal(res.class_sums, np.asarray(oracle))
+    bits = feature_bits(IngressSpec(patch=spec, **boolz), jnp.asarray(frames))
+    fired = cl.eval_clauses_folded(bits, spec, sv.include, sv.nonempty)
+    np.testing.assert_array_equal(
+        fired, clause_eval_ref(lits, sv.include_packed, sv.nonempty))
+    assert not np.asarray(fired)[:, :2].any()   # empty, and x with not-x
+    assert np.asarray(fired).any()
+    assert got.stats("m").folded_checks == 1
+    assert got.stats("m").as_dict()["folded_checks"] == 1
+    assert want.stats("m").folded_checks == 0
+
+
+def test_folded_checks_count_raw_chunks_only():
+    """Two chunks of a raw request count two; the literal form and
+    warmup count none."""
+    cfg = CoTMConfig(n_clauses=24, n_classes=10, patch=EDGE_SPEC)
+    engine = ServingEngine(max_batch=8)
+    engine.register("m", _fold_model(cfg), cfg, path="matmul")
+    engine.warmup("m", buckets=[8])
+    frames = _fold_frames(EDGE_SPEC, 11, seed=4)
+    raw = engine.classify("m", frames)
+    assert engine.stats("m").folded_checks == 2
+    lits = engine.classify("m", engine.preprocess("m", frames), preprocessed=True)
+    np.testing.assert_array_equal(lits.class_sums, raw.class_sums)
+    assert engine.stats("m").folded_checks == 2

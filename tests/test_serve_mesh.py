@@ -354,6 +354,57 @@ print("OK")
     assert "OK" in r.stdout
 
 
+def test_folded_check_on_clause_sharded_1x2_subprocess():
+    """On a virtual-CPU 1x2 clause-sharded mesh the matmul path's raw
+    form derives its folded kernel and constant from each shard's own
+    clauses: class sums equal the single-device dense path, and every
+    chunk counts as a folded check (subprocess: the device count is set
+    before jax initializes)."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import sys; sys.path.insert(0, "src")
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.cotm import CoTMConfig, CoTMModel
+from repro.core.patches import PatchSpec
+from repro.serve import ServingEngine, make_serve_mesh
+
+for spec, method, kw in (
+    (PatchSpec(image_x=11, image_y=11, window_x=5, window_y=5), "threshold", {}),
+    (PatchSpec(image_x=12, image_y=12, window_x=4, window_y=4, channels=3,
+               therm_bits=3), "thermometer", {"levels": 3}),
+):
+    cfg = CoTMConfig(n_clauses=40, n_classes=10, patch=spec)
+    rng = np.random.default_rng(0)
+    ta = np.full((40, cfg.n_literals), 50, np.uint8)
+    for j in range(1, 40):                    # clause 0 stays empty
+        ta[j, rng.choice(cfg.n_literals, rng.integers(1, 5), replace=False)] = 200
+    model = CoTMModel(ta_state=jnp.asarray(ta), weights=jnp.asarray(
+        rng.integers(-127, 128, (10, 40)), jnp.int32))
+    ref = ServingEngine(max_batch=8)
+    ref.register("m", model, cfg, path="dense", booleanize_method=method,
+                 booleanize_kw=kw)
+    eng = ServingEngine(max_batch=8, mesh=make_serve_mesh(1, 2))
+    eng.register("m", model, cfg, path="matmul", booleanize_method=method,
+                 booleanize_kw=kw)
+    assert eng.devices == 2 and eng.mesh.shard_clauses
+    shape = (11, spec.image_y, spec.image_x) + ((3,) if spec.channels > 1 else ())
+    imgs = np.random.default_rng(5).integers(0, 256, shape).astype(np.uint8)
+    want, got = ref.classify("m", imgs), eng.classify("m", imgs)
+    np.testing.assert_array_equal(want.class_sums, got.class_sums)
+    np.testing.assert_array_equal(want.predictions, got.predictions)
+    assert np.abs(got.class_sums).max() > 0
+    assert eng.stats("m").folded_checks == 2, eng.stats("m").as_dict()
+print("OK")
+"""
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=REPO, timeout=300, env={**os.environ, "PYTHONPATH": "src"},
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
+
+
 @pytest.mark.slow
 def test_sharded_serve_8dev_subprocess():
     """The full 1/2/8-device bit-identity sweep from a plain run: the

@@ -316,6 +316,30 @@ class TestTM404:
         )
         assert findings == []
 
+    def test_folded_convolution_bound(self):
+        # bits [0, 1] against a {-1, 0, 1} kernel over a 4x4 window of 3
+        # features: each output sums 48 products, so |v| <= 48; the same
+        # window at 2^21 features would pass fp32's exact range.
+        def f(x, k):
+            return jax.lax.conv_general_dilated(
+                x.astype(jnp.bfloat16), k.astype(jnp.bfloat16), (1, 1),
+                "VALID", dimension_numbers=("NHWC", "HWIO", "NHWC"),
+                preferred_element_type=jnp.float32,
+            ).astype(jnp.int32)
+
+        findings, stats = analyze_fn(
+            f, [self.S((2, 8, 8, 3), jnp.uint8), self.S((4, 4, 3, 5), jnp.int8)],
+            [Interval(0, 1), Interval(-1, 1)], "fixture:conv",
+        )
+        assert findings == []
+        assert stats.widest_int == Interval(-48, 48)
+        findings, _ = analyze_fn(
+            f, [self.S((1, 8, 8, 1 << 21), jnp.uint8),
+                self.S((4, 4, 1 << 21, 1), jnp.int8)],
+            [Interval(0, 1), Interval(-1, 1)], "fixture:conv-wide",
+        )
+        assert any(f.key.endswith("conv_general_dilated:inexact") for f in findings)
+
     def test_dtype_interval(self):
         assert dtype_interval(jnp.int8) == Interval(-128, 127)
         assert dtype_interval(jnp.uint32) == Interval(0, (1 << 32) - 1)

@@ -164,3 +164,45 @@ def test_composite_step_compiles_for_v5e(one_chip):
         on_chip, raw, path_name="matmul", ingress=ingress, params=()
     ).compile()
     assert "s16[10,1000]" in compiled.as_text()
+
+
+def test_composite_step_folds_its_clause_checks_for_v5e(one_chip):
+    """The composite step at bucket 256 on the ``matmul`` path checks
+    every specialist's clauses as the folded convolution
+    (``core.clauses.eval_clauses_folded``): the executable holds a
+    convolution under ``specialist<k>/clause_conv`` for each of the four
+    and no patch-window gather, and its temporaries stay under the
+    58,499,072 bytes the gathered form compiled to at this bucket."""
+    import re
+
+    from repro.configs.convcotm import CIFAR10_COMPOSITES, COMPOSITE_BOOLEANIZE
+    from repro.core.composites import CompositeModel
+    from repro.core.cotm import CoTMModel
+    from repro.serve.engine import composite_step_jit
+    from repro.serve.paths import get_path
+    from repro.serve.servable import freeze_composite
+
+    comp = CIFAR10_COMPOSITES
+    model = CompositeModel(members=tuple(
+        CoTMModel(ta_state=jax.ShapeDtypeStruct((c.n_clauses, c.n_literals), jnp.uint8),
+                  weights=jax.ShapeDtypeStruct((c.n_classes, c.n_clauses), jnp.int32))
+        for c in comp.specialists))
+    servable = jax.eval_shape(lambda m: freeze_composite(m, comp), model)
+    on_chip = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), servable
+    )
+    path = get_path("matmul")
+    ingress = tuple(
+        path.ingress_spec(c.patch, **b)
+        for c, b in zip(comp.specialists, COMPOSITE_BOOLEANIZE["cifar10-composites"])
+    )
+    raw = jax.ShapeDtypeStruct((256, 32, 32, 3), jnp.uint8, sharding=one_chip)
+    compiled = composite_step_jit().lower(
+        on_chip, raw, path_name="matmul", ingress=ingress, params=()
+    ).compile()
+    text = compiled.as_text()
+    convs = [ln for ln in text.splitlines() if " convolution(" in ln]
+    for k in range(len(comp.specialists)):
+        assert any(f"specialist{k}/clause_conv/" in ln for ln in convs), k
+    assert not re.search(r" gather\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 58_499_072

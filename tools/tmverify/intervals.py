@@ -145,6 +145,20 @@ def _dot_general(eqn, ins: List[Interval]) -> Interval:
     return Interval(k * pmin, k * pmax)
 
 
+def _conv(eqn, ins: List[Interval]) -> Interval:
+    """Each output element sums at most kernel window x input features
+    products (the rhs's size over its output-feature dim); padding and
+    dilation only drop terms, so the bound widens to include 0."""
+    rhs_shape = eqn.invars[1].aval.shape
+    out_feature_dim = eqn.params["dimension_numbers"].rhs_spec[0]
+    k = 1
+    for n in rhs_shape:
+        k *= int(n)
+    k //= max(1, int(rhs_shape[out_feature_dim]))
+    pmin, pmax = _products(ins[0], ins[1])
+    return Interval(k * min(pmin, 0), k * max(pmax, 0))
+
+
 def _reduce_sum(eqn, ins: List[Interval]) -> Interval:
     shape = eqn.invars[0].aval.shape
     n = 1
@@ -190,6 +204,7 @@ _HANDLERS: Dict[str, Callable] = {
     "max": lambda e, i: Interval(max(i[0].lo, i[1].lo), max(i[0].hi, i[1].hi)),
     "min": lambda e, i: Interval(min(i[0].lo, i[1].lo), min(i[0].hi, i[1].hi)),
     "dot_general": _dot_general,
+    "conv_general_dilated": _conv,
     "reduce_sum": _reduce_sum,
     "reduce_max": lambda e, i: i[0],
     "reduce_min": lambda e, i: i[0],
@@ -423,6 +438,7 @@ def _max_geometry_cases():
 
     from repro.core import clauses as cl
     from repro.core.cotm import MAX_GEOMETRY, WEIGHT_MAX, WEIGHT_MIN
+    from repro.core.patches import PatchSpec
     from repro.kernels import ref
 
     G = MAX_GEOMETRY
@@ -459,6 +475,18 @@ def _max_geometry_cases():
             cl.eval_clauses_matmul(literals, include), weights
         )
 
+    # The folded raw check (serve/paths.py, matmul's raw form) at the
+    # widest window the envelope admits with position bits left over:
+    # 36x36x3 window bits + 24 position bits, 2o = 7824 <= L.
+    fold_spec = PatchSpec(image_x=48, image_y=48, window_x=36, window_y=36,
+                          channels=3)
+    assert fold_spec.n_literals <= L
+
+    def folded_eval(bits, include, weights):
+        return cl.class_sums(
+            cl.eval_clauses_folded(bits, fold_spec, include), weights
+        )
+
     return [
         ("ir:ref.class_sum", ref.class_sum_ref,
          [S((B, C), u8), S((m, C), i8)], [bit, wt]),
@@ -477,6 +505,10 @@ def _max_geometry_cases():
          [S((B, 128), u8), S((m, 128), i8)], [bit, wt]),
         ("ir:train.eval_matmul", train_eval,
          [S((B, P, L), u8), S((C, L), u8), S((m, C), i8)],
+         [bit, bit, wt]),
+        ("ir:serve.eval_folded", folded_eval,
+         [S((B, 48, 48, 3, 1), u8), S((C, fold_spec.n_literals), u8),
+          S((m, C), i8)],
          [bit, bit, wt]),
     ]
 
