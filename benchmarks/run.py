@@ -1,5 +1,5 @@
 """Benchmark harness entry point: one function per paper table + the JAX
-measured benchmarks + the roofline table.  Prints ``name,us_per_call,
+measured benchmarks.  Prints ``name,us_per_call,
 derived`` CSV rows per the repo contract, then the table reproductions.
 
 ``--emit-json DIR`` instead runs the serving/ingress regression harness
@@ -18,7 +18,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
 import time
 
 from repro.launch.compile_cache import enable_compile_cache
@@ -226,23 +225,6 @@ def main() -> None:
         rate = f"{r['cls_per_s']:,}" if r["cls_per_s"] else "n/a"
         print(f"  {r['design']:45s} {r['algorithm']:10s} {r['operation']:12s} "
               f"rate={rate} EPC={epc}")
-
-    # --- Roofline table (from dry-run artifacts + analytic models) -------
-    try:
-        from benchmarks.roofline_table import render_markdown, roofline_rows
-
-        rows = roofline_rows("16x16")
-        compiled = sum(1 for r in rows if r["compiled"])
-        print(f"\n== Roofline (16x16, {compiled}/{len(rows)} cells compiled) ==")
-        for r in rows:
-            print(
-                f"  {r['arch']:24s} {r['shape']:12s} dom={r['dominant']:10s} "
-                f"frac={r['roofline_fraction']:.2f} "
-                f"c={r['compute_s']:.2e} m={r['memory_s']:.2e} "
-                f"x={r['collective_s']:.2e}"
-            )
-    except Exception as e:  # dry-run artifacts absent
-        print(f"\n(roofline table unavailable: {e})", file=sys.stderr)
 
 
 if __name__ == "__main__":

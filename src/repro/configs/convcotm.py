@@ -1,9 +1,8 @@
 """The paper's own accelerator configurations (``--arch convcotm-*``).
 
-These are CoTMConfig (not ModelConfig) instances: the ConvCoTM is the
-paper's architecture and runs through the same launcher / benchmark
-harness as the LM archs, but with its own model/inference code
-(repro.core).  Values follow Sec. III-D / IV:
+These are CoTMConfig instances, served and trained through the
+``repro.launch`` drivers with the model/inference code of ``repro.core``.
+Values follow Sec. III-D / IV:
 
   * 28x28 booleanized images, 10x10 window, stride 1 -> 361 patches,
     272 literals; 128 clauses; 10 classes; int8 weights.

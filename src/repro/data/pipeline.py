@@ -4,9 +4,9 @@ Mirrors the ASIC's double-buffered image registers (Sec. IV-C): while batch
 k is being classified on device, batch k+1 is already being transferred —
 ``DoubleBufferedLoader`` keeps one device-resident batch in flight.
 
-For the distributed LM substrate the same loader shards the leading batch
-axis over the ("pod", "data") mesh axes with ``jax.device_put`` on a
-NamedSharding; for the single-host CPU runs it degenerates to one device.
+Given a NamedSharding the loader shards the leading batch axis over a
+mesh with ``jax.device_put``; without one it places each batch on one
+device.
 Pipeline state (epoch cursor + RNG) is checkpointable so a restarted job
 resumes mid-epoch (see checkpoint/).
 """

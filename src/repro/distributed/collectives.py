@@ -1,41 +1,22 @@
-"""Distributed-optimization collectives.
-
-``quantize_int8`` / ``dequantize_int8`` — per-block int8 quantization with
-error feedback, used for gradient compression on the slow inter-pod links.
-
-``compressed_grad_sync`` — the gradient compression step of the train
-loop: quantize(grad + error_residual) -> (what would cross the pod links)
--> dequantize; the un-transmitted remainder becomes the next step's error
-residual.  Under GSPMD the actual pod-axis all-reduce is emitted by XLA
-from the batch-sharded loss; compressing the tensor *before* that
-reduction bounds inter-pod bytes at 1/4 of fp32 while error feedback keeps
-the optimizer trajectory unbiased (standard EF-SGD argument).
-
-``int8_psum_shard_map`` — an explicit manual int8 all-reduce over a named
-mesh axis (shard_map), for runtimes where the pod link is driven manually;
-unit-tested on a virtual multi-device CPU mesh.
+"""Collectives of the TM mesh paths: the ``shard_map`` the meshed serving
+and training steps are built on, a per-leaf ``psum``, and the exact integer
+delta reduction of data-parallel training.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Tuple
+from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = [
-    "quantize_int8",
-    "dequantize_int8",
-    "compressed_grad_sync",
-    "int8_psum_shard_map",
     "tree_psum_batch",
     "shard_map",
     "psum_tree",
 ]
-
-BLOCK = 2048
 
 
 #: ``jax.shard_map`` with the varying-manual-axes check off: the bodies
@@ -54,73 +35,6 @@ def psum_tree(tree: Any, axis: str) -> Any:
     return jax.tree.map(lambda x: jax.lax.psum(x, axis), tree)
 
 
-def quantize_int8(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Per-block symmetric int8 quantization. Returns (q, scales)."""
-    flat = x.astype(jnp.float32).reshape(-1)
-    n = flat.shape[0]
-    pad = (-n) % BLOCK
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros(pad, jnp.float32)])
-    blocks = flat.reshape(-1, BLOCK)
-    scale = jnp.max(jnp.abs(blocks), axis=1, keepdims=True) / 127.0
-    scale = jnp.maximum(scale, 1e-12)
-    q = jnp.clip(jnp.round(blocks / scale), -127, 127).astype(jnp.int8)
-    return q, scale
-
-
-def dequantize_int8(q: jax.Array, scale: jax.Array, shape, dtype) -> jax.Array:
-    flat = (q.astype(jnp.float32) * scale).reshape(-1)
-    n = 1
-    for s in shape:
-        n *= s
-    return flat[:n].reshape(shape).astype(dtype)
-
-
-def compressed_grad_sync(grads: Any, residual: Any) -> Tuple[Any, Any]:
-    """Error-feedback int8 compression of a gradient pytree.
-
-    Returns (dequantized grads, new residual). ``residual`` has the same
-    structure as ``grads`` (fp32).
-    """
-
-    def one(g, r):
-        gf = g.astype(jnp.float32) + r
-        q, s = quantize_int8(gf)
-        deq = dequantize_int8(q, s, g.shape, jnp.float32)
-        return deq.astype(g.dtype), gf - deq
-
-    flat_g, treedef = jax.tree.flatten(grads)
-    flat_r = jax.tree.leaves(residual)
-    outs = [one(g, r) for g, r in zip(flat_g, flat_r)]
-    return (
-        jax.tree.unflatten(treedef, [o[0] for o in outs]),
-        jax.tree.unflatten(treedef, [o[1] for o in outs]),
-    )
-
-
-def int8_psum_shard_map(x: jax.Array, mesh: Mesh, axis: str = "pod") -> jax.Array:
-    """Explicit int8-compressed all-reduce over one mesh axis.
-
-    Each shard quantizes its contribution; the int8 payload is what crosses
-    the ``axis`` links; the psum accumulates in int32 and each shard
-    rescales with the max of the per-shard scales (conservative shared
-    scale, standard for quantized all-reduce).
-    """
-
-    def body(xs):
-        q, s = quantize_int8(xs)
-        s_max = jax.lax.pmax(s, axis)
-        # Requantize against the shared scale so the reduction is exact in
-        # int32: q' = round(q * s / s_max).
-        q2 = jnp.round(q.astype(jnp.float32) * (s / s_max)).astype(jnp.int32)
-        tot = jax.lax.psum(q2, axis)
-        return dequantize_int8(tot, s_max, xs.shape, xs.dtype)
-
-    other = tuple(a for a in mesh.axis_names if a != axis)
-    spec = P(*((None,) * x.ndim))
-    return shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec)(x)
-
-
 def tree_psum_batch(tree: Any, mesh: Mesh | None = None, axis: str = "data") -> Any:
     """Sum each leaf of a per-sample pytree over its leading batch axis.
 
@@ -128,8 +42,7 @@ def tree_psum_batch(tree: Any, mesh: Mesh | None = None, axis: str = "data") -> 
     ``jnp.sum(x, axis=0)``; with a mesh the batch axis is sharded over the
     named ``axis``, each device reduces its local shard, and an exact
     integer ``psum`` combines the partial sums — TA/weight deltas are
-    small ints, so unlike the LM gradient path no quantization is needed
-    and the result is bit-identical to the single-device sum.
+    small ints, so the result is bit-identical to the single-device sum.
 
     Args:
       tree: pytree of arrays ``[B, ...]`` (cast int8 deltas to int32

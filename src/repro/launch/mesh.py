@@ -1,9 +1,9 @@
-"""Production meshes.
+"""The serving device mesh.
 
-Defined as functions (never module-level constants) so importing this
-module never touches jax device state — the dry-run sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* any jax
-initialization, and smoke tests must keep seeing 1 device.
+Built by a function (never a module-level constant) so importing this
+module never touches jax device state: on the CPU the device count is set
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax
+initializes.
 """
 
 from __future__ import annotations
@@ -12,24 +12,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-__all__ = [
-    "make_production_mesh",
-    "make_serve_device_mesh",
-    "make_test_mesh",
-    "required_devices",
-]
-
-
-def required_devices(multi_pod: bool = False) -> int:
-    return 512 if multi_pod else 256
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
-    """(16, 16) ("data", "model") single pod; (2, 16, 16) ("pod", "data",
-    "model") for the 2-pod = 512-chip dry-run."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+__all__ = ["make_serve_device_mesh"]
 
 
 def make_serve_device_mesh(data: int = 1, model: int = 1) -> Mesh:
@@ -53,8 +36,3 @@ def make_serve_device_mesh(data: int = 1, model: int = 1) -> Mesh:
         )
     devs = np.array(jax.devices()[:n]).reshape(data, model)
     return Mesh(devs, ("data", "model"))
-
-
-def make_test_mesh(data: int = 1, model: int = 1) -> Mesh:
-    """Small mesh over however many devices the process has (tests)."""
-    return make_serve_device_mesh(data, model)

@@ -28,76 +28,20 @@ one-shot request loop (see ``repro.serve.service``; rate sweeps live in
         --arch convcotm-mnist --service --rate 2000 --requests 512 \
         --max-delay-us 200
 
-LM archs keep the prefill+decode loop:
-
-    PYTHONPATH=src python -m repro.launch.serve \
-        --arch xlstm-350m --reduced --batch 4 --prompt-len 32 --gen 16
+``--arch`` takes the ``repro.configs.convcotm.COTM_CONFIGS`` names.
 """
 
 from __future__ import annotations
 
 import argparse
 import asyncio
-import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, reduced_config
-from repro.launch import specs as S
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import encdec as ed
-from repro.models import transformer as tfm
-from repro.models.base import init_params
-from repro.train.serve_step import decode, sample_tokens
 
-__all__ = ["generate", "parse_serve_mesh", "serve_tm", "serve_tm_service"]
-
-
-def generate(
-    cfg,
-    params,
-    prompt_tokens: jax.Array,     # [B, P]
-    gen_len: int,
-    *,
-    mesh=None,
-    max_seq: int | None = None,
-    temperature: float = 0.0,
-    frontend_embeds=None,
-    seed: int = 0,
-):
-    """Prompt -> generated tokens [B, gen_len] via cached decode steps."""
-    b, plen = prompt_tokens.shape
-    max_seq = max_seq or (plen + gen_len)
-
-    if cfg.is_encoder_decoder:
-        enc_out = ed.encode(params, frontend_embeds, cfg, mesh=mesh)
-        cross = ed.prepare_cross_cache(params, enc_out, cfg)
-        cache = ed.init_self_cache(b, cfg, max_seq)
-        dec_fn = jax.jit(
-            lambda p, t, c, po: decode(p, t, c, po, cfg, cross_cache=cross, mesh=mesh)
-        )
-    else:
-        cache = tfm.init_decode_cache(b, cfg, max_seq)
-        dec_fn = jax.jit(lambda p, t, c, po: decode(p, t, c, po, cfg, mesh=mesh))
-
-    key = jax.random.PRNGKey(seed)
-    # Teacher-forced prefill through the decode path (exercises the cache
-    # exactly as continuous serving does).
-    logits = None
-    for i in range(plen):
-        logits, cache = dec_fn(params, prompt_tokens[:, i : i + 1], cache, jnp.int32(i))
-
-    out = []
-    done = jnp.zeros((b,), bool)
-    tok = None
-    for j in range(gen_len):
-        key, k = jax.random.split(key)
-        tok, done = sample_tokens(k, logits, temperature=temperature, done=done)
-        out.append(tok)
-        logits, cache = dec_fn(params, tok[:, None], cache, jnp.int32(plen + j))
-    return jnp.stack(out, axis=1)
+__all__ = ["parse_serve_mesh", "serve_tm", "serve_tm_service"]
 
 
 def parse_serve_mesh(spec: str | None, shard: str = "batch"):
@@ -350,14 +294,10 @@ async def serve_tm_service(
 
 
 def main():
+    from repro.configs.convcotm import COTM_CONFIGS
+
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", required=True)
-    ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen", type=int, default=16)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    # TM serving flags
+    ap.add_argument("--arch", required=True, choices=sorted(COTM_CONFIGS))
     ap.add_argument("--requests", type=int, default=32)
     ap.add_argument("--max-batch", type=int, default=256)
     ap.add_argument("--eval-path", default=None)
@@ -403,65 +343,37 @@ def main():
     args = ap.parse_args()
     enable_compile_cache()
 
-    from repro.configs.convcotm import COTM_CONFIGS
-
-    if args.arch in COTM_CONFIGS:
-        mesh = parse_serve_mesh(args.mesh, args.shard)
-        if args.service:
-            asyncio.run(
-                serve_tm_service(
-                    args.arch,
-                    n_requests=args.requests,
-                    rate=args.rate,
-                    max_batch=args.max_batch,
-                    max_delay_us=args.max_delay_us,
-                    high_water=args.high_water,
-                    eval_path=args.eval_path,
-                    ckpt_dir=args.ckpt_dir,
-                    submit_form=args.submit_form,
-                    autotune=args.autotune,
-                    mesh=mesh,
-                    deadline_s=args.deadline_s,
-                    malformed_frac=args.malformed_frac,
-                    abandon_frac=args.abandon_frac,
-                )
+    mesh = parse_serve_mesh(args.mesh, args.shard)
+    if args.service:
+        asyncio.run(
+            serve_tm_service(
+                args.arch,
+                n_requests=args.requests,
+                rate=args.rate,
+                max_batch=args.max_batch,
+                max_delay_us=args.max_delay_us,
+                high_water=args.high_water,
+                eval_path=args.eval_path,
+                ckpt_dir=args.ckpt_dir,
+                submit_form=args.submit_form,
+                autotune=args.autotune,
+                mesh=mesh,
+                deadline_s=args.deadline_s,
+                malformed_frac=args.malformed_frac,
+                abandon_frac=args.abandon_frac,
             )
-            return
-        serve_tm(
-            args.arch,
-            n_requests=args.requests,
-            max_batch=args.max_batch,
-            eval_path=args.eval_path,
-            ckpt_dir=args.ckpt_dir,
-            ingress=args.ingress,
-            autotune=args.autotune,
-            mesh=mesh,
         )
         return
-
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = reduced_config(cfg)
-    key = jax.random.PRNGKey(0)
-    params = init_params(S.model_decls(cfg), key)
-    rng = np.random.default_rng(0)
-    prompts = jnp.asarray(
-        rng.integers(0, cfg.vocab_size, (args.batch, args.prompt_len)), jnp.int32
+    serve_tm(
+        args.arch,
+        n_requests=args.requests,
+        max_batch=args.max_batch,
+        eval_path=args.eval_path,
+        ckpt_dir=args.ckpt_dir,
+        ingress=args.ingress,
+        autotune=args.autotune,
+        mesh=mesh,
     )
-    fe = None
-    if cfg.is_encoder_decoder or cfg.modality == "vision":
-        fe = jnp.asarray(
-            rng.standard_normal((args.batch, 16, cfg.d_model)), cfg.dtype
-        )
-    t0 = time.time()
-    toks = generate(
-        cfg, params, prompts, args.gen, temperature=args.temperature,
-        frontend_embeds=fe,
-    )
-    dt = time.time() - t0
-    print(f"generated {toks.shape} in {dt:.1f}s "
-          f"({args.batch * args.gen / dt:.1f} tok/s)")
-    print(np.asarray(toks)[:2])
 
 
 if __name__ == "__main__":

@@ -1,198 +1,15 @@
-"""Roofline analysis from compiled dry-run artifacts (no hardware).
-
-TPU v5e per-chip constants (targets; the container is CPU-only):
-  peak bf16 compute : 197 TFLOP/s
-  HBM bandwidth     : 819 GB/s
-  ICI link bandwidth: ~50 GB/s per link
-
-The compiled module after GSPMD partitioning is the *per-device* program,
-so ``cost_analysis()`` FLOPs/bytes are per-chip numbers; the three terms
-are therefore computed per chip directly:
-
-  compute term    = flops / 197e12                       [s]
-  memory term     = bytes_accessed / 819e9               [s]
-  collective term = sum_op (wire_bytes(op) / 50e9)       [s]
-
-wire_bytes uses ring-algorithm factors on the *operand* bytes parsed from
-the HLO text: all-reduce ~2x(N-1)/N, all-gather/reduce-scatter/
-collective-permute ~1x, all-to-all ~(N-1)/N.  N is unknown per-op from
-text alone, so the asymptotic factors (2, 1, 1, 1) are used — an upper
-bound within (N-1)/N of exact.
-"""
+"""Roofline ceilings of the ConvCoTM eval paths against the TPU v5e
+per-chip peaks (bf16 compute 197 TFLOP/s, HBM 819 GB/s)."""
 
 from __future__ import annotations
 
-import re
 from typing import Any, Dict, Optional
 
-__all__ = [
-    "HW",
-    "parse_collective_bytes",
-    "roofline_terms",
-    "model_flops",
-    "tm_path_roofline",
-]
+__all__ = ["tm_path_roofline"]
 
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
-ICI_BW = 50e9
 
-HW = {"peak_flops": PEAK_FLOPS, "hbm_bw": HBM_BW, "ici_bw": ICI_BW}
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
-    "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
-    "s32": 4, "u32": 4, "f32": 4,
-    "s64": 8, "u64": 8, "f64": 8, "c64": 8, "c128": 16,
-}
-
-COLLECTIVE_OPS = (
-    "all-reduce",
-    "all-gather",
-    "reduce-scatter",
-    "all-to-all",
-    "collective-permute",
-)
-
-_WIRE_FACTOR = {
-    "all-reduce": 2.0,
-    "all-gather": 1.0,
-    "reduce-scatter": 1.0,
-    "all-to-all": 1.0,
-    "collective-permute": 1.0,
-}
-
-# "%name = f32[1,2,3]{...}" or tuple results "(f32[..], f32[..])"
-_DEF_RE = re.compile(r"%?([\w.\-]+)\s*=\s*(\(?)([^=]*?)\s+(\S[\w\-]*)\(")
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-
-
-def _shape_bytes(shape_text: str) -> int:
-    """Sum bytes over every 'dtype[dims]' occurrence in a type string."""
-    total = 0
-    for dtype, dims in _SHAPE_RE.findall(shape_text):
-        if dtype not in _DTYPE_BYTES:
-            continue
-        n = 1
-        if dims:
-            for d in dims.split(","):
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dtype]
-    return total
-
-
-def parse_collective_bytes(hlo_text: str) -> Dict[str, Dict[str, float]]:
-    """Per collective opcode: {count, operand_bytes, wire_bytes}.
-
-    Two-pass: build def-name -> shape-bytes map, then for each collective
-    instruction sum its operands' bytes (falling back to the result shape
-    when an operand is unknown, e.g. a constant folded inline).
-    """
-    defs: Dict[str, int] = {}
-    lines = hlo_text.splitlines()
-    for ln in lines:
-        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s([\w\-]+)\(", ln)
-        if m:
-            defs[m.group(1)] = _shape_bytes(m.group(2))
-
-    out = {op: {"count": 0, "operand_bytes": 0.0, "wire_bytes": 0.0}
-           for op in COLLECTIVE_OPS}
-    for ln in lines:
-        m = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.+?)\s([\w\-]+)\((.*)", ln)
-        if not m:
-            continue
-        name, result_type, opcode, rest = m.groups()
-        base = None
-        for op in COLLECTIVE_OPS:
-            if opcode == op or opcode.startswith(op + "-start"):
-                base = op
-                break
-        # Also catch fused start/done forms like "all-gather-start".
-        if base is None:
-            for op in COLLECTIVE_OPS:
-                if opcode.startswith(op):
-                    base = op
-                    break
-        if base is None or opcode.endswith("-done"):
-            continue
-        # Operand names inside the first (...) group.
-        operand_names = re.findall(r"%?([\w.\-]+)", rest.split(")")[0])
-        ob = sum(defs.get(n, 0) for n in operand_names if n in defs)
-        if ob == 0:
-            ob = _shape_bytes(result_type)
-        out[base]["count"] += 1
-        out[base]["operand_bytes"] += float(ob)
-        out[base]["wire_bytes"] += float(ob) * _WIRE_FACTOR[base]
-    return out
-
-
-def collective_counts_by_computation(hlo_text: str) -> Dict[str, Dict[str, int]]:
-    """Collective instruction counts per HLO computation (e.g. the
-    while-loop body of the layer scan vs the entry) — used to verify that
-    a sharding/architecture change really removed collectives from the
-    per-layer body (EXPERIMENTS.md §Perf evidence)."""
-    out: Dict[str, Dict[str, int]] = {}
-    current = "<entry>"
-    for ln in hlo_text.splitlines():
-        m = re.match(r"\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\([^)]*\)\s*->", ln)
-        if m and "=" not in ln.split("->")[0]:
-            current = m.group(1)
-            continue
-        m2 = re.match(r"\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*.+?\s([\w\-]+)\(", ln)
-        if not m2:
-            continue
-        opcode = m2.group(1)
-        for op in COLLECTIVE_OPS:
-            if opcode == op or (opcode.startswith(op) and not opcode.endswith("-done")):
-                out.setdefault(current, {}).setdefault(op, 0)
-                out[current][op] += 1
-                break
-    return out
-
-
-def roofline_terms(
-    cost: Dict[str, float],
-    hlo_text: str,
-    *,
-    chips: int,
-) -> Dict[str, Any]:
-    """Three roofline terms in seconds (per-chip program)."""
-    flops = float(cost.get("flops", 0.0))
-    bytes_acc = float(cost.get("bytes accessed", 0.0))
-    coll = parse_collective_bytes(hlo_text)
-    wire = sum(v["wire_bytes"] for v in coll.values())
-    terms = {
-        "compute_s": flops / PEAK_FLOPS,
-        "memory_s": bytes_acc / HBM_BW,
-        "collective_s": wire / ICI_BW,
-        "flops_per_chip": flops,
-        "bytes_per_chip": bytes_acc,
-        "wire_bytes_per_chip": wire,
-        "collectives": coll,
-        "chips": chips,
-    }
-    dom = max(("compute_s", "memory_s", "collective_s"), key=lambda k: terms[k])
-    terms["dominant"] = dom.replace("_s", "")
-    step = max(terms["compute_s"], terms["memory_s"], terms["collective_s"])
-    terms["bound_step_s"] = step
-    terms["roofline_fraction"] = terms["compute_s"] / step if step > 0 else 0.0
-    return terms
-
-
-def model_flops(
-    n_params: int,
-    n_active_params: int,
-    tokens: int,
-    kind: str,
-) -> float:
-    """Ideal model FLOPs: 6·N·D train, 2·N·D forward-only (per step)."""
-    n = n_active_params
-    return (6.0 if kind == "train" else 2.0) * n * tokens
-
-
-# ---------------------------------------------------------------------------
-# ConvCoTM serving paths
-# ---------------------------------------------------------------------------
 
 def tm_path_roofline(
     config,

@@ -1,181 +1,37 @@
-"""Logical-axis sharding rules.
+"""Logical-axis sharding rules of the TM serving mesh.
 
-Parameters and activations declare *logical* axes ("batch", "embed",
-"mlp", ...); this module maps them onto the physical mesh axes of whichever
-mesh is active — the single-pod (16, 16) ("data", "model") production mesh,
-the multi-pod (2, 16, 16) ("pod", "data", "model") mesh, or the 1-device
-CPU test mesh — so model code never mentions physical axes.
+Serving arrays declare *logical* axes; this module maps them onto the
+physical axes of the ("data", "model") mesh that ``serve/mesh.py`` builds,
+so the serving code never names a physical axis:
 
-Rules (MaxText-style):
-  batch   -> ("pod", "data")   data parallelism; the pod axis only ever
-                               carries batch (gradient all-reduce is the
-                               only inter-pod collective).
-  fsdp    -> "data"            parameter / optimizer-state sharding
-                               (ZeRO): the non-tensor-parallel dim of every
-                               large parameter is sharded over "data".
-  tensor  -> "model"           tensor parallelism (heads / mlp / vocab).
-  expert  -> "model"           expert parallelism for MoE archs whose
-                               expert count divides the model axis.
-  seq     -> "model"           sequence sharding for long-context decode
-                               KV caches (paged over the model axis).
+  batch   -> "data"    request batches (data parallelism).
+  clause  -> "model"   the clause pool (clause-sharded evaluation).
   (None)  -> replicated.
+
+A logical axis whose physical axis the mesh lacks resolves to replicated.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Sequence, Tuple, Union
+from typing import Optional, Sequence
 
-import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-__all__ = [
-    "LOGICAL_RULES",
-    "logical_to_physical",
-    "spec",
-    "sharding",
-    "shard",
-    "mesh_axis_size",
-]
+__all__ = ["LOGICAL_RULES", "logical_to_physical", "spec", "sharding"]
 
-Axis = Union[str, None, Tuple[str, ...]]
-
-LOGICAL_RULES = {
-    "batch": ("pod", "data"),
-    "fsdp": ("data",),
-    "tensor": ("model",),
-    "expert": ("model",),
-    "seq": ("model",),
-    "clause": ("model",),
-    "replicated": (),
-}
-
-# Sharding profiles (perf iteration, EXPERIMENTS.md §Perf):
-#   tp       — default: TP over "model", ZeRO over "data".
-#   dp       — small archs (< ~1 B params): no tensor parallelism; params
-#              ZeRO-sharded over BOTH axes, batch over ("pod","data").
-#              Eliminates the per-layer activation all-reduces that
-#              dominate small-model cells.
-#   serve_tp — decode: weights DECODE-RESIDENT, sharded over "model" only
-#              (no per-step fsdp all-gather; the ASIC's "model clock
-#              stopped" discipline applied to the pod).
-# "clause" (the TM clause pool axis, serve/mesh.py) maps to "model" in
-# every profile: clause sharding is the TM's tensor parallelism.
-PROFILES = {
-    "tp": LOGICAL_RULES,
-    "dp": {
-        "batch": ("pod", "data"),
-        "fsdp": ("data", "model"),
-        "tensor": (),
-        "expert": (),
-        "seq": ("model",),
-        "clause": ("model",),
-        "replicated": (),
-    },
-    "serve_tp": {
-        "batch": ("pod", "data"),
-        "fsdp": (),
-        "tensor": ("model",),
-        "expert": ("model",),
-        "seq": ("model",),
-        "clause": ("model",),
-        "replicated": (),
-    },
-}
-
-_ACTIVE_PROFILE = "tp"
+LOGICAL_RULES = {"batch": "data", "clause": "model"}
 
 
-def set_profile(name: str) -> None:
-    """Select the active sharding profile (launcher-scoped)."""
-    global _ACTIVE_PROFILE
-    if name not in PROFILES:
-        raise KeyError(f"unknown sharding profile {name}")
-    global LOGICAL_RULES
-    _ACTIVE_PROFILE = name
-    LOGICAL_RULES = PROFILES[name]
+def logical_to_physical(axis: Optional[str], mesh: Mesh) -> Optional[str]:
+    """One logical axis -> its physical mesh axis, or None if ``mesh`` lacks it."""
+    phys = LOGICAL_RULES.get(axis)
+    return phys if phys in mesh.axis_names else None
 
 
-def get_profile() -> str:
-    return _ACTIVE_PROFILE
-
-
-def _mesh_axes(mesh: Mesh) -> Tuple[str, ...]:
-    return tuple(mesh.axis_names)
-
-
-def logical_to_physical(axis: Axis, mesh: Mesh) -> Optional[Union[str, Tuple[str, ...]]]:
-    """One logical axis -> physical mesh axes present in ``mesh``."""
-    if axis is None:
-        return None
-    names = _mesh_axes(mesh)
-    if isinstance(axis, tuple):
-        out: list = []
-        for a in axis:
-            p = logical_to_physical(a, mesh)
-            if p is None:
-                continue
-            out.extend(p if isinstance(p, tuple) else (p,))
-        return tuple(out) if out else None
-    phys = tuple(a for a in LOGICAL_RULES.get(axis, ()) if a in names)
-    if not phys:
-        return None
-    return phys if len(phys) > 1 else phys[0]
-
-
-def spec(logical: Sequence[Axis], mesh: Mesh) -> P:
+def spec(logical: Sequence[Optional[str]], mesh: Mesh) -> P:
     """Logical axis tuple -> PartitionSpec for ``mesh``."""
     return P(*(logical_to_physical(a, mesh) for a in logical))
 
 
-def sharding(logical: Sequence[Axis], mesh: Mesh) -> NamedSharding:
+def sharding(logical: Sequence[Optional[str]], mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, spec(logical, mesh))
-
-
-def shard(x: jax.Array, logical: Sequence[Axis], mesh: Mesh) -> jax.Array:
-    """with_sharding_constraint with logical axes (no-op on 1-device mesh)."""
-    if mesh.size == 1:
-        return x
-    return jax.lax.with_sharding_constraint(x, sharding(logical, mesh))
-
-
-def sharding_for(shape: Tuple[int, ...], logical: Sequence[Axis], mesh: Mesh) -> NamedSharding:
-    """NamedSharding for a concrete shape; logical axes whose mesh-axis
-    product does not divide the dim size are dropped (jit input shardings
-    require exact divisibility — e.g. a global_batch=1 long-context cell
-    cannot shard its batch axis)."""
-    from jax.sharding import PartitionSpec as PS
-
-    base = spec(logical, mesh)
-    fixed = []
-    for dim, axes in zip(shape, base):
-        if axes is None:
-            fixed.append(None)
-            continue
-        ax_tuple = axes if isinstance(axes, tuple) else (axes,)
-        size = 1
-        for a in ax_tuple:
-            size *= mesh.shape[a]
-        fixed.append(axes if (size and dim % size == 0) else None)
-    return NamedSharding(mesh, PS(*fixed))
-
-
-def mesh_axis_size(mesh: Mesh, logical: str) -> int:
-    """Product of the physical axis sizes a logical axis maps onto."""
-    phys = logical_to_physical(logical, mesh)
-    if phys is None:
-        return 1
-    if isinstance(phys, str):
-        phys = (phys,)
-    size = 1
-    for a in phys:
-        size *= mesh.shape[a]
-    return size
-
-
-@functools.lru_cache(maxsize=8)
-def single_device_mesh() -> Mesh:
-    """1-device mesh used by smoke tests and CPU examples."""
-    import numpy as np
-
-    return Mesh(np.array(jax.devices()[:1]), ("data",))

@@ -1,114 +1,22 @@
-"""Serving: prefill and decode steps for every family, plus sampling.
-
-``prefill``  — full-sequence forward producing last-position logits (the
-shape lowered for the ``prefill_32k`` cells).  For simplicity and HLO size
-the prefill does not write the KV cache tensor-by-tensor; production
-prefill-to-decode handoff re-runs the cached projections into the decode
-cache layout (``prime_cache``), which is itself jittable.
-
-``decode``   — single-token step against the cache (the ``decode_32k`` and
-``long_500k`` cells lower this function).
-
-The sampler applies the ASIC's monotone-saturation idea (Sec. IV-D CSRF)
-to EOS handling: sequences whose EOS flag has latched are frozen and their
-per-step work is masked out — the same "saturated OR needs no more
-evaluation" reasoning, applied to batched decoding.
-"""
+"""The single-step TM classify function closed over a frozen servable."""
 
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs.base import ModelConfig
-from repro.models import encdec as ed
-from repro.models import transformer as tfm
-
-__all__ = [
-    "prefill",
-    "decode",
-    "sample_tokens",
-    "make_serve_fns",
-    "make_tm_serve_fn",
-]
-
-
-def prefill(
-    params: Any, batch: Dict, cfg: ModelConfig, *, mesh=None
-) -> jax.Array:
-    """Returns last-position logits [B, vocab]."""
-    if cfg.is_encoder_decoder:
-        hidden = ed.encdec_forward(
-            params, batch["frontend_embeds"], batch["dec_tokens"], cfg, mesh=mesh
-        )
-    else:
-        hidden, _ = tfm.forward(
-            params, batch.get("tokens"), cfg, mesh=mesh,
-            frontend_embeds=batch.get("frontend_embeds"),
-        )
-    last = hidden[:, -1]
-    from repro.models.layers import lm_logits, softcap
-
-    logits = lm_logits(params["embed"], last, cfg).astype(jnp.float32)
-    return softcap(logits, cfg.logit_softcap)
-
-
-def decode(
-    params: Any,
-    tokens: jax.Array,
-    cache: Any,
-    pos: jax.Array,
-    cfg: ModelConfig,
-    *,
-    cross_cache: Optional[Dict] = None,
-    mesh=None,
-) -> Tuple[jax.Array, Any]:
-    """One decode step -> (logits [B, vocab], new cache)."""
-    if cfg.is_encoder_decoder:
-        return ed.encdec_decode_step(
-            params, tokens, cache, cross_cache, pos, cfg, mesh=mesh
-        )
-    return tfm.decode_step(params, tokens, cache, pos, cfg, mesh=mesh)
-
-
-def sample_tokens(
-    key: jax.Array,
-    logits: jax.Array,
-    *,
-    temperature: float = 0.0,
-    eos_id: int = 2,
-    done: Optional[jax.Array] = None,
-    pad_id: int = 0,
-) -> Tuple[jax.Array, jax.Array]:
-    """Greedy/temperature sampling with latched EOS masking.
-
-    Returns (tokens [B], done [B]); once done latches, the sequence emits
-    pad tokens (frozen — the saturation early-exit).
-    """
-    if temperature > 0.0:
-        nxt = jax.random.categorical(key, logits / temperature, axis=-1)
-    else:
-        nxt = jnp.argmax(logits, axis=-1)
-    nxt = nxt.astype(jnp.int32)
-    if done is None:
-        done = jnp.zeros(nxt.shape, bool)
-    nxt = jnp.where(done, pad_id, nxt)
-    done = done | (nxt == eos_id)
-    return nxt, done
+__all__ = ["make_tm_serve_fn"]
 
 
 def make_tm_serve_fn(servable, path: Optional[str] = None):
     """Jitted TM classify step closed over a frozen :class:`ServableModel`.
 
-    The ConvCoTM analogue of ``make_serve_fns``: the model-side state is
-    baked in (the register-file image), the returned function maps
-    literals (in the path's input form) to ``(predictions, class_sums)``.
-    Prefer :class:`repro.serve.ServingEngine` for batched traffic — this
-    is the single-step building block (the engine's own jitted step,
-    shared compile cache included).
+    The model-side state is baked in (the register-file image), the
+    returned function maps literals (in the path's input form) to
+    ``(predictions, class_sums)``.  Prefer
+    :class:`repro.serve.ServingEngine` for batched traffic — this is the
+    single-step building block (the engine's own jitted step, shared
+    compile cache included).
     """
     from repro.serve.engine import classify_step
     from repro.serve.paths import get_path
@@ -116,17 +24,3 @@ def make_tm_serve_fn(servable, path: Optional[str] = None):
     name = path or servable.config.eval_path
     get_path(name)  # fail fast on unknown paths
     return functools.partial(classify_step, servable, path_name=name)
-
-
-def make_serve_fns(cfg: ModelConfig, mesh=None):
-    """(prefill_fn, decode_fn) closed over cfg/mesh, ready for jit."""
-
-    def prefill_fn(params, batch):
-        return prefill(params, batch, cfg, mesh=mesh)
-
-    def decode_fn(params, tokens, cache, pos, cross_cache=None):
-        return decode(
-            params, tokens, cache, pos, cfg, cross_cache=cross_cache, mesh=mesh
-        )
-
-    return prefill_fn, decode_fn

@@ -3,7 +3,6 @@ accelerator reproduced in JAX) + the launcher drivers."""
 
 import dataclasses
 import os
-import subprocess
 import sys
 
 import jax
@@ -198,34 +197,79 @@ class TestPipeline:
 
 
 class TestDrivers:
-    @pytest.mark.slow
-    def test_train_driver_runs_and_checkpoints(self, tmp_path):
-        r = subprocess.run(
-            [
-                sys.executable, "-m", "repro.launch.train",
-                "--arch", "h2o-danube-1.8b", "--reduced",
-                "--steps", "4", "--batch", "4", "--seq", "32",
-                "--ckpt-dir", str(tmp_path), "--microbatches", "2",
-            ],
-            capture_output=True, text=True, timeout=540,
-            env={**os.environ, "PYTHONPATH": "src"}, cwd=REPO,
-        )
-        assert r.returncode == 0, r.stderr[-3000:]
-        assert "step" in r.stdout
+    """The TM modes of the two CLI drivers, on the synthetic glyph fallback
+    of ``get_dataset`` at a small size."""
+
+    ARCH = "convcotm-mnist"
+    KW = dict(epochs=1, batch=50, n_train=100, n_test=50)
+
+    @pytest.fixture(scope="class")
+    def ckpt(self, tmp_path_factory):
+        """One epoch of ``run_tm_training``, checkpointed."""
+        from repro.launch.train import run_tm_training
+
+        ckpt = str(tmp_path_factory.mktemp("tm_ckpt"))
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr("repro.data.datasets.DATA_DIR", ckpt)
+            out = run_tm_training(self.ARCH, ckpt_dir=ckpt, **self.KW)
+        return ckpt, out
+
+    @pytest.fixture(autouse=True)
+    def _synthetic(self, tmp_path, monkeypatch):
+        monkeypatch.setattr("repro.data.datasets.DATA_DIR", str(tmp_path))
+
+    def test_training_writes_a_checkpoint(self, ckpt):
         from repro.checkpoint.checkpointer import latest_step
 
-        assert latest_step(str(tmp_path)) == 4
+        ckpt_dir, out = ckpt
+        assert latest_step(ckpt_dir) == 1
+        assert out["epochs"] == 1.0
+        assert out["samples_per_s"] > 0
+        assert 0.0 <= out["accuracy"] <= 1.0
 
-    @pytest.mark.slow
-    def test_serve_driver_generates(self):
-        r = subprocess.run(
-            [
-                sys.executable, "-m", "repro.launch.serve",
-                "--arch", "xlstm-350m", "--reduced",
-                "--batch", "2", "--prompt-len", "4", "--gen", "4",
-            ],
-            capture_output=True, text=True, timeout=540,
-            env={**os.environ, "PYTHONPATH": "src"}, cwd=REPO,
+    def test_training_resumes_and_trains_no_further_epoch(self, ckpt, capsys):
+        from repro.checkpoint.checkpointer import latest_step
+        from repro.launch.train import run_tm_training
+
+        ckpt_dir, _ = ckpt
+        out = run_tm_training(self.ARCH, ckpt_dir=ckpt_dir, **self.KW)
+        assert "resumed from epoch 1" in capsys.readouterr().out
+        assert out["epochs"] == 1.0
+        assert out["samples_per_s"] == 0.0          # no epoch ran
+        assert latest_step(ckpt_dir) == 1
+
+    @pytest.mark.parametrize("ingress", ["device", "host"])
+    def test_serve_answers_every_frame(self, ckpt, ingress, monkeypatch):
+        from repro.launch.serve import serve_tm
+        from repro.serve import ServingEngine
+
+        sent = []
+        classify = ServingEngine.classify
+
+        def spy(engine, name, frames, **kw):
+            res = classify(engine, name, frames, **kw)
+            sent.append((len(frames), len(res.predictions), kw.get("ingress")))
+            return res
+
+        monkeypatch.setattr(ServingEngine, "classify", spy)
+        stats = serve_tm(
+            self.ARCH, n_requests=3, max_batch=4, ckpt_dir=ckpt[0], ingress=ingress
         )
-        assert r.returncode == 0, r.stderr[-3000:]
-        assert "generated" in r.stdout
+        assert len(sent) == 3
+        assert all(n == answered and form == ingress for n, answered, form in sent)
+        assert stats["requests"] == 3
+        assert stats["images"] == sum(n for n, _, _ in sent)
+
+    @pytest.mark.parametrize("driver", ["serve", "train"])
+    def test_cli_refuses_a_language_model_arch(self, driver, monkeypatch, capsys):
+        import importlib
+
+        main = importlib.import_module(f"repro.launch.{driver}").main
+        monkeypatch.setattr(sys, "argv", [driver, "--arch", "h2o-danube-1.8b"])
+        with pytest.raises(SystemExit) as exc:
+            main()
+        assert exc.value.code == 2
+        err = capsys.readouterr().err
+        assert "h2o-danube-1.8b" in err
+        for arch in COTM_CONFIGS:
+            assert arch in err

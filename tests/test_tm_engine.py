@@ -8,6 +8,10 @@ so "same accuracy as the naive epoch loop" holds by construction and is
 asserted directly on the glyphs example config.
 """
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -243,6 +247,52 @@ class TestEngineAPI:
         np.testing.assert_array_equal(
             np.asarray(m_a.weights), np.asarray(m_b.weights)
         )
+
+
+@pytest.mark.slow
+def test_tm_engine_data_parallel_8dev():
+    """TrainerEngine with a mesh: per-device delta sums combined by the
+    shard_map psum must give a model bit-identical to the unmeshed run
+    (subprocess: the device count must be set before jax initializes)."""
+    code = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import sys; sys.path.insert(0, "src")
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+from repro.core.cotm import CoTMConfig
+from repro.core.patches import PatchSpec
+from repro.train.tm_engine import TrainerEngine
+
+spec = PatchSpec(image_x=8, image_y=8, window_x=3, window_y=3)
+cfg = CoTMConfig(n_clauses=16, n_classes=3, patch=spec, T=15, s=3.0)
+rng = np.random.default_rng(0)
+x = (rng.random((64, 8, 8)) > 0.5).astype(np.uint8)
+y = rng.integers(0, 3, 64).astype(np.int32)
+key = jax.random.PRNGKey(2)
+
+plain = TrainerEngine(cfg, batch_size=16)
+ds = plain.prepare(x, y, booleanize_method="none")
+m1 = plain.init_model(key)
+_, m1, _, _ = plain.fit(key, m1, ds, epochs=2)
+
+mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
+meshed = TrainerEngine(cfg, batch_size=16, mesh=mesh)
+ds2 = meshed.prepare(x, y, booleanize_method="none")
+m2 = meshed.init_model(key)
+_, m2, _, _ = meshed.fit(key, m2, ds2, epochs=2)
+
+np.testing.assert_array_equal(np.asarray(m1.ta_state), np.asarray(m2.ta_state))
+np.testing.assert_array_equal(np.asarray(m1.weights), np.asarray(m2.weights))
+print("OK")
+"""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        cwd=repo, timeout=540, env={**os.environ, "PYTHONPATH": "src"},
+    )
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "OK" in r.stdout
 
 
 class TestEvaluateHostSyncContract:
