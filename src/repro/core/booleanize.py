@@ -24,6 +24,8 @@ import numpy as np
 __all__ = [
     "threshold_booleanize",
     "gaussian_kernel1d",
+    "gaussian_band_matrix",
+    "gaussian_local_mean",
     "adaptive_gaussian_booleanize",
     "thermometer_encode",
     "thermometer_thresholds",
@@ -56,6 +58,24 @@ def gaussian_kernel1d(size: int, sigma: Optional[float] = None) -> np.ndarray:
     return (k / k.sum()).astype(np.float32)
 
 
+def gaussian_band_matrix(n: int, block_size: int) -> np.ndarray:
+    """Edge replication then the ``block_size`` Gaussian along an axis of
+    length ``n``, as one ``[n, n]`` float32 matrix ``G``:
+    ``G @ v == np.convolve(np.pad(v, block_size // 2, mode="edge"), k,
+    "valid")`` with ``k = gaussian_kernel1d(block_size)``.
+
+    A clipped tap adds its weight to the edge column, so each row sums to 1.
+    Summed in float64, then cast.
+    """
+    k = gaussian_kernel1d(block_size).astype(np.float64)
+    pad = block_size // 2
+    rows = np.arange(n)
+    g = np.zeros((n, n), np.float64)
+    for t, w in enumerate(k):
+        g[rows, np.clip(rows + t - pad, 0, n - 1)] += w
+    return g.astype(np.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("block_size", "channels_last"))
 def adaptive_gaussian_booleanize(
     images: jax.Array,
@@ -70,9 +90,12 @@ def adaptive_gaussian_booleanize(
     what ``cv2.adaptiveThreshold(..., ADAPTIVE_THRESH_GAUSSIAN_C,
     THRESH_BINARY, block_size, c)`` does.
 
-    Both passes run at ``Precision.HIGHEST``: at the default precision the
-    TPU rounds the float32 convolution through bfloat16, which moves
-    pixels near ``local_mean - c`` across the threshold.
+    The local mean of each plane is ``G_y · x · G_xᵀ``
+    (:func:`gaussian_local_mean`, :func:`gaussian_band_matrix`): two
+    contractions, over Y and then X, which run on the TPU's matrix unit.
+    Both run at ``Precision.HIGHEST``: at a lower precision the TPU rounds
+    the float32 band weights through bfloat16, which moves pixels near
+    ``local_mean - c`` across the threshold.
 
     Args:
       images: ``[..., H, W]`` uint8/float, or ``[..., H, W, Z]`` with
@@ -85,29 +108,21 @@ def adaptive_gaussian_booleanize(
     if block_size % 2 != 1:
         raise ValueError(f"block_size must be odd, got {block_size}")
     x = images.astype(jnp.float32)
-    if channels_last:
-        x = jnp.moveaxis(x, -1, -3)
-    batch_shape = x.shape[:-2]
-    h, w = x.shape[-2:]
-    x2 = x.reshape((-1, h, w))
+    return (x > (gaussian_local_mean(x, block_size, channels_last) - c)).astype(jnp.uint8)
 
-    k = jnp.asarray(gaussian_kernel1d(block_size))
-    pad = block_size // 2
-    conv = functools.partial(
-        jnp.convolve, mode="valid", precision=jax.lax.Precision.HIGHEST
-    )
 
-    # Separable convolution with edge replication.
-    xp = jnp.pad(x2, ((0, 0), (pad, pad), (0, 0)), mode="edge")
-    # Convolve rows (axis 1).
-    xr = jax.vmap(
-        lambda img: jax.vmap(lambda col: conv(col, k), in_axes=1, out_axes=1)(img)
-    )(xp)
-    xp2 = jnp.pad(xr, ((0, 0), (0, 0), (pad, pad)), mode="edge")
-    local_mean = jax.vmap(lambda img: jax.vmap(lambda row: conv(row, k))(img))(xp2)
-
-    out = (x2 > (local_mean - c)).astype(jnp.uint8).reshape(batch_shape + (h, w))
-    return jnp.moveaxis(out, -3, -1) if channels_last else out
+def gaussian_local_mean(x: jax.Array, block_size: int, channels_last: bool) -> jax.Array:
+    """float32 ``G_y · x · G_xᵀ`` of each plane of ``x`` (``[..., H, W]``, or
+    ``[..., H, W, Z]`` with ``channels_last``), both contractions at
+    ``Precision.HIGHEST``; the local mean of
+    :func:`adaptive_gaussian_booleanize`."""
+    z = "z" if channels_last else ""
+    h, w = x.shape[-3:-1] if channels_last else x.shape[-2:]
+    gy = jnp.asarray(gaussian_band_matrix(h, block_size))
+    gx = jnp.asarray(gaussian_band_matrix(w, block_size))
+    hi = jax.lax.Precision.HIGHEST
+    rows = jnp.einsum(f"yi,...ix{z}->...yx{z}", gy, x, precision=hi)
+    return jnp.einsum(f"xj,...yj{z}->...yx{z}", gx, rows, precision=hi)
 
 
 def thermometer_thresholds(levels: int, lo: float = 0.0, hi: float = 255.0) -> np.ndarray:

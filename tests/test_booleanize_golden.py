@@ -10,7 +10,8 @@ test-time dependency).
 
 Exactness caveat: OpenCV computes the Gaussian local mean in 8-bit
 fixed point (its uint8 GaussianBlur path) and rounds it to uint8 before
-comparing; the JAX path keeps the separable convolution in float32.
+comparing; the JAX path keeps the local mean (two band-matrix
+contractions) in float32.
 The two can therefore disagree only for pixels whose value falls within
 a few gray levels of the decision boundary ``local_mean - c`` —
 empirically the fixed-point mean deviates by up to ~2.5 levels, so the
@@ -29,6 +30,7 @@ import pytest
 from repro.core.booleanize import (
     adaptive_gaussian_booleanize,
     booleanize,
+    gaussian_band_matrix,
     gaussian_kernel1d,
 )
 
@@ -115,8 +117,8 @@ class TestAdaptiveChannels:
     each smoothed over Y and X only, as ``adaptiveThreshold`` on each
     plane; checked against the float64 NumPy local mean above."""
 
-    @pytest.mark.parametrize("shape", [(3, 16, 13), (3, 16, 13, 3)],
-                             ids=["gray", "rgb"])
+    @pytest.mark.parametrize("shape", [(3, 16, 13), (3, 16, 13, 3), (2, 32, 32, 3)],
+                             ids=["gray", "rgb", "rgb-composite"])
     @pytest.mark.parametrize("bs,c", [(5, 2.0), (11, 2.0)])
     def test_each_channel_matches_numpy(self, shape, bs, c):
         rng = np.random.default_rng(bs)
@@ -142,13 +144,32 @@ class TestAdaptiveChannels:
         np.testing.assert_array_equal(a[..., 0], b[..., 0])
 
     def test_convolutions_run_at_highest_precision(self):
-        """The TPU rounds a default-precision float32 convolution through
-        bfloat16; both passes ask for HIGHEST."""
+        """The local mean of a channels-last frame is two contractions with
+        the band matrices, straight on ``[B, Y, X, Z]``; the TPU rounds a
+        default-precision float32 contraction through bfloat16, so both ask
+        for HIGHEST, and no float convolution is left."""
         import jax
 
         jaxpr = str(jax.make_jaxpr(
             lambda x: adaptive_gaussian_booleanize(x, 5, 2.0, channels_last=True)
         )(np.zeros((1, 8, 8, 3), np.uint8)))
-        convs = jaxpr.count("conv_general_dilated[")
-        assert convs == 2
-        assert jaxpr.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == convs
+        dots = jaxpr.count("dot_general[")
+        assert dots == 2
+        assert jaxpr.count("precision=(Precision.HIGHEST, Precision.HIGHEST)") == dots
+        assert "conv_general_dilated[" not in jaxpr
+
+
+class TestBandMatrix:
+    """``gaussian_band_matrix`` is edge replication then the Gaussian
+    window along one axis, as one matrix."""
+
+    @pytest.mark.parametrize("n", [8, 28, 32])
+    @pytest.mark.parametrize("bs", [5, 11])
+    def test_matches_padded_convolution(self, n, bs):
+        g = gaussian_band_matrix(n, bs)
+        assert g.shape == (n, n) and g.dtype == np.float32
+        k = gaussian_kernel1d(bs).astype(np.float64)
+        v = np.random.default_rng(n * bs).uniform(0, 1, n)
+        want = np.convolve(np.pad(v, bs // 2, mode="edge"), k, "valid")
+        np.testing.assert_allclose(g.astype(np.float64) @ v, want, rtol=0, atol=1e-6)
+        np.testing.assert_allclose(g.astype(np.float64).sum(axis=1), 1.0, rtol=0, atol=1e-6)
